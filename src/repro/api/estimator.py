@@ -62,6 +62,11 @@ except ImportError:  # sklearn absent: the manual contract below suffices
         pass
 
 
+#: largest difference, relative to a descriptor feature's largest training
+#: value, between its compiled-program values and its training values
+_REPLAY_RTOL = 1e-12
+
+
 class NotFittedError(RuntimeError):
     """Raised when predict/transform/score is called before fit."""
 
@@ -213,7 +218,10 @@ class _BaseSisso(_SkBase):
         )
 
         # compile every model's descriptor and validate it reproduces the
-        # training value matrix exactly (core/descriptor.py contract)
+        # training value matrix (core/descriptor.py contract): exactly on
+        # a CPU; a TPU emulates fp64, and its compiled program may round
+        # the last bit differently from the per-op training path (7e-15 at
+        # values ~50 on a v5e), while a wrong lineage is off by O(1)
         xmat = fit.fspace.values_matrix()
         models_by_dim = {}
         for dim, models in fit.models_by_dim.items():
@@ -222,7 +230,8 @@ class _BaseSisso(_SkBase):
                 program = compile_features(mdl.features, fit.fspace)
                 got = solver.engine.eval_program(program, xp)
                 want = xmat[[f.row for f in mdl.features]]
-                if not np.array_equal(got, want):
+                scale = np.abs(want).max(axis=1, keepdims=True)
+                if not np.all(np.abs(got - want) <= _REPLAY_RTOL * scale):
                     raise RuntimeError(
                         f"compiled descriptor diverged from training values "
                         f"for dim-{dim} model {list(program.exprs)} "

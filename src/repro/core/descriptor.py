@@ -15,8 +15,13 @@ Evaluation is dispatched through the execution-engine layer
 every backend's ``eval_block`` used during training — so *predict-on-train
 reproduces the training value matrix bit-for-bit*.  The jnp backend compiles
 the whole tape into one jit-cached closure (one executable per batch shape,
-reused across serving requests); XLA's elementwise ops are deterministic, so
-the fused program stays bitwise identical to the per-op training path.
+reused across serving requests).  Each tape step sits behind an
+optimization barrier: XLA would otherwise rewrite across steps (``x /
+sqrt(y)`` becomes ``x * rsqrt(y)``, one ulp off the per-op training value),
+so the compiled program runs exactly the per-op HLO that training ran.  That
+makes predict-on-train bit-exact on a CPU; a TPU emulates fp64, and there
+the compiled program can still round the last bit differently from the
+per-op training path, which round-trips every value through the host.
 
 Pure numpy is deliberately *not* used for the math: host libm and XLA disagree
 in the last ulp on transcendentals (exp/log/cbrt), which would break the exact
@@ -153,7 +158,8 @@ def program_evaluator_jnp(program: DescriptorProgram):
     def run(x: jnp.ndarray) -> jnp.ndarray:  # (n_inputs, S) -> (n_outputs, S)
         tape = [x[i] for i in range(program.n_inputs)]
         for ins in program.instructions:
-            tape.append(apply_op(ins.op_id, tape[ins.a], tape[ins.b]))
+            tape.append(jax.lax.optimization_barrier(
+                apply_op(ins.op_id, tape[ins.a], tape[ins.b])))
         return jnp.stack([tape[o] for o in program.outputs])
 
     return jax.jit(run)

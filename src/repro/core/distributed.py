@@ -15,13 +15,12 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:  # jax >= 0.5 re-exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # jax 0.4.x keeps it in experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .sis import ScoreContext, TaskLayout, scores_from_reductions
+from .sis import (
+    ScoreContext, TaskLayout, scores_from_reductions, screen_reductions,
+)
 
 
 def _dp_axes(mesh: Mesh) -> Tuple[str, ...]:
@@ -60,9 +59,7 @@ def _sis_sharded_fn(mesh: Mesh, n_residuals: int):
         out_specs=P(dp),
     )
     def local(x_blk, m_blk, yt_blk, counts, mask_blk):
-        sums = x_blk @ m_blk.T
-        sumsq = (x_blk * x_blk) @ m_blk.T
-        dots = x_blk @ yt_blk.T
+        sums, sumsq, dots = screen_reductions(x_blk, m_blk, yt_blk)
         if sample_ax is not None:
             sums = jax.lax.psum(sums, sample_ax)
             sumsq = jax.lax.psum(sumsq, sample_ax)
@@ -117,12 +114,10 @@ def _sis_topk_fn(mesh: Mesh, n_residuals: int, k_local: int, k_merge: int):
         in_specs=(P(dp, sample_ax), P(None, sample_ax), P(None, sample_ax),
                   P(None), P(dp)),
         out_specs=(P(None), P(None)),
-        check_rep=False,
+        check_vma=False,
     )
     def local(x_blk, m_blk, yt_blk, counts, mask_blk):
-        sums = x_blk @ m_blk.T
-        sumsq = (x_blk * x_blk) @ m_blk.T
-        dots = x_blk @ yt_blk.T
+        sums, sumsq, dots = screen_reductions(x_blk, m_blk, yt_blk)
         if sample_ax is not None:
             sums = jax.lax.psum(sums, sample_ax)
             sumsq = jax.lax.psum(sumsq, sample_ax)
@@ -254,7 +249,7 @@ def make_l0_topk_fn(mesh: Mesh, scorer, k_local: int, k_merge: int,
         shard_map, mesh=mesh,
         in_specs=(P(dp, None), P(dp)) + (P(),) * n_operands,
         out_specs=(P(None), P(None)),
-        check_rep=False,
+        check_vma=False,
     )
     def local(tup_blk, vld_blk, *ops):
         sse = scorer(tup_blk, *ops)
@@ -274,30 +269,36 @@ def make_l0_topk_reduced_fn(mesh: Mesh, reducer, k_local: int, k_merge: int,
     """Reduced-epilogue variant of :func:`make_l0_topk_fn`.
 
     ``reducer(tuples_blk, valid_blk, *operands) -> (sse (k_local,), local_idx
-    (k_local,))`` runs a *kernel-side* top-k (e.g. the Pallas Gram-gather
-    reduced epilogue via ``Backend.l0_device_reducer``) so the full per-shard
-    SSE vector never reaches HBM — only k-sized panels.  The reducer masks
-    its own padding (valid rows form a global prefix, so each shard derives
-    its live count from ``valid_blk``) and returns ascending fp32 SSEs with
-    ``+inf`` sentinels; indices are shard-local and lifted to global row
-    numbers here before the all-gather merge.  Because the reducer is an
-    fp32 prescreen, the caller rescores the merged survivors in fp64.
+    (k_local,), floor)`` runs a *kernel-side* top-k (e.g. the Pallas
+    Gram-gather reduced epilogue via ``Backend.l0_device_reducer``) so the
+    full per-shard SSE vector never reaches HBM — only k-sized panels.  The
+    reducer masks its own padding (valid rows form a global prefix, so each
+    shard derives its live count from ``valid_blk``) and returns ascending
+    fp32 SSE bounds with ``+inf`` sentinels and the floor under every bound
+    it left out; indices are shard-local and lifted to global row numbers
+    here before the all-gather merge.  Returns ``(bounds (k_merge,), gidx
+    (k_merge,), floor)``, the floor taken over every shard and the merge.
+    Because the reducer is an fp32 prescreen, the caller rescores the
+    merged survivors in fp64 and certifies them against the floor.
     """
     dp = _dp_axes(mesh)
 
     @functools.partial(
         shard_map, mesh=mesh,
         in_specs=(P(dp, None), P(dp)) + (P(),) * n_operands,
-        out_specs=(P(None), P(None)),
-        check_rep=False,
+        out_specs=(P(None), P(None), P()),
+        check_vma=False,
     )
     def local(tup_blk, vld_blk, *ops):
-        sse, lidx = reducer(tup_blk, vld_blk, *ops)
+        sse, lidx, floor = reducer(tup_blk, vld_blk, *ops)
         gidx = tup_blk.shape[0] * _shard_index(dp) + lidx
         gv = jax.lax.all_gather(-sse, dp, tiled=True)
         gi = jax.lax.all_gather(gidx, dp, tiled=True)
         n2, s2 = jax.lax.top_k(gv, k_merge)
-        return -n2, gi[s2]
+        floor = jax.lax.pmin(floor, dp)
+        cut = jnp.sum(jnp.isfinite(gv)) > jnp.sum(jnp.isfinite(n2))
+        floor = jnp.where(cut, jnp.minimum(floor, -n2[-1]), floor)
+        return -n2, gi[s2], floor
 
     return jax.jit(local)
 
@@ -381,7 +382,7 @@ def _overlap_sis_fn(mesh: Mesh, topk: Optional[Tuple[int, int]]):
         in_specs=(P(dp, None), P(None, None), P(None, None), P(None, None),
                   P(dp)),
         out_specs=out_specs,
-        check_rep=topk is None,
+        check_vma=topk is None,
     )
     def local(x_blk, task_mem, class_mem, masks, mask_blk):
         scores = overlap_scores_ops(x_blk, task_mem, class_mem, masks)
@@ -472,7 +473,7 @@ def _fused_sis_topk_fn(mesh: Mesh, op_id: int, n_residuals: int,
         in_specs=(P(dp, None), P(dp, None), P(None, None), P(None, None),
                   P(None, None), P(dp)),
         out_specs=(P(None), P(None)),
-        check_rep=False,
+        check_vma=False,
     )
     def local(a_blk, b_blk, m_blk, yt_blk, cnt, nv_blk):
         vals, gidx = fused_gen_sis_topk_pallas(
@@ -522,8 +523,11 @@ def fused_sis_topk_sharded(
         "fused sharded SIS requires sample-replicated meshes; use the "
         "compose path (eval + sis_topk_sharded) on sample-sharded meshes"
     )
+    from ..kernels.ops import fused_sis_block_rows
+
     dtype = jnp.float32 if dtype is None else jnp.dtype(dtype)
     bsz, s = a.shape
+    block_b = fused_sis_block_rows(s, block_b)
     nd = _n_dp(mesh)
     s_pad = ((max(s, 128) + 127) // 128) * 128
     chunk = nd * block_b
@@ -586,9 +590,8 @@ def sis_scores_distributed(
         out_specs=(P(dp), P(dp)),
     )
     def local(x_blk, m_blk, yt_blk):
-        sums = jax.lax.psum(x_blk @ m_blk.T, "model")
-        sumsq = jax.lax.psum((x_blk * x_blk) @ m_blk.T, "model")
-        dots = jax.lax.psum(x_blk @ yt_blk.T, "model")
+        sums, sumsq, dots = jax.lax.psum(
+            screen_reductions(x_blk, m_blk, yt_blk), "model")
         scores = scores_from_reductions(sums, sumsq, dots, counts,
                                         ctx.n_residuals)
         vals, idx = jax.lax.top_k(scores, k_local)

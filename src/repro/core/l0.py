@@ -25,6 +25,7 @@ coefficient matrices for each task").
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import warnings
 from typing import Iterator, Optional, Sequence, Tuple
@@ -44,15 +45,26 @@ _JITTER = 1e-10
 
 @dataclasses.dataclass
 class GramStats:
-    """Per-task sufficient statistics for least squares over feature tuples."""
+    """Per-task sufficient statistics for least squares over feature tuples.
 
-    gram: jnp.ndarray    # (T, m, m)   X_t X_tᵀ
-    fsum: jnp.ndarray    # (T, m)      X_t · 1
-    b: jnp.ndarray       # (T, m)      X_t y_t
+    The statistics are those of the *task-centered* data (each feature and
+    the target minus its per-task mean), so ``fsum`` and ``ysum`` are zero
+    and the intercept decouples from the slopes.  An SSE is then a small
+    difference of centered moments, not of raw second moments that the
+    means dominate: the uncentered form lost the planted Kaggle model in
+    fp32 and near-exact thermal fits in the fp32 kernel prescreen.  The
+    means are kept for :func:`coefficients_for`'s intercepts.
+    """
+
+    gram: jnp.ndarray    # (T, m, m)   X_t X_tᵀ  (centered X_t)
+    fsum: jnp.ndarray    # (T, m)      X_t · 1   (zero)
+    b: jnp.ndarray       # (T, m)      X_t y_t   (centered X_t, y_t)
     n: jnp.ndarray       # (T,)        samples per task
-    ysum: jnp.ndarray    # (T,)
-    yty: jnp.ndarray     # (T,)
+    ysum: jnp.ndarray    # (T,)        (zero)
+    yty: jnp.ndarray     # (T,)        centered y_t · y_t
     m: int
+    xmean: Optional[np.ndarray] = None   # (T, m) fp64 per-task means
+    ymean: Optional[np.ndarray] = None   # (T,)
 
     @property
     def n_tasks(self) -> int:
@@ -60,27 +72,38 @@ class GramStats:
 
 
 def compute_gram_stats(
-    x: jnp.ndarray,  # (m, S) feature values (standardize upstream for conditioning)
+    x: jnp.ndarray,  # (m, S) feature values
     y: jnp.ndarray,  # (S,)
     layout: TaskLayout,
     dtype=jnp.float64,
 ) -> GramStats:
-    x = jnp.asarray(x, dtype)
-    y = jnp.asarray(y, dtype)
-    grams, fsums, bs, ns, ysums, ytys = [], [], [], [], [], []
+    """Centered per-task Gram statistics (see :class:`GramStats`).
+
+    The centering runs on the host in fp64 whatever ``dtype`` is, so the
+    rounding of the ``dtype`` moments is relative to the centered scale.
+    """
+    x = np.asarray(x, np.float64)
+    y = np.asarray(y, np.float64)
+    # HIGHEST: a TPU runs fp32 dots as bf16 passes by default
+    dot = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
+    grams, bs, ytys, xmeans, ymeans = [], [], [], [], []
     for lo, hi in layout.slices:
-        xt = x[:, lo:hi]
-        yt = y[lo:hi]
-        grams.append(xt @ xt.T)
-        fsums.append(xt.sum(axis=1))
-        bs.append(xt @ yt)
-        ns.append(hi - lo)
-        ysums.append(yt.sum())
-        ytys.append(yt @ yt)
+        xm = x[:, lo:hi].mean(axis=1)
+        ym = y[lo:hi].mean()
+        xt = jnp.asarray(x[:, lo:hi] - xm[:, None], dtype)
+        yt = jnp.asarray(y[lo:hi] - ym, dtype)
+        grams.append(dot(xt, xt.T))
+        bs.append(dot(xt, yt))
+        ytys.append(dot(yt, yt))
+        xmeans.append(xm)
+        ymeans.append(ym)
+    t, m = len(layout.slices), int(x.shape[0])
     return GramStats(
-        gram=jnp.stack(grams), fsum=jnp.stack(fsums), b=jnp.stack(bs),
-        n=jnp.asarray(ns, dtype), ysum=jnp.stack(ysums), yty=jnp.stack(ytys),
-        m=int(x.shape[0]),
+        gram=jnp.stack(grams), fsum=jnp.zeros((t, m), dtype),
+        b=jnp.stack(bs),
+        n=jnp.asarray([hi - lo for lo, hi in layout.slices], dtype),
+        ysum=jnp.zeros((t,), dtype), yty=jnp.stack(ytys), m=m,
+        xmean=np.stack(xmeans), ymean=np.asarray(ymeans),
     )
 
 
@@ -88,41 +111,64 @@ def compute_gram_stats(
 # engine 1: Gram-cached scoring (TPU-native)
 # ---------------------------------------------------------------------------
 
-def _solve_tuple_task(g, s_, b, n, ysum, yty, idx):
-    """SSE of the LSQ fit (with intercept) for one tuple in one task."""
-    if np.dtype(g.dtype).itemsize < 4:
-        # sub-fp32 Gram stats (bf16 precision mode): the SPD solve has no
-        # sub-fp32 Cholesky lowering — bf16 is a storage/matmul format,
-        # solves run in fp32
-        g, s_, b = g.astype(jnp.float32), s_.astype(jnp.float32), b.astype(jnp.float32)
-        n, ysum, yty = (v.astype(jnp.float32) for v in (n, ysum, yty))
-    gs = g[jnp.ix_(idx, idx)]                       # (n, n)
-    ss = s_[idx]                                    # (n,)
-    bs = b[idx]                                     # (n,)
-    k = idx.shape[0]
-    a = jnp.zeros((k + 1, k + 1), g.dtype)
-    a = a.at[:k, :k].set(gs)
-    a = a.at[:k, k].set(ss)
-    a = a.at[k, :k].set(ss)
-    a = a.at[k, k].set(n)
-    rhs = jnp.concatenate([bs, ysum[None]])
-    a = a + _JITTER * jnp.eye(k + 1, dtype=g.dtype)
-    c = jax.scipy.linalg.solve(a, rhs, assume_a="pos")
-    sse = yty - c @ rhs
-    bad = ~jnp.isfinite(sse)
-    return jnp.where(bad, jnp.inf, jnp.maximum(sse, 0.0))
+def _spd_sse(a, rhs, yty):
+    """``yty - rhsᵀ A⁻¹ rhs`` for a batch of small SPD systems.
+
+    ``a`` is a k×k nested list and ``rhs`` a length-k list of (B,) arrays,
+    one per coefficient.  The Cholesky factor is unrolled over the static
+    ``k``, so every step is an elementwise op over the batch: on a TPU a
+    batched ``jax.scipy.linalg.solve`` compiles in time that grows with the
+    batch (90 s for 65,536 fp32 4×4 systems, longer in fp64), and a
+    ``(B, k, k)`` array pads its two minor axes to a whole (8, 128) tile.
+    A system that is not positive definite gives a NaN pivot, which the
+    caller maps to +inf.
+    """
+    k = len(rhs)
+    low = [[None] * k for _ in range(k)]
+    z = []
+    for j in range(k):
+        d = a[j][j] - sum(low[j][p] * low[j][p] for p in range(j))
+        ljj = jnp.sqrt(d)
+        low[j][j] = ljj
+        for i in range(j + 1, k):
+            low[i][j] = (a[i][j]
+                         - sum(low[i][p] * low[j][p] for p in range(j))) / ljj
+        z.append((rhs[j] - sum(low[j][p] * z[p] for p in range(j))) / ljj)
+    return yty - sum(zj * zj for zj in z)
 
 
 def score_tuples_gram(stats: GramStats, tuples: jnp.ndarray) -> jnp.ndarray:
-    """Total SSE over tasks for each tuple.  tuples: (B, n) int32."""
+    """Total SSE over tasks for each tuple.  tuples: (B, n) int32.
 
-    def per_tuple(idx):
-        per_task = jax.vmap(_solve_tuple_task, in_axes=(0, 0, 0, 0, 0, 0, None))(
-            stats.gram, stats.fsum, stats.b, stats.n, stats.ysum, stats.yty, idx
-        )
-        return per_task.sum()
-
-    return jax.vmap(per_tuple)(jnp.asarray(tuples))
+    Per task, the LSQ fit with intercept is the (n+1)×(n+1) SPD system
+    gathered from the Gram statistics; its SSE is ``yty - c·rhs``.
+    """
+    idx = jnp.asarray(tuples)
+    n = idx.shape[1]
+    cols = [idx[:, p] for p in range(n)]
+    dtype = stats.gram.dtype
+    if np.dtype(dtype).itemsize < 4:
+        # sub-fp32 Gram stats (bf16 precision mode): bf16 is a
+        # storage/matmul format, solves run in fp32
+        dtype = jnp.float32
+    total = jnp.zeros((idx.shape[0],), dtype)
+    for t in range(stats.n_tasks):
+        g = stats.gram[t].astype(dtype)
+        fs = stats.fsum[t].astype(dtype)
+        b = stats.b[t].astype(dtype)
+        a = [[None] * (n + 1) for _ in range(n + 1)]
+        for p in range(n):
+            for q in range(p, n):
+                a[p][q] = a[q][p] = g[cols[p], cols[q]]
+            a[p][n] = a[n][p] = fs[cols[p]]
+        a[n][n] = stats.n[t].astype(dtype)
+        for p in range(n + 1):
+            a[p][p] = a[p][p] + _JITTER
+        rhs = [b[c] for c in cols] + [stats.ysum[t].astype(dtype)]
+        sse = _spd_sse(a, rhs, stats.yty[t].astype(dtype))
+        total = total + jnp.where(jnp.isfinite(sse), jnp.maximum(sse, 0.0),
+                                  jnp.inf)
+    return total
 
 
 def coefficients_for(
@@ -146,7 +192,13 @@ def coefficients_for(
         c = jax.scipy.linalg.solve(a, rhs, assume_a="pos")
         coefs.append(np.asarray(c[:k]))
         intercepts.append(float(c[k]))
-    return np.stack(coefs), np.asarray(intercepts)
+    coefs, intercepts = np.stack(coefs), np.asarray(intercepts)
+    if stats.xmean is not None:
+        # the statistics are centered: map the intercept back to raw data
+        sel = np.asarray(idx)
+        intercepts = intercepts + stats.ymean - np.einsum(
+            "tn,tn->t", coefs.astype(np.float64), stats.xmean[:, sel])
+    return coefs, intercepts
 
 
 # ---------------------------------------------------------------------------
@@ -345,15 +397,6 @@ def l0_search(
         dtype = engine.backend.compute_dtype
     n_dim, n_keep, block = int(n_dim), int(n_keep), int(block)
     m = int(np.asarray(x).shape[0])
-    if not engine.backend.l0_ranking_exact(method, n_dim, n_keep,
-                                           layout.n_tasks, m, problem=kind):
-        warnings.warn(
-            f"n_keep={n_keep} exceeds the backend's exact-rescore window "
-            f"(rescore_k={getattr(engine.backend, 'rescore_k', None)}); "
-            f"top-k entries beyond it rank on fp32 pre-pass SSEs — raise "
-            f"rescore_k on the backend",
-            RuntimeWarning, stacklevel=2,
-        )
     if prob is None:
         prob = engine.prepare_l0(x, y, layout, method=method, dtype=dtype,
                                  problem=kind)

@@ -136,13 +136,27 @@ def scores_from_reductions(
     return jnp.where(jnp.isfinite(score), score, -jnp.inf)
 
 
+def screen_reductions(
+    values: jnp.ndarray,      # (B, S)
+    membership: jnp.ndarray,  # (T, S)
+    y_tilde: jnp.ndarray,     # (R*T, S)
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """The screen's three matmuls ``(sums, sumsq, dots)``.
+
+    Pinned to ``HIGHEST``: on a TPU an fp32 dot otherwise runs as bf16
+    passes, whose ~1e-3 relative error reorders near-tied SIS scores.
+    """
+    hi = jax.lax.Precision.HIGHEST
+    return (jnp.matmul(values, membership.T, precision=hi),
+            jnp.matmul(values * values, membership.T, precision=hi),
+            jnp.matmul(values, y_tilde.T, precision=hi))
+
+
 def score_block(values: jnp.ndarray, ctx: ScoreContext) -> jnp.ndarray:
     """Pure-jnp scoring of a (B, s_pad) value block (oracle path)."""
     m = jnp.asarray(ctx.membership, values.dtype)
     yt = jnp.asarray(ctx.y_tilde, values.dtype)
-    sums = values @ m.T
-    sumsq = (values * values) @ m.T
-    dots = values @ yt.T
+    sums, sumsq, dots = screen_reductions(values, m, yt)
     return scores_from_reductions(
         sums, sumsq, dots, jnp.asarray(ctx.counts, values.dtype), ctx.n_residuals
     )

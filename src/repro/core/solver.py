@@ -281,6 +281,9 @@ class SissoSolver:
         fault_stats = getattr(self.engine.backend, "fault_stats", None)
         if fault_stats is not None:
             stats["resilience"] = dict(fault_stats)
+        # ℓ0 blocks per (tuple width, scoring path) — what actually ran
+        stats["l0_paths"] = {w: dict(p) for w, p in getattr(
+            self.engine.backend, "l0_paths", {}).items()}
         return SissoFit(models_by_dim=models_by_dim, fspace=fspace,
                         timings=timings, problem=problem.kind, stats=stats)
 

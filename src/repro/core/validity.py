@@ -86,15 +86,28 @@ def value_rules_from_moments(
     """Canonical rule from per-task reductions (fused-kernel epilogue form).
 
     The whole-sample variance is recovered from the per-task first/second
-    moments: ``var = (sum_t sumsq_t - (sum_t sums_t)^2 / N) / N``.
+    moments (:func:`variance_ok_from_moments`).
     """
-    n = counts.sum()
-    total = sums.sum(axis=-1)
-    ss = jnp.maximum(sumsq.sum(axis=-1) - total * total / n, 0.0)
-    var = ss / n
     return (
         finite
         & (max_abs <= u_bound)
         & (max_abs >= l_bound)
-        & (var > MIN_STD * MIN_STD)
+        & variance_ok_from_moments(sums, sumsq, counts)
     )
+
+
+def variance_ok_from_moments(
+    sums: jnp.ndarray,    # per-task sums over real samples, tasks on ``axis``
+    sumsq: jnp.ndarray,   # per-task sums of squares, same layout
+    counts: jnp.ndarray,  # true samples per task (any shape)
+    axis: int = -1,
+    keepdims: bool = False,
+) -> jnp.ndarray:
+    """The rule's variance clause: ``var = (sum_t sumsq_t - (sum_t
+    sums_t)^2 / N) / N > MIN_STD^2``, reducing the task ``axis``."""
+    n = counts.sum()
+    total = sums.sum(axis=axis, keepdims=keepdims)
+    ss = jnp.maximum(
+        sumsq.sum(axis=axis, keepdims=keepdims) - total * total / n, 0.0
+    )
+    return ss / n > MIN_STD * MIN_STD

@@ -378,12 +378,6 @@ class DebugBackend(Backend):
         # re-checked at the l0_topk/ReducedBlock boundary above)
         return self._inner.l0_device_reducer(prob, width, k_local)
 
-    def l0_ranking_exact(self, method, n_dim, n_keep, n_tasks, m,
-                         problem="regression"):
-        return self._inner.l0_ranking_exact(
-            method, n_dim, n_keep, n_tasks, m, problem=problem
-        )
-
     # -- prediction ----------------------------------------------------
     def eval_program(self, program, x):
         out = self._inner.eval_program(program, x)

@@ -27,12 +27,17 @@ from __future__ import annotations
 
 import abc
 import dataclasses
+import threading
 from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
 
 from ..core.sis import ReducedBlock, ScoreContext, TaskLayout
 from ..core.l0 import GramStats
+
+
+#: guards :meth:`Backend.record_l0_path` (prefetch threads score blocks)
+_PATH_LOCK = threading.Lock()
 
 
 @dataclasses.dataclass
@@ -256,18 +261,22 @@ class Backend(abc.ABC):
         Regression: total SSE of the per-task LSQ fits; classification:
         domain-overlap count + tie term (core/problem.py)."""
 
-    def l0_ranking_exact(self, method: str, n_dim: int, n_keep: int,
-                         n_tasks: int, m: int,
-                         problem: str = "regression") -> bool:
-        """Would a top-``n_keep`` merged from :meth:`l0_scores` blocks rank
-        on exact fp64 SSEs for this sweep?
+    def rescore_window(self, n_keep: int) -> int:
+        """Prescreened ℓ0 survivors per block that are rescored exactly
+        for a top-``n_keep`` (backends whose ℓ0 kernel is an fp32 prescreen
+        and the distribution wrapper composed over them)."""
+        return 2 * int(n_keep)
 
-        True here (every base implementation is fp64 end-to-end); backends
-        with a two-phase fp32 pre-pass override this with their own
-        dispatch conditions so the warning logic in ``core/l0.py`` has a
-        single owner — the backend that actually makes the choice.
-        """
-        return True
+    def record_l0_path(self, width: int, path: str) -> None:
+        """Count one ℓ0 block of tuple ``width`` scored by ``path``.
+
+        The solver reports the counts as ``SissoFit.stats["l0_paths"]``
+        (``{width: {path: blocks}}``), so a caller sees which path each
+        width took rather than predicting it."""
+        with _PATH_LOCK:
+            paths = self.__dict__.setdefault("l0_paths", {})
+            by_path = paths.setdefault(int(width), {})
+            by_path[path] = by_path.get(path, 0) + 1
 
     # -- prediction: compiled descriptor programs ----------------------
     def eval_program(self, program, x: np.ndarray) -> np.ndarray:

@@ -20,7 +20,9 @@ from ..core.operators import apply_op
 from ..core.problem import (
     ClassStats, overlap_scores_ops, score_tuples_overlap,
 )
-from ..core.sis import ScoreContext, scores_from_reductions
+from ..core.sis import (
+    ScoreContext, scores_from_reductions, screen_reductions,
+)
 from ..core.validity import value_rules_jnp
 from .base import Backend, L0Problem
 
@@ -33,9 +35,7 @@ def _eval_jit(op_id, a, b, l_bound, u_bound):
 
 @functools.partial(jax.jit, static_argnames=("n_residuals",))
 def _score_jit(values, membership, y_tilde, counts, n_residuals):
-    sums = values @ membership.T
-    sumsq = (values * values) @ membership.T
-    dots = values @ y_tilde.T
+    sums, sumsq, dots = screen_reductions(values, membership, y_tilde)
     return scores_from_reductions(sums, sumsq, dots, counts, n_residuals)
 
 

@@ -6,36 +6,39 @@
   reduced top-k epilogue + device merge return O(k) winners — full score
   vectors never exist, in HBM or on the host.
 * ℓ0 pairs — ``kernels/ops.py:l0_score_pairs``: closed-form SSE gathered
-  from Gram statistics (the tile kernel's math, XLA-gather form, fp64).
+  from Gram statistics (the tile kernel's math, XLA-gather form, in the
+  compute dtype).
 * ℓ0 widths ≥ 3 — ``kernels/l0_gather.py``: blocked Gram-gather kernel
   over VMEM-resident Gram statistics (one-hot MXU gathers + unrolled
-  closed-form solves), **two-phase**: the fp32 kernel pre-screens (with a
-  reduced epilogue on the ``n_keep`` path), then the surviving candidates
-  are re-scored from fp64 Gram statistics so downstream top-k rankings
-  match ``reference`` bit-for-bit.
+  elimination), **two-phase and certified**: the fp32 kernel bounds every
+  tuple's SSE from below (with a reduced epilogue on the ``n_keep``
+  path), the lowest bounds are rescored from fp64 Gram statistics, and
+  the block's top-k is exact whenever its n_keep-th rescored SSE lies
+  below the lowest bound left out — otherwise the whole block is
+  rescored in fp64.  The blocks each path settled are counted in
+  ``l0_paths`` (``Backend.record_l0_path``).
 
 Compute dtype policy (``set_precision``):
 
 =============  ======================  =================================
-precision      SIS kernel operands     ℓ0 gather pre-screen
+precision      SIS kernel operands     ℓ0 gather prescreen
 =============  ======================  =================================
 fp64 (default) fp32 (historical pin)   fp32 pack
 fp32           fp32                    fp32 pack
 bf16           bf16 (fp32 accumulate)  fp32 pack — see below
 =============  ======================  =================================
 
-The ℓ0 pre-screen stays fp32 even under bf16 precision: the gathered SSE
-is a small difference of large Gram terms, and quantizing the Gram matrix
-to 8 mantissa bits makes the cancellation error O(1) relative — measured
-99th-pct relative error ≈ 1 vs ≈ 2e-2 for fp32 — which would void the
-containment argument the two-phase rescore rests on.  bf16 belongs where
-the paper puts it: bulk child-value generation + correlation matmuls,
-where errors stay relative and the fp64 rescore pins final rankings.
+The ℓ0 pack holds fp32 entries even under bf16 precision: the kernel's
+error bound scales with the pack's rounding, and a bf16 pack would widen
+it 2^15-fold, sending near-exact fits to the fp64 fallback on most
+blocks.  bf16 belongs where the paper puts it: bulk child-value
+generation + correlation matmuls, where errors stay relative and the
+fp64 rescore pins final rankings.
 
 Everything else (width-1 tuples, QR method, classification) inherits the
 jnp implementation — the kernels accelerate, the semantics stay canonical.
-On CPU containers the kernels run with ``interpret=True`` (same code path,
-same numerics); on TPU they lower to Mosaic.
+Off a TPU the kernels run with ``interpret=True``; on a TPU they compile
+with Mosaic (tests/test_tpu_compile.py compiles them for a described v5e).
 """
 from __future__ import annotations
 
@@ -47,7 +50,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.l0 import compute_gram_stats, score_tuples_gram
-from ..core.sis import ReducedBlock, ScoreContext, scores_from_reductions
+from ..core.sis import (
+    ReducedBlock, ScoreContext, scores_from_reductions, screen_reductions,
+)
 from ..kernels import autotune
 from ..kernels import ops as kops
 from .base import L0Problem
@@ -61,9 +66,7 @@ def _sis_topk_jit(values, membership, y_tilde, counts, mask, n_residuals, k):
     Same score math as the jnp full-vector path, so the winners it returns
     are the ones a host stable sort of that vector would pick (lax.top_k
     ties resolve to the lowest index, matching stable order)."""
-    sums = values @ membership.T
-    sumsq = (values * values) @ membership.T
-    dots = values @ y_tilde.T
+    sums, sumsq, dots = screen_reductions(values, membership, y_tilde)
     scores = scores_from_reductions(sums, sumsq, dots, counts, n_residuals)
     scores = jnp.where(mask, scores, -jnp.inf)
     vals, idx = jax.lax.top_k(scores, k)
@@ -208,60 +211,41 @@ class PallasBackend(JnpBackend):
 
     # -- ℓ0 --------------------------------------------------------------
 
-    def l0_ranking_exact(self, method, n_dim, n_keep, n_tasks, m,
-                         problem="regression"):
-        """Mirrors the ℓ0 dispatch: only the width ≥ 3 regression gram
-        path within the VMEM budget runs the fp32 pre-pass; its exactness
-        windows are ``rescore_k`` (full-vector / merge) and ``block_t``
-        (per-tile reduced epilogue)."""
-        if problem not in self.kernel_problems:
-            return True  # delegated problems score on the exact jnp path
-        if method != "gram" or n_dim < 3 or n_dim not in self.l0_widths:
-            return True  # exact fp64 paths (pairs, jnp delegation, QR)
-        if kops.gram_pack_nbytes(n_tasks, m) > kops.GRAM_VMEM_BUDGET:
-            return True  # falls back to the exact jnp gram path
-        # require headroom: near n_keep == rescore_k, a non-rescored fp32
-        # SSE can still slip into the final top-k when rescoring raises
-        # borderline fp64 values past it; the reduced path additionally
-        # needs the per-tile window to cover the same margin
-        return 2 * n_keep <= self.rescore_k and 2 * n_keep <= self.block_t
+    def rescore_window(self, n_keep: int) -> int:
+        """Prescreened tuples per block rescored in fp64: ``rescore_k``,
+        grown to twice a caller's ``n_keep``."""
+        return max(self.rescore_k, 2 * int(n_keep))
+
+    def _fp64_stats(self, prob: L0Problem):
+        """fp64 centered Gram stats, built once per problem from the fp64
+        master ``x``/``y``: the source of the kernel pack and of every
+        exact rescore, whatever the compute dtype of ``prob.stats``."""
+        with self._l0_cache_lock:  # prefetch workers race the first fill
+            stats = prob.cache.get("l0_fp64_stats")
+            if stats is None:
+                stats = prob.stats
+                if jnp.dtype(stats.gram.dtype) != jnp.float64:
+                    stats = compute_gram_stats(prob.x, prob.y, prob.layout,
+                                               jnp.float64)
+                prob.cache["l0_fp64_stats"] = stats
+        return stats
 
     def _gram_pack(self, prob: L0Problem) -> dict:
-        """fp32 kernel pack, built from ≥fp32 Gram statistics.
-
-        Under bf16 precision ``prob.stats`` is bf16 (compute dtype); the
-        pack is rebuilt from the fp64 master copies instead, because a
-        bf16-quantized Gram matrix destroys the SSE cancellation (module
-        docstring) no matter what dtype the kernel runs in.
-        """
-        with self._l0_cache_lock:  # prefetch workers race the first fill
+        """fp32 kernel pack, rounded from the fp64 Gram statistics (the
+        kernel's error bound counts one fp32 rounding per entry)."""
+        stats = self._fp64_stats(prob)
+        with self._l0_cache_lock:
             pack = prob.cache.get("gram_pack")
             if pack is None:
-                stats = prob.stats
-                if jnp.dtype(stats.gram.dtype).itemsize < 4:
-                    stats = compute_gram_stats(
-                        jnp.asarray(prob.x), jnp.asarray(prob.y),
-                        prob.layout, jnp.float32,
-                    )
                 pack = prob.cache["gram_pack"] = kops.pack_gram(stats)
         return pack
 
     def _exact_rescore(self, prob: L0Problem, tuples_dev) -> np.ndarray:
-        """fp64 SSEs for O(k) candidate tuples, from true-fp64 Gram stats.
-
-        ``prob.stats`` is compute-dtype; the rescore must not inherit its
-        rounding, so the stats are rebuilt once per problem from the fp64
-        master ``x``/``y`` (cached, jitted).
-        """
+        """fp64 SSEs of candidate tuples (jitted, cached per problem)."""
+        stats = self._fp64_stats(prob)
         with self._l0_cache_lock:
             fn = prob.cache.get("l0_fp64_rescore")
             if fn is None:
-                stats = prob.stats
-                if jnp.dtype(stats.gram.dtype) != jnp.float64:
-                    stats = compute_gram_stats(
-                        jnp.asarray(prob.x), jnp.asarray(prob.y),
-                        prob.layout, jnp.float64,
-                    )
                 fn = jax.jit(functools.partial(score_tuples_gram, stats))
                 prob.cache["l0_fp64_rescore"] = fn
         return np.asarray(fn(tuples_dev), np.float64)
@@ -277,48 +261,62 @@ class PallasBackend(JnpBackend):
         width = int(tuples.shape[1])
         if len(tuples) == 0 or prob.problem not in self.kernel_problems \
                 or prob.method != "gram" or width not in self.l0_widths:
+            self.record_l0_path(width, "jnp")
             return super().l0_scores(prob, tuples)
         if width == 2:
+            self.record_l0_path(width, "closed-form pairs")
             return np.asarray(
                 kops.l0_score_pairs(prob.stats, jnp.asarray(tuples, jnp.int32))
             )
         return self._l0_scores_gather(prob, tuples)
 
     def _l0_scores_gather(self, prob: L0Problem, tuples) -> np.ndarray:
-        """Widths ≥ 3: fp32 Gram-gather kernel + exact fp64 rescore.
+        """Widths ≥ 3: Gram-gather kernel bounds + exact fp64 rescore.
 
-        Phase 1 scores the whole block on device; phase 2 re-scores the
-        block's best ``rescore_k`` tuples from the fp64 Gram statistics and
-        splices the exact values in.  A caller merging a top-k with
-        2k ≤ rescore_k (the :meth:`l0_ranking_exact` gate) ranks on exact
-        fp64 SSEs: the fp32 pass only has to keep true winners inside the
-        rescore set, a ~50× margin at the defaults.
+        Phase 1 bounds every tuple's SSE from below on device; phase 2
+        rescores the ``rescore_k`` lowest bounds from fp64 Gram statistics
+        and splices the exact values in.  Every tuple left out has an SSE
+        at least ``floor``, the lowest bound left out; when the best half
+        of the rescored tuples all lie below it, the vector's top
+        ``rescore_k // 2`` are exact and in exact order.  Otherwise (a
+        near-exact fit with more near-ties than the window) the whole
+        block is rescored in fp64.
         """
-        if not self._gather_eligible(prob, int(tuples.shape[1])):
+        width = int(tuples.shape[1])
+        if not self._gather_eligible(prob, width):
             # Gram stats would not fit in VMEM (huge subspace) — use the
             # generic device path; checked arithmetically so the fp32 pack
             # is never even allocated.
+            self.record_l0_path(width, "jnp")
             return super().l0_scores(prob, tuples)
         pack = self._gram_pack(prob)
         block_t = self._tuned_l0_block(pack, tuples)
-        sse32 = np.asarray(
+        out = np.asarray(
             kops.l0_score_tuples(pack, tuples, block_t=block_t,
-                                 interpret=self.interpret)
-        )
-        out = sse32.astype(np.float64)
+                                 interpret=self.interpret), np.float64)
         r = min(len(out), self.rescore_k)
-        # stable sort, not argpartition: equal fp32 SSEs must admit the
-        # same (lowest-index) candidates the reduced path's device merge
-        # keeps, or the two paths could rescore different tied borderline
-        # sets
-        cand = np.argsort(sse32, kind="stable")[:r] if r < len(out) \
-            else np.arange(len(out))
-        out[cand] = self._exact_rescore(prob, jnp.asarray(tuples)[cand])
+        # stable sort, not argpartition: equal bounds must admit the same
+        # (lowest-index) candidates the reduced path's device merge keeps
+        order = np.argsort(out, kind="stable")
+        cand = order[:r]
+        floor = out[order[r]] if r < len(out) else np.inf
+        exact = self._exact_rescore(prob, jnp.asarray(tuples)[cand])
+        if floor != np.inf and not np.sort(exact)[max(r // 2, 1) - 1] < floor:
+            self.record_l0_path(width, "exact fp64 (window not certified)")
+            return self._exact_rescore(prob, jnp.asarray(tuples))
+        self.record_l0_path(width, "Gram-gather kernel")
+        out[cand] = exact
         return out
 
     def l0_topk(self, prob: L0Problem, tuples, n_keep: int) -> ReducedBlock:
         """Reduced ℓ0 path: per-tile top-k epilogue → device merge → fp64
-        rescore of the O(k) survivors.  Full SSE vectors never exist."""
+        rescore of the O(k) survivors.  Full SSE vectors never exist.
+
+        The survivors are the lowest SSE bounds; the exclusion floor
+        bounds every tuple left out.  The result is exact when the
+        ``n_keep``-th best rescored SSE lies below that floor, and comes
+        from an fp64 rescore of the whole block when it does not.
+        """
         width = int(tuples.shape[1]) if len(tuples) else 0
         if len(tuples) == 0 or width < 3 \
                 or not self._gather_eligible(prob, width):
@@ -329,25 +327,26 @@ class PallasBackend(JnpBackend):
         tuples = jnp.asarray(tuples, jnp.int32)
         n_total = int(tuples.shape[0])
         block_t, epi = self._tuned_l0_topk_cfg(pack, tuples, n_keep)
-        # phase-1 survivors: same budget as the full-vector rescore set,
-        # bounded by what the per-tile windows can supply
-        r = min(n_total, max(self.rescore_k, int(n_keep)))
+        r = min(n_total, self.rescore_window(n_keep))
         k_epi = min(block_t, max(epi, 2 * int(n_keep), 1))
-        sse32, gidx = kops.l0_topk_tuples(
+        _, gidx, floor = kops.l0_topk_tuples(
             pack, tuples, n_keep=r, block_t=block_t,
             epilogue_k=k_epi, interpret=self.interpret,
         )
-        if len(gidx) == 0:
-            return ReducedBlock(indices=np.zeros((0,), np.int64),
-                                scores=np.zeros((0,)), n_source=n_total)
         # order candidates by global index before the stable rescore sort
         # so exact-SSE ties resolve to the lowest index — the order a
         # stable sort of the full vector produces
         gidx = np.sort(gidx)
-        exact = self._exact_rescore(prob, tuples[jnp.asarray(gidx)])
+        exact = self._exact_rescore(prob, tuples[jnp.asarray(gidx)]) \
+            if len(gidx) else np.zeros((0,))
         order = np.argsort(exact, kind="stable")[: int(n_keep)]
-        keep = np.isfinite(exact[order])
-        order = order[keep]
+        order = order[np.isfinite(exact[order])]
+        kth = exact[order[-1]] if len(order) == int(n_keep) else np.inf
+        if floor != np.inf and not kth < floor:
+            self.record_l0_path(width, "exact fp64 (window not certified)")
+            return ReducedBlock.reduce_host(
+                self._exact_rescore(prob, tuples), int(n_keep), largest=False)
+        self.record_l0_path(width, "Gram-gather kernel")
         return ReducedBlock(indices=gidx[order].astype(np.int64),
                             scores=exact[order], n_source=n_total)
 
@@ -355,10 +354,11 @@ class PallasBackend(JnpBackend):
         """Traceable per-shard reduced Gram-gather for engine/sharded.py.
 
         Returns a closure running the reduced-epilogue kernel on one
-        shard's tuple block and extracting its ``k_local`` best (fp32
-        prescreen — the wrapper rescores merged survivors via
-        :meth:`_exact_rescore`).  ``None`` when the gather kernel does not
-        cover this problem/width.
+        shard's tuple block and extracting its ``k_local`` lowest SSE
+        bounds and the floor under every bound it left out
+        (``kops.excluded_floor``) — the wrapper rescores merged survivors
+        via :meth:`_exact_rescore` and certifies them against the floor.
+        ``None`` when the gather kernel does not cover this problem/width.
         """
         if width < 3 or not self._gather_eligible(prob, width):
             return None
@@ -388,7 +388,8 @@ class PallasBackend(JnpBackend):
                 block_t=block_t, interpret=interpret,
             )
             neg, sel = jax.lax.top_k(-vals.reshape(-1), int(k_local))
-            return -neg, gidx.reshape(-1)[sel]
+            return (-neg, gidx.reshape(-1)[sel],
+                    kops.excluded_floor(vals, k_epi, block_t, -neg))
 
         return reducer, operands
 

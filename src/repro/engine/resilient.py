@@ -330,12 +330,6 @@ class ResilientExecution(Backend):
         # can't wrap a shard_map trace — pass through to the inner
         return self._inner.l0_device_reducer(prob, width, k_local)
 
-    def l0_ranking_exact(self, method, n_dim, n_keep, n_tasks, m,
-                         problem="regression"):
-        return self._inner.l0_ranking_exact(
-            method, n_dim, n_keep, n_tasks, m, problem=problem
-        )
-
     # -- prediction ----------------------------------------------------
     def eval_program(self, program, x):
         return self._dispatch(

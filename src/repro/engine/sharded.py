@@ -242,9 +242,10 @@ class ShardedExecution(Backend):
 
         Prefers the inner backend's device-side reduced epilogue
         (``Backend.l0_device_reducer``, e.g. the Pallas Gram-gather top-k
-        panels) with a 2×``n_keep`` prescreen margin — the kernel screen is
-        fp32, so the wrapper rescores merged survivors in fp64 before the
-        final ranking.  Falls back to the full-vector traceable scorers
+        panels), keeping as many survivors as the inner backend rescores
+        per block (``Backend.rescore_window``) — the kernel screen is an
+        fp32 bound, so the wrapper rescores merged survivors in fp64 before
+        the final ranking.  Falls back to the full-vector traceable scorers
         (overlap / Gram closed form / QR) when the inner backend has none.
         Returns ``(fn, operands, prescreen, k_merge)``.
         """
@@ -252,11 +253,12 @@ class ShardedExecution(Backend):
         with self._cache_lock:
             entry = prob.cache.get(key)
             if entry is None:
-                k_local = min(2 * int(n_keep), b_shard)
+                survivors = self.inner.rescore_window(n_keep)
+                k_local = min(survivors, b_shard)
                 dev = self.inner.l0_device_reducer(prob, width, k_local)
                 if dev is not None:
                     reducer, operands = dev
-                    k_merge = min(2 * int(n_keep), self._nd * k_local)
+                    k_merge = min(survivors, self._nd * k_local)
                     fn = make_l0_topk_reduced_fn(
                         self.mesh, reducer, k_local, k_merge, len(operands))
                     entry = prob.cache[key] = (fn, operands, True, k_merge)
@@ -296,19 +298,32 @@ class ShardedExecution(Backend):
         valid[:b] = True
         fn, operands, prescreen, _ = self._l0_reducer(
             prob, width, int(n_keep), bp // self._nd)
-        sses, idx = fn(tuples, jnp.asarray(valid), *operands)
-        sses = np.asarray(sses, np.float64)
-        idx = np.asarray(idx)
+        out = fn(tuples, jnp.asarray(valid), *operands)
+        sses, idx = np.asarray(out[0], np.float64), np.asarray(out[1])
         keep = np.isfinite(sses)
         sses, idx = sses[keep], idx[keep]
-        if prescreen and len(idx):
-            # the device screen is fp32; rescore the O(k) survivors in fp64
-            # and re-rank.  Candidates sort by global index first so exact-
-            # SSE ties resolve to the lowest index (stable-merge semantics).
+        if not prescreen:
+            self.record_l0_path(width, "sharded exact scorer")
+        elif len(idx):
+            # the device screen is an fp32 bound; rescore the O(k)
+            # survivors in fp64 and re-rank.  Candidates sort by global
+            # index first so exact-SSE ties resolve to the lowest index
+            # (stable-merge semantics).
             gidx = np.sort(np.unique(idx))
             exact = self.inner._exact_rescore(prob, tuples[jnp.asarray(gidx)])
             order = np.argsort(exact, kind="stable")[: int(n_keep)]
+            order = order[np.isfinite(exact[order])]
             sses, idx = exact[order], gidx[order]
+        if prescreen:
+            # certified only when every tuple left out bounds above the
+            # n_keep-th rescored SSE (PallasBackend.l0_topk); otherwise
+            # the inner backend settles this block on one device
+            floor = float(out[2])
+            kth = sses[-1] if len(sses) == int(n_keep) else np.inf
+            if floor != np.inf and not kth < floor:
+                self.record_l0_path(width, "one-device fallback")
+                return self.inner.l0_topk(prob, tuples[:b], n_keep)
+            self.record_l0_path(width, "sharded Gram-gather kernel")
         return ReducedBlock(
             indices=idx.astype(np.int64), scores=sses, n_source=b
         )

@@ -1,4 +1,9 @@
-"""Pallas TPU kernels for the SISSO hot spots (validated in interpret mode).
+"""Pallas TPU kernels for the SISSO hot spots.
+
+fused_sis.py and l0_gather.py (with topk.py's in-kernel epilogue) compile
+for a TPU v5e: tests/test_tpu_compile.py compiles them for a described v5e,
+and chip_smoke.py runs them on the chip.  l0_tile.py has only run in
+interpret mode.  Off a TPU every kernel runs with ``interpret=True``.
 
 fused_sis.py — P1+P2+P3: generate candidate values, validate, project against
               residuals entirely in VMEM (never materializes the last rung).

@@ -12,7 +12,8 @@ any n ≥ 3 works; VMEM, not the kernel, is the practical ceiling.
 
 Per grid step (one tile of ``block_t`` tuples):
 
-    VMEM-resident:   G (T, m_pad, m_pad), s/b (T, m_pad), scalars (T, 8)
+    VMEM-resident:   G (P, T, m_pad, m_pad), s/b (P, T, m_pad) bf16
+                     planes, scalars (T, 8) fp32
     HBM → VMEM:      tuple tile (n, block_t) int32  — device-enumerated
                      by kernels/unrank.py, so no host traffic at all
     compute:         one-hot(idx_p)                 VPU  (iota compare)
@@ -20,7 +21,7 @@ Per grid step (one tile of ``block_t`` tuples):
                      (n+1)×(n+1) solve + SSE        VPU  (unrolled
                                                     Gaussian elimination,
                                                     ref.eliminate_spd_sse)
-    VMEM → HBM:      per-tuple SSE (1, block_t) fp32   [full variant]
+    VMEM → HBM:      per-tuple SSE bound (1, block_t)  [full variant]
                      top-k (vals, idx) (1, k_pad)      [reduced variant]
 
 Gathering by one-hot matmul instead of dynamic indexing keeps the kernel
@@ -28,12 +29,19 @@ Mosaic-lowerable (TPU has no fast arbitrary gather) and turns the hot loop
 into n dense (m_pad × m_pad)·(m_pad × block_t) matmuls per task — MXU work
 proportional to tuples scored, independent of sample count.
 
-Compute dtype: the Gram pack (G, s, b) may arrive in bf16 — one-hots are
-built in the pack's dtype so the gather matmuls run native on the MXU, with
-fp32 accumulation via ``preferred_element_type``; the elimination and SSE
-stay fp32 (scalars are always fp32).  The backend runs the existing
-two-phase exact rescore (top candidates re-scored from fp64 Gram stats) so
-final rankings match ``reference`` bit-for-bit on the parity suite.
+Compute dtype: the Gram pack (G, s, b) is stored as bf16 *planes* that
+sum to each entry (``ops.pack_gram``: three planes carry an fp32 value
+exactly, one is a bf16 rounding).  One-hots are bf16, so every gather is a
+few native single-pass MXU matmuls that select entries bit for bit
+(``ref.gather_planes``).  The elimination stays fp32 (scalars are always
+fp32).
+
+Output: per tuple, a certified *lower bound* on its SSE (the fp32 SSE less
+its backward-error bound, ``ref.eliminate_spd_sse``).  The backend rescores
+the tuples with the lowest bounds from fp64 Gram stats, and a tuple left
+out has an exact SSE at least its bound — so the backend can prove that no
+tuple it skipped belongs in the top k, and rescore the whole block in fp64
+when it cannot.
 """
 from __future__ import annotations
 
@@ -42,38 +50,48 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .ref import eliminate_spd_sse, gathered_system
+from .ref import (
+    eliminate_spd_sse, entry_error, gather_planes, gathered_system,
+)
+from .spec import block_spec, x32_kernel
 from .topk import block_topk
+
+#: scoped VMEM the gather kernels may use.  The resident Gram planes are
+#: double-buffered, and with the (m_pad, block_t) one-hot and gather
+#: panels a pack at ``ops.GRAM_VMEM_BUDGET`` needs ~21 MB, over the 16 MiB
+#: default; a v5e core has 128 MiB.
+VMEM_LIMIT_BYTES = 48 * 1024 * 1024
 
 
 def _tile_sse(tup, gram, fsum, bvec, scal, *, n: int, n_tasks: int):
-    """(1, block_t) fp32 total SSE for one tile of width-n tuples."""
-    m_pad, block_t = gram.shape[1], tup.shape[1]
+    """(1, block_t) fp32 total-SSE lower bounds for one tile of width-n
+    tuples (``ref.eliminate_spd_sse``)."""
+    m_pad, block_t = gram.shape[2], tup.shape[1]
     iota = jax.lax.broadcasted_iota(jnp.int32, (m_pad, block_t), 0)
     onehots = [
         (iota == tup[p : p + 1, :]).astype(gram.dtype) for p in range(n)
     ]
+    err = entry_error(n + 1, gram.shape[0])
     total = jnp.zeros((1, block_t), jnp.float32)
     for t in range(n_tasks):  # static unroll over tasks
-        g = gram[t]
-        g_cols = [
-            jnp.dot(g, oh, preferred_element_type=jnp.float32)
-            for oh in onehots
-        ]
         a, rhs = gathered_system(
-            g_cols, onehots, fsum[t : t + 1, :], bvec[t : t + 1, :],
+            [gather_planes(gram[:, t], oh) for oh in onehots], onehots,
+            [gather_planes(fsum[:, t : t + 1], oh) for oh in onehots],
+            [gather_planes(bvec[:, t : t + 1], oh) for oh in onehots],
             scal[t, 0], scal[t, 1],
         )
-        total = total + eliminate_spd_sse(a, rhs, scal[t, 2])
+        total = total + eliminate_spd_sse(a, rhs, scal[t, 2], err)
     return total
 
 
+@x32_kernel
 def _kernel(
     tup_ref,    # (n, block_t) int32 tuple tile (transposed: lanes = tuples)
-    gram_ref,   # (T, m_pad, m_pad) compute dtype
-    fsum_ref,   # (T, m_pad)
-    b_ref,      # (T, m_pad)
+    gram_ref,   # (P, T, m_pad, m_pad) bf16 planes
+    fsum_ref,   # (P, T, m_pad)
+    b_ref,      # (P, T, m_pad)
     scal_ref,   # (T, 8) fp32: [n_samples, ysum, yty, 0, ...]
     sse_out,    # (1, block_t)
     *, n: int, n_tasks: int, m_pad: int, block_t: int,
@@ -84,6 +102,7 @@ def _kernel(
     )
 
 
+@x32_kernel
 def _kernel_topk(
     tup_ref, gram_ref, fsum_ref, b_ref, scal_ref, nv_ref, val_ref, idx_ref,
     *, n: int, n_tasks: int, m_pad: int, block_t: int, k: int, k_pad: int,
@@ -107,16 +126,16 @@ def _kernel_topk(
 )
 def l0_gather_tuples_pallas(
     tuples_t: jnp.ndarray,   # (n, b_pad) int32, b_pad % block_t == 0
-    gram: jnp.ndarray,       # (T, m_pad, m_pad), m_pad % 128 == 0
-    fsum: jnp.ndarray,       # (T, m_pad)
-    bvec: jnp.ndarray,       # (T, m_pad)
+    gram: jnp.ndarray,       # (P, T, m_pad, m_pad) bf16, m_pad % 128 == 0
+    fsum: jnp.ndarray,       # (P, T, m_pad)
+    bvec: jnp.ndarray,       # (P, T, m_pad)
     scal: jnp.ndarray,       # (T, 8) fp32
     n: int,
     block_t: int = 256,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Per-tuple total SSE (b_pad,) fp32 for a padded tuple block."""
-    t, m_pad, _ = gram.shape
+    planes, t, m_pad, _ = gram.shape
     b_pad = tuples_t.shape[1]
     assert b_pad % block_t == 0 and m_pad % 128 == 0
     ntiles = b_pad // block_t
@@ -127,14 +146,16 @@ def l0_gather_tuples_pallas(
         kern,
         grid=(ntiles,),
         in_specs=[
-            pl.BlockSpec((n, block_t), lambda i: (0, i)),
-            pl.BlockSpec((t, m_pad, m_pad), lambda i: (0, 0, 0)),
-            pl.BlockSpec((t, m_pad), lambda i: (0, 0)),
-            pl.BlockSpec((t, m_pad), lambda i: (0, 0)),
-            pl.BlockSpec((t, 8), lambda i: (0, 0)),
+            block_spec((n, block_t), lambda i: (0, i)),
+            block_spec((planes, t, m_pad, m_pad), lambda i: (0, 0, 0, 0)),
+            block_spec((planes, t, m_pad), lambda i: (0, 0, 0)),
+            block_spec((planes, t, m_pad), lambda i: (0, 0, 0)),
+            block_spec((t, 8), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_t), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((ntiles, block_t), jnp.float32),
+        out_specs=block_spec((1, block_t), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, b_pad), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(tuples_t, gram, fsum, bvec, scal)
     return sse.reshape(-1)
@@ -145,9 +166,9 @@ def l0_gather_tuples_pallas(
 )
 def l0_gather_topk_pallas(
     tuples_t: jnp.ndarray,   # (n, b_pad) int32, b_pad % block_t == 0
-    gram: jnp.ndarray,       # (T, m_pad, m_pad), m_pad % 128 == 0
-    fsum: jnp.ndarray,       # (T, m_pad)
-    bvec: jnp.ndarray,       # (T, m_pad)
+    gram: jnp.ndarray,       # (P, T, m_pad, m_pad) bf16, m_pad % 128 == 0
+    fsum: jnp.ndarray,       # (P, T, m_pad)
+    bvec: jnp.ndarray,       # (P, T, m_pad)
     scal: jnp.ndarray,       # (T, 8) fp32
     nv,                      # real tuple count (int or traced scalar)
     n: int,
@@ -160,7 +181,7 @@ def l0_gather_topk_pallas(
     int32)`` winner panels for :func:`..kernels.topk.merge_block_topk`
     (``largest=False``).  Padding tuples (tile position >= ``nv``) are +inf
     in-kernel and can never be selected."""
-    t, m_pad, _ = gram.shape
+    planes, t, m_pad, _ = gram.shape
     b_pad = tuples_t.shape[1]
     assert b_pad % block_t == 0 and m_pad % 128 == 0
     ntiles = b_pad // block_t
@@ -175,21 +196,24 @@ def l0_gather_topk_pallas(
         kern,
         grid=(ntiles,),
         in_specs=[
-            pl.BlockSpec((n, block_t), lambda i: (0, i)),
-            pl.BlockSpec((t, m_pad, m_pad), lambda i: (0, 0, 0)),
-            pl.BlockSpec((t, m_pad), lambda i: (0, 0)),
-            pl.BlockSpec((t, m_pad), lambda i: (0, 0)),
-            pl.BlockSpec((t, 8), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
+            block_spec((n, block_t), lambda i: (0, i)),
+            block_spec((planes, t, m_pad, m_pad), lambda i: (0, 0, 0, 0)),
+            block_spec((planes, t, m_pad), lambda i: (0, 0, 0)),
+            block_spec((planes, t, m_pad), lambda i: (0, 0, 0)),
+            block_spec((t, 8), lambda i: (0, 0)),
+            block_spec((1, 1), lambda i: (0, 0)),
         ],
+        # lane-major panels: tile i owns lanes [i*k_pad, (i+1)*k_pad)
         out_specs=(
-            pl.BlockSpec((1, k_pad), lambda i: (i, 0)),
-            pl.BlockSpec((1, k_pad), lambda i: (i, 0)),
+            block_spec((1, k_pad), lambda i: (0, i)),
+            block_spec((1, k_pad), lambda i: (0, i)),
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((ntiles, k_pad), jnp.float32),
-            jax.ShapeDtypeStruct((ntiles, k_pad), jnp.int32),
+            jax.ShapeDtypeStruct((1, ntiles * k_pad), jnp.float32),
+            jax.ShapeDtypeStruct((1, ntiles * k_pad), jnp.int32),
         ),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(tuples_t, gram, fsum, bvec, scal, nv_arr)
-    return vals, gidx
+    return vals.reshape(ntiles, k_pad), gidx.reshape(ntiles, k_pad)

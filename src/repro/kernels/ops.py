@@ -1,8 +1,9 @@
 """jit'd wrappers around the Pallas kernels (+ padding & layout policy).
 
-On CPU containers the kernels execute with ``interpret=True`` (Pallas runs
-the kernel body in Python/XLA) — same code path, same numerics; on TPU the
-same calls lower to Mosaic.
+Off a TPU the kernels run with ``interpret=True`` (the kernel body runs as
+XLA on the host); on a TPU the same calls compile with Mosaic.  Interpret
+mode accepts kernels the TPU compiler refuses, so only
+tests/test_tpu_compile.py and a chip run show that a kernel lowers.
 """
 from __future__ import annotations
 
@@ -33,6 +34,30 @@ def _pad_to(n: int, mult: int) -> int:
 # ---------------------------------------------------------------------------
 # fused generation + SIS
 # ---------------------------------------------------------------------------
+
+#: scoped VMEM the TPU compiler grants a kernel by default (16 MiB on v5e)
+_SCOPED_VMEM_BYTES = 16 * 1024 * 1024
+#: fp32 ``(block_b, s_pad)`` tiles the fused-SIS kernel keeps live: the two
+#: double-buffered child streams plus the generated values and their
+#: temporaries.  The v5e compiler asked for 25.7 MB at 256 × 2432, about
+#: 10.3 tiles; 12 leaves headroom.
+_FUSED_SIS_LIVE_TILES = 12
+
+
+def fused_sis_block_rows(s: int, block_b: int) -> int:
+    """Candidate rows per fused-SIS grid step for ``s`` samples.
+
+    ``block_b`` halved (never below one 128-lane output row) until the
+    kernel's live tiles fit the scoped VMEM limit: 256 rows at the thermal
+    width (s_pad = 256), 128 at the Kaggle width (s_pad = 2432).
+    """
+    s_pad = _pad_to(max(s, 128), 128)
+    rows = int(block_b)
+    while rows > 128 and \
+            rows * s_pad * 4 * _FUSED_SIS_LIVE_TILES > _SCOPED_VMEM_BYTES:
+        rows //= 2
+    return rows
+
 
 def _sis_operands(a, b, ctx, block_b, dtype):
     """Pad/cast the fused-SIS operand set to kernel layout in ``dtype``."""
@@ -68,6 +93,7 @@ def fused_gen_sis(
     interpret = _interpret_default() if interpret is None else interpret
     dtype = jnp.float32 if dtype is None else jnp.dtype(dtype)
     bsz = a.shape[0]
+    block_b = fused_sis_block_rows(a.shape[1], block_b)
     a_p, b_p, m_p, yt_p, cnt = _sis_operands(a, b, ctx, block_b, dtype)
 
     scores = fused_gen_sis_pallas(
@@ -103,6 +129,7 @@ def fused_gen_sis_topk(
     interpret = _interpret_default() if interpret is None else interpret
     dtype = jnp.float32 if dtype is None else jnp.dtype(dtype)
     bsz = a.shape[0]
+    block_b = fused_sis_block_rows(a.shape[1], block_b)
     a_p, b_p, m_p, yt_p, cnt = _sis_operands(a, b, ctx, block_b, dtype)
 
     # per-block window must cover n_keep, else a single block could hold
@@ -156,43 +183,68 @@ def l0_score_pairs(stats: GramStats, pairs: jnp.ndarray) -> jnp.ndarray:
 GRAM_VMEM_BUDGET = 8 * 1024 * 1024
 
 
-def gram_pack_nbytes(n_tasks: int, m: int, itemsize: int = 4) -> int:
-    """Bytes :func:`pack_gram` would occupy at the given element size —
+#: bf16 planes per packed Gram entry, by the precision of the gathered
+#: value: three planes carry an fp32 rounding exactly
+GRAM_PLANES = {"float32": 3, "bfloat16": 1}
+
+
+def gram_pack_nbytes(n_tasks: int, m: int, dtype=jnp.float32) -> int:
+    """Bytes :func:`pack_gram` would occupy at the given precision —
     computable *before* building the pack, so over-budget subspaces never
-    pay the allocation.  (The (T, 8) scalar array is always fp32; counting
-    it at ``itemsize`` keeps this a conservative-enough estimate.)"""
+    pay the allocation.  (The (T, 8) fp32 scalar array is counted at the
+    planes' size; a conservative-enough estimate.)"""
     m_pad = _pad_to(max(m, 128), 128)
-    return itemsize * n_tasks * (m_pad * m_pad + 2 * m_pad + 8)
+    planes = GRAM_PLANES[jnp.dtype(dtype).name]
+    return 2 * planes * n_tasks * (m_pad * m_pad + 2 * m_pad + 8)
+
+
+def split_planes(a, planes: int) -> np.ndarray:
+    """``(planes, *a.shape)`` bf16 array whose planes sum (in fp32, in
+    order) to ``a`` rounded to fp32 — exactly for three planes: each plane
+    takes the bf16 rounding of what the earlier planes left, and the
+    remainders are exact in fp32."""
+    rest = np.asarray(a, np.float32)
+    out = []
+    for _ in range(planes):
+        hi = rest.astype(jnp.bfloat16)
+        out.append(hi)
+        rest = rest - hi.astype(np.float32)
+    return np.stack(out)
 
 
 def pack_gram(stats: GramStats, dtype=jnp.float32) -> dict:
-    """Pad Gram statistics to lane-aligned arrays for the gather kernel.
+    """Pad Gram statistics to lane-aligned bf16 planes for the gather
+    kernel.
 
-    ``dtype`` is the kernel compute dtype for G/s/b (bf16 halves the VMEM
-    residency and runs the gather matmuls MXU-native); the scalar array
-    stays fp32 because the elimination epilogue is fp32.  Zero padding is
-    inert: tuples only ever index real features, and padded Gram
-    rows/columns are never touched by their one-hot gathers.
+    ``dtype`` is the precision of the gathered entries: fp32 packs three
+    planes per entry (an exact fp32 gather), bf16 one (a third of the VMEM
+    and MXU passes, at bf16 rounding — the kernel's error bound widens to
+    match).  The scalar array stays fp32.  Zero padding is inert: tuples
+    only ever index real features, and padded Gram rows/columns are never
+    touched by their one-hot gathers.
     """
     dtype = jnp.dtype(dtype)
+    planes = GRAM_PLANES[dtype.name]
     t = stats.n_tasks
     m = stats.m
     m_pad = _pad_to(max(m, 128), 128)
-    gram = np.zeros((t, m_pad, m_pad), np.float32)
-    fsum = np.zeros((t, m_pad), np.float32)
-    bvec = np.zeros((t, m_pad), np.float32)
+    gram = np.zeros((t, m_pad, m_pad), np.float64)
+    fsum = np.zeros((t, m_pad), np.float64)
+    bvec = np.zeros((t, m_pad), np.float64)
     scal = np.zeros((t, 8), np.float32)
-    gram[:, :m, :m] = np.asarray(stats.gram, np.float32)
-    fsum[:, :m] = np.asarray(stats.fsum, np.float32)
-    bvec[:, :m] = np.asarray(stats.b, np.float32)
+    gram[:, :m, :m] = np.asarray(stats.gram, np.float64)
+    fsum[:, :m] = np.asarray(stats.fsum, np.float64)
+    bvec[:, :m] = np.asarray(stats.b, np.float64)
     scal[:, 0] = np.asarray(stats.n, np.float32)
     scal[:, 1] = np.asarray(stats.ysum, np.float32)
     scal[:, 2] = np.asarray(stats.yty, np.float32)
     return {
-        "gram": jnp.asarray(gram, dtype), "fsum": jnp.asarray(fsum, dtype),
-        "bvec": jnp.asarray(bvec, dtype), "scal": jnp.asarray(scal),
+        "gram": jnp.asarray(split_planes(gram, planes)),
+        "fsum": jnp.asarray(split_planes(fsum, planes)),
+        "bvec": jnp.asarray(split_planes(bvec, planes)),
+        "scal": jnp.asarray(scal),
         "m": m, "m_pad": m_pad, "dtype": str(dtype),
-        "vmem_bytes": gram_pack_nbytes(t, m, dtype.itemsize),
+        "vmem_bytes": gram_pack_nbytes(t, m, dtype),
     }
 
 
@@ -207,7 +259,8 @@ def l0_score_tuples(
     block_t: int = 256,
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
-    """fp32 total SSE (B,) for width-n tuples via the Gram-gather kernel.
+    """fp32 total-SSE lower bounds (B,) for width-n tuples via the
+    Gram-gather kernel (each at most the tuple's exact SSE).
 
     Padding tuples are the benign (0, 1, …, n-1) combination, sliced off
     before returning.  The result stays on device so the caller can fuse
@@ -238,12 +291,15 @@ def l0_topk_tuples(
     epilogue_k: int = 64,
     interpret: Optional[bool] = None,
 ) -> Tuple[np.ndarray, np.ndarray]:
-    """Reduced-epilogue Gram-gather: the ``n_keep`` lowest-SSE tuples.
+    """Reduced-epilogue Gram-gather: the ``n_keep`` lowest SSE bounds.
 
     Per-tile top-k panels (window grown to cover ``n_keep``) merged on
     device; only the O(k) winners cross the host boundary.  Returns
-    ``(sses (k',) f64 ascending, indices (k',) i64)`` — indices are
-    positions into ``tuples``; padding tuples can never appear.
+    ``(bounds (k',) f64 ascending, indices (k',) i64, floor)`` — indices
+    are positions into ``tuples``; padding tuples can never appear.
+    ``floor`` is at most the bound of every tuple left out (a full tile's
+    last panel entry, the merge's last kept entry when it cut), +inf when
+    nothing was left out.
     """
     faults.check("kernel.l0")
     interpret = _interpret_default() if interpret is None else interpret
@@ -262,10 +318,28 @@ def l0_topk_tuples(
     )
     k_merge = min(int(n_keep), vals.shape[0] * k_epi, b)
     v, i = merge_block_topk(vals, gidx, k=k_merge, largest=False)
+    floor = float(excluded_floor(vals, k_epi, block_t, v))
     v = np.asarray(v, np.float64)
     i = np.asarray(i)
     keep = np.isfinite(v)
-    return v[keep], i[keep].astype(np.int64)
+    return v[keep], i[keep].astype(np.int64), floor
+
+
+def excluded_floor(panels: jnp.ndarray, k: int, block_t: int,
+                   merged: jnp.ndarray):
+    """Lower bound on the prescreen value of every tuple a reduced epilogue
+    left out (+inf when it left none out).
+
+    ``panels`` are the per-tile ``(ntiles, k_pad)`` winners (``k`` of each
+    ``block_t``-tuple tile, ascending, +inf sentinels) and ``merged`` the
+    ascending merge of them.  A tile with a full panel (finite k-th entry)
+    may have cut tuples at or above that entry, unless it keeps its whole
+    tile; a merge that kept fewer entries than the panels hold cut tuples
+    at or above its last kept entry.  Traceable.
+    """
+    floor = jnp.min(panels[:, k - 1]) if k < block_t else jnp.inf
+    cut = jnp.sum(jnp.isfinite(panels)) > jnp.sum(jnp.isfinite(merged))
+    return jnp.where(cut, jnp.minimum(floor, merged[-1]), floor)
 
 
 def _task_padded_layout(

@@ -96,8 +96,42 @@ def solve3_sse(a, b, c, d, e, f, r1, r2, r3, yty):
 # ℓ0 generic-width scoring, closed form (kernel: l0_gather.py)
 # ---------------------------------------------------------------------------
 
-def eliminate_spd_sse(a, rhs, yty, rel_jitter=1e-6, eps=1e-30):
-    """SSE after solving the k×k SPD system by unrolled Gaussian elimination.
+def plane_roundoff(planes: int) -> float:
+    """Relative rounding of a Gram-pack entry stored as ``planes`` bf16
+    planes (``ops.pack_gram``): three carry its fp32 rounding exactly."""
+    return max(2.0 ** -24, 2.0 ** -(8 * planes + 1))
+
+
+def entry_error(k: int, planes: int) -> float:
+    """Backward error per entry of a gathered k×k system, relative to
+    sqrt(M_ii·M_jj): storage rounding of the pack plus the fp32
+    elimination's γ_(k+1), with a 4x margin for the TPU's inexact
+    reciprocal."""
+    return plane_roundoff(planes) + 4.0 * (k + 2) * 2.0 ** -24
+
+
+def gather_planes(planes, onehot):
+    """fp32 ``Σ_i planes[i] @ onehot``: one-hot columns of a bf16-plane
+    pack.  Each MXU product is one bf16 entry times 1 and the planes add
+    up exactly, so the gather returns the packed value bit for bit —
+    which an fp32 matmul at the TPU's default precision (one bf16 pass)
+    would not, and one at ``HIGHEST`` costs six passes and VMEM for
+    splitting the resident Gram on every grid step."""
+    out = jnp.dot(planes[0], onehot, preferred_element_type=jnp.float32)
+    for i in range(1, planes.shape[0]):
+        out = out + jnp.dot(planes[i], onehot,
+                            preferred_element_type=jnp.float32)
+    return out
+
+
+#: least pivot, relative to its diagonal entry, of a system the bound
+#: trusts: below it the computed coefficients (which the bound scales
+#: with) may be far from the exact ones, and the tuple bounds at 0
+MIN_REL_PIVOT = 2.0 ** -10
+
+
+def eliminate_spd_sse(a, rhs, yty, entry_err, eps=1e-30):
+    """Certified lower bound on the SSE of a k×k SPD least-squares system.
 
     ``a`` is a k×k nested list and ``rhs`` a length-k list of mutually
     broadcastable arrays — each entry is one coefficient *vectorized over a
@@ -106,21 +140,28 @@ def eliminate_spd_sse(a, rhs, yty, rel_jitter=1e-6, eps=1e-30):
     in ``l0_widths``).  Shared by the Pallas gather kernel and its
     pure-jnp oracle.
 
-    A scale-relative diagonal jitter keeps fp32 elimination stable (the
-    absolute 1e-10 jitter of the fp64 path vanishes in fp32); degenerate
-    pivots or non-finite results map to +inf SSE, and the two-phase exact
-    rescore re-ranks anything that survives in fp64.
+    Gaussian elimination of the augmented SPD matrix M = [[A, r], [rᵀ,
+    yty]] leaves the SSE ``yty - rᵀA⁻¹r`` as its last pivot.  Elimination
+    without pivoting is backward stable on SPD matrices: the computed pivot
+    is the exact SSE of some M + ΔM with |ΔM_ij| ≤ e·sqrt(M_ii·M_jj),
+    e = ``entry_err`` (:func:`entry_error`), and such a ΔM moves the SSE by
+    at most e·(Σ_p |c_p|·sqrt(A_pp) + sqrt(yty))² for the solution c.  The
+    return value is the pivot minus twice that bound (the computed c stands
+    in for the exact one), floored at 0: the exact SSE is never below it,
+    so a prescreen that ranks by it can prove which tuples it may skip.  A
+    system the bound cannot vouch for — a pivot under ``MIN_REL_PIVOT`` of
+    its diagonal entry, or a non-finite result — bounds at 0 and is always
+    left to the exact rescore.
     """
     k = len(rhs)
+    diag = [a[p][p] for p in range(k)]
     a = [[a[i][j] for j in range(k)] for i in range(k)]
-    rhs0 = list(rhs)
     rhs = list(rhs)
-    for p in range(k):
-        a[p][p] = a[p][p] * (1.0 + rel_jitter)
+    sse = yty
     ok = True
     for p in range(k):
         piv = a[p][p]
-        good = jnp.abs(piv) > eps
+        good = piv > jnp.maximum(MIN_REL_PIVOT * diag[p], eps)
         ok = good & ok
         inv = jnp.where(good, 1.0, 0.0) / jnp.where(good, piv, 1.0)
         for r in range(p + 1, k):
@@ -128,27 +169,28 @@ def eliminate_spd_sse(a, rhs, yty, rel_jitter=1e-6, eps=1e-30):
             for c in range(p + 1, k):
                 a[r][c] = a[r][c] - f * a[p][c]
             rhs[r] = rhs[r] - f * rhs[p]
+        sse = sse - rhs[p] * inv * rhs[p]
     coef = [None] * k
     for p in range(k - 1, -1, -1):
         acc = rhs[p]
         for c in range(p + 1, k):
             acc = acc - a[p][c] * coef[c]
-        piv = a[p][p]
-        good = jnp.abs(piv) > eps
-        coef[p] = jnp.where(good, 1.0, 0.0) * acc / jnp.where(good, piv, 1.0)
-    sse = yty
+        coef[p] = acc * jnp.where(ok, 1.0, 0.0) / jnp.where(ok, a[p][p], 1.0)
+    spread = jnp.sqrt(jnp.maximum(yty, 0.0))
     for p in range(k):
-        sse = sse - coef[p] * rhs0[p]
-    return jnp.where(ok & jnp.isfinite(sse), jnp.maximum(sse, 0.0), jnp.inf)
+        spread = spread + jnp.abs(coef[p]) * jnp.sqrt(jnp.maximum(diag[p], 0.0))
+    bound = sse - 2.0 * entry_err * spread * spread
+    return jnp.where(ok & jnp.isfinite(bound), jnp.maximum(bound, 0.0), 0.0)
 
 
-def gathered_system(g_cols, onehots, fsum_row, b_row, count, ysum):
+def gathered_system(g_cols, onehots, s_vals, b_vals, count, ysum):
     """Assemble the (n+1)×(n+1) normal equations for a tile of tuples.
 
     ``g_cols[p] = G @ onehot_p`` is the one-hot-matmul gather of Gram
     columns (the MXU-friendly gather: G[:, idx_p] as an (m_pad, B) panel);
-    entries, feature sums and projections reduce out of it elementwise.
-    Returns (a, rhs) in the nested-list form ``eliminate_spd_sse`` takes.
+    entries reduce out of it elementwise.  ``s_vals[p]``/``b_vals[p]`` are
+    the gathered (1, B) feature sums and projections.  Returns (a, rhs) in
+    the nested-list form ``eliminate_spd_sse`` takes.
     """
     n = len(onehots)
     k = n + 1
@@ -159,40 +201,39 @@ def gathered_system(g_cols, onehots, fsum_row, b_row, count, ysum):
             e = jnp.sum(g_cols[p] * onehots[q], axis=0, keepdims=True)
             a[p][q] = e
             a[q][p] = e
-        sp = jnp.dot(fsum_row, onehots[p], preferred_element_type=jnp.float32)
-        a[p][n] = sp
-        a[n][p] = sp
-        rhs[p] = jnp.dot(b_row, onehots[p], preferred_element_type=jnp.float32)
+        a[p][n] = s_vals[p]
+        a[n][p] = s_vals[p]
+        rhs[p] = b_vals[p]
     a[n][n] = count
     rhs[n] = ysum
     return a, rhs
 
 
 def l0_gather_sse_ref(
-    gram: jnp.ndarray,   # (T, m_pad, m_pad) fp32 Gram matrices (zero-padded)
-    fsum: jnp.ndarray,   # (T, m_pad)
-    bvec: jnp.ndarray,   # (T, m_pad)
-    scal: jnp.ndarray,   # (T, 8): [n, ysum, yty, 0, ...]
+    gram: jnp.ndarray,   # (P, T, m_pad, m_pad) bf16 planes (zero-padded)
+    fsum: jnp.ndarray,   # (P, T, m_pad)
+    bvec: jnp.ndarray,   # (P, T, m_pad)
+    scal: jnp.ndarray,   # (T, 8) fp32: [n, ysum, yty, 0, ...]
     tuples: jnp.ndarray,  # (B, n) int32
 ) -> jnp.ndarray:
     """Pure-jnp oracle for the gather kernel: same one-hot gathers, same
-    elimination, whole batch at once.  Returns (B,) fp32 total SSE."""
-    m_pad = gram.shape[1]
+    elimination, whole batch at once.  Returns (B,) fp32 total SSE lower
+    bounds (:func:`eliminate_spd_sse`)."""
+    m_pad = gram.shape[2]
     n = tuples.shape[1]
     tup = tuples.T.astype(jnp.int32)                       # (n, B)
     iota = jax.lax.broadcasted_iota(jnp.int32, (m_pad, tup.shape[1]), 0)
-    onehots = [(iota == tup[p][None, :]).astype(jnp.float32) for p in range(n)]
+    onehots = [(iota == tup[p][None, :]).astype(gram.dtype) for p in range(n)]
+    err = entry_error(n + 1, gram.shape[0])
     total = jnp.zeros((1, tup.shape[1]), jnp.float32)
-    for t in range(gram.shape[0]):
-        g_cols = [
-            jnp.dot(gram[t], oh, preferred_element_type=jnp.float32)
-            for oh in onehots
-        ]
+    for t in range(gram.shape[1]):
         a, rhs = gathered_system(
-            g_cols, onehots, fsum[t][None, :], bvec[t][None, :],
+            [gather_planes(gram[:, t], oh) for oh in onehots], onehots,
+            [gather_planes(fsum[:, t:t + 1], oh) for oh in onehots],
+            [gather_planes(bvec[:, t:t + 1], oh) for oh in onehots],
             scal[t, 0], scal[t, 1],
         )
-        total = total + eliminate_spd_sse(a, rhs, scal[t, 2])
+        total = total + eliminate_spd_sse(a, rhs, scal[t, 2], err)
     return total.reshape(-1)
 
 

@@ -56,10 +56,9 @@ def block_topk(scores: jnp.ndarray, k: int, k_pad: int,
     pos_iota = jax.lax.broadcasted_iota(jnp.int32, (1, b), 1)
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, k_pad), 1)
     sentinel = jnp.float32(-jnp.inf) if largest else jnp.float32(jnp.inf)
-    vals = jnp.full((1, k_pad), sentinel, jnp.float32)
-    pos = jnp.full((1, k_pad), -1, jnp.int32)
-    work = scores.astype(jnp.float32)
-    for j in range(k):
+
+    def extract(j, carry):
+        vals, pos, work = carry
         m = work.max() if largest else work.min()
         # first occurrence among exact ties -> lowest block position, the
         # stable-sort tie order the host merge (TopK.push) produces
@@ -67,6 +66,15 @@ def block_topk(scores: jnp.ndarray, k: int, k_pad: int,
         vals = jnp.where(lane == j, m, vals)
         pos = jnp.where(lane == j, p, pos)
         work = jnp.where(pos_iota == p, sentinel, work)
+        return vals, pos, work
+
+    # a rolled loop: k unrolled rounds make the kernel's compile time grow
+    # with k (hundreds of rounds at SIS-sized k)
+    vals, pos, _ = jax.lax.fori_loop(0, k, extract, (
+        jnp.full((1, k_pad), sentinel, jnp.float32),
+        jnp.full((1, k_pad), -1, jnp.int32),
+        scores.astype(jnp.float32),
+    ))
     return vals, pos
 
 

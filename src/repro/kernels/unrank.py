@@ -21,9 +21,11 @@ an ascending combination iff ``a`` is),
     lex_rank(a) = C(m, n) - 1 - Σ_i C(b_i, i)
 
 Colex unranking is greedy: for i = n…1, ``b_i`` is the largest c with
-C(c, i) ≤ r' — found here by a vectorized binary search with exact int64
-binomials (stepwise exact division, so no float rounding for any count
-that fits an int64).
+C(c, i) ≤ r' — found here by a vectorized binary search over a table of
+exact binomials C(c, i), c < m, built on the host in Python integers.  The
+table keeps the device side to int64 compares, subtractions and gathers:
+the TPU emulates int64, and an in-graph binomial by stepwise int64
+division took minutes to compile for a v5e.
 """
 from __future__ import annotations
 
@@ -45,37 +47,19 @@ def comb_exact(n: int, k: int) -> int:
     return n_models(n, k) if 0 <= k <= n else 0
 
 
-def _comb_i64(c: jnp.ndarray, k: int) -> jnp.ndarray:
-    """Vectorized exact C(c, k) in int64 for static small k.
-
-    The running product after step j is C(c, j) · (j-th falling factor),
-    and every prefix product of j consecutive integers is divisible by j!,
-    so each ``// (j)`` divides exactly — int64 stays exact as long as
-    (k+1)·C(c, k) < 2^63 (checked by the caller via ``fits_int64``).
-    """
-    c = c.astype(jnp.int64)
-    out = jnp.ones_like(c)
-    for j in range(k):
-        out = out * jnp.maximum(c - j, 0) // (j + 1)
-    return out
-
-
 def device_unrank_ok(m: int, n: int) -> bool:
     """True when device unranking is exact for this (m, n) space.
 
     Every intermediate must fit the widest integer the device computes in:
-    int64 under jax x64, int32 otherwise.  Two things can overflow: the
-    rank arithmetic (bounded by C(m, n)) and ``_comb_i64``'s falling-
-    factorial prefix products, whose peak is (k+1)·C(m-1, k+1) over the
-    steps actually taken — for n > m/2 that peak dwarfs C(m, n), so both
-    are checked.  Rejected spaces use the host-exact fallback in
-    ``core/l0.py`` (slower, never wrong).
+    int64 under jax x64, int32 otherwise.  The rank arithmetic is bounded
+    by C(m, n) and the binomial table by max_i C(m-1, i), which for
+    n > m/2 exceeds C(m, n), so both are checked.  Rejected spaces use the
+    host-exact fallback in ``core/l0.py`` (slower, never wrong).
     """
     bound = 2**62 if jax.config.jax_enable_x64 else 2**30
     if comb_exact(m, n) >= bound:
         return False
-    peak = max((k + 1) * comb_exact(m - 1, k + 1) for k in range(n))
-    return peak < bound
+    return max(comb_exact(m - 1, i) for i in range(n + 1)) < bound
 
 
 def unrank_lex_host(rank: int, m: int, n: int) -> list:
@@ -106,6 +90,11 @@ def unrank_lex(ranks: jnp.ndarray, m: int, n: int) -> jnp.ndarray:
     """
     total = comb_exact(m, n)
     r = (total - 1) - ranks.astype(jnp.int64)  # colex rank of the dual
+    # binom[i, c] = C(c, i), exact host integers
+    binom = jnp.asarray(
+        [[comb_exact(c, i) for c in range(m)] for i in range(n + 1)],
+        dtype=r.dtype,
+    )
     cols = []
     n_steps = max(1, int(np.ceil(np.log2(max(m, 2)))) + 1)
     for i in range(n, 0, -1):
@@ -113,11 +102,11 @@ def unrank_lex(ranks: jnp.ndarray, m: int, n: int) -> jnp.ndarray:
         lo = jnp.full_like(r, i - 1)
         hi = jnp.full_like(r, m - 1)
         for _ in range(n_steps):
-            mid = (lo + hi + 1) // 2
-            take = _comb_i64(mid, i) <= r
+            mid = (lo + hi + 1) >> 1
+            take = binom[i][mid] <= r
             lo = jnp.where(take, mid, lo)
             hi = jnp.where(take, hi, mid - 1)
-        r = r - _comb_i64(lo, i)
+        r = r - binom[i][lo]
         cols.append((m - 1 - lo).astype(jnp.int32))
     return jnp.stack(cols, axis=1)
 

@@ -28,6 +28,7 @@ import numpy as np
 
 from ..api import load_artifact
 from ..serve import ServingTier
+from .compile_cache import enable_compile_cache
 
 
 def parse_artifact_specs(specs: List[str]) -> List[Tuple[str, str]]:
@@ -72,6 +73,7 @@ def main():
                     help="randomize batch sizes to exercise shape bucketing")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     artifacts = parse_artifact_specs(args.artifact)
     tier = ServingTier(n_replicas=args.replicas, row_budget=args.budget,

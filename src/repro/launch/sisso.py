@@ -33,6 +33,7 @@ from ..configs.sisso_kaggle import kaggle_bandgap_case
 from ..configs.sisso_thermal import thermal_conductivity_case
 from ..data import classification_dataset
 from ..runtime import WorkJournal
+from .compile_cache import enable_compile_cache
 
 
 def _run_classification(args) -> None:
@@ -84,6 +85,7 @@ def main():
     ap.add_argument("--save", default=None,
                     help="write the fitted model artifact (JSON) here")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.problem == "classification":
         if args.kernels:

@@ -16,8 +16,10 @@ its stable-argsort top-``N_KEEP`` panel, exactly the per-block panel
 
 Injected faults, and what must survive them:
 
-* worker 0 runs under ``REPRO_FAULTS=worker.tick:kill@3`` — it dies with
-  ``os._exit(137)`` on its third block, mid-lease.  The coordinator sees
+* worker 0 runs under ``REPRO_FAULTS=worker.tick:kill@1`` — it dies with
+  ``os._exit(137)`` on its first block, mid-lease (every worker is handed
+  its first block at once, so the kill cannot miss however the load skews
+  the workers' speeds).  The coordinator sees
   EOF, releases its leases (``LeaseTable.release_worker``) and the block
   *reissues* to a surviving worker.
 * the coordinator's 2nd journal publication is torn mid-JSON
@@ -33,8 +35,10 @@ bit — the fault-free single-process ``l0_search`` top-k.
 import json
 import os
 import queue
+import shutil
 import subprocess
 import sys
+import tempfile
 import threading
 
 import numpy as np
@@ -78,7 +82,7 @@ def worker_main(rank: int) -> None:
             break
         assert parts[0] == "SCORE", parts
         bi = int(parts[1])
-        # fault site: REPRO_FAULTS=worker.tick:kill@3 makes rank 0 die
+        # fault site: REPRO_FAULTS=worker.tick:kill@1 makes rank 0 die
         # here (os._exit) holding its lease — the preemption under test
         faults.check("worker.tick")
         tuples = np.asarray(enum.block_tuples(bi))
@@ -130,7 +134,7 @@ def coordinator_main() -> None:
     for rank in range(N_WORKERS):
         wenv = dict(env)
         if rank == 0:
-            wenv["REPRO_FAULTS"] = "worker.tick:kill@3"
+            wenv["REPRO_FAULTS"] = "worker.tick:kill@1"
         procs[rank] = subprocess.Popen(
             [sys.executable, os.path.abspath(__file__), "worker", str(rank)],
             stdin=subprocess.PIPE, stdout=subprocess.PIPE,
@@ -138,8 +142,8 @@ def coordinator_main() -> None:
         threading.Thread(target=_reader, args=(rank, procs[rank], events),
                          daemon=True).start()
 
-    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        "_elastic_journal.json")
+    journal_dir = tempfile.mkdtemp(prefix="elastic-journal-")
+    path = os.path.join(journal_dir, "journal.json")
     journal = WorkJournal(path)
     journal.clear()
     table = LeaseTable(n_blocks, ttl=300.0)
@@ -165,7 +169,11 @@ def coordinator_main() -> None:
         kind, rank, bi, panel = events.get(timeout=120)
         if kind == "ready":
             alive.add(rank)
-            dispatch(rank)
+            # start every worker together: a worker that came up late could
+            # otherwise find the sweep done before it gets a block
+            if len(alive) == N_WORKERS:
+                for r in sorted(alive):
+                    dispatch(r)
         elif kind == "dead":
             if rank in alive:
                 alive.discard(rank)
@@ -219,7 +227,7 @@ def coordinator_main() -> None:
     np.testing.assert_array_equal(sse, ref.sses)
     np.testing.assert_array_equal(tuples, ref.tuples)
     print("elastic: final top-k bit-identical to fault-free l0_search: OK")
-    journal.clear()
+    shutil.rmtree(journal_dir, ignore_errors=True)
 
 
 if __name__ == "__main__":

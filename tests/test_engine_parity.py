@@ -306,6 +306,31 @@ def test_l0_search_ranking_parity_partial_rescore(rng):
     np.testing.assert_allclose(res.sses, ref.sses, rtol=1e-6, atol=1e-8)
 
 
+@pytest.mark.parametrize("backend", ["pallas", "sharded:pallas"])
+def test_l0_near_exact_width4_certified(rng, backend):
+    """A near-exact fit: every width-4 superset of the planted pair (231 of
+    them) lies within fp32 noise of the best, far more than a 64-tuple
+    rescore window holds.  The prescreen cannot rank them; the exclusion
+    floor must detect that and settle the block in fp64, so the top-k still
+    equals the reference.  Blocks without near-ties stay on the kernel."""
+    m, s = 24, 96
+    x = rng.uniform(0.5, 3.0, (m, s))
+    y = 2.0 * x[3] - x[7] + 1e-4 * rng.normal(size=s)
+    layout = TaskLayout.from_task_ids(np.repeat([0, 1], s // 2))
+    ref = l0_search(x, y, layout, n_dim=4, n_keep=10, block=4096,
+                    engine=get_engine("reference"))
+    eng = get_engine(backend)
+    getattr(eng.backend, "inner", eng.backend).rescore_k = 64
+    res = l0_search(x, y, layout, n_dim=4, n_keep=10, block=4096, engine=eng)
+    assert np.array_equal(res.tuples, ref.tuples)
+    np.testing.assert_allclose(res.sses, ref.sses, rtol=1e-6, atol=1e-8)
+    paths = eng.backend.l0_paths[4]
+    fallback = ("exact fp64 (window not certified)" if backend == "pallas"
+                else "one-device fallback")
+    assert paths.get(fallback, 0) >= 1
+    assert sum(paths.values()) > paths[fallback]  # the rest: kernel path
+
+
 @pytest.mark.parametrize("backend", DEVICE_BACKENDS)
 def test_full_fit_parity_thermal(case, backend):
     """End-to-end: identical descriptor and matching SSE on every backend

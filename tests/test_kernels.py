@@ -35,7 +35,7 @@ def _oracle_scores(op_id, xa, xb, ctx_pad, s, l_b=1e-5, u_b=1e8):
 
 
 OPS_SWEEP = [om.ADD, om.SUB, om.MUL, om.DIV, om.ABS_DIFF, om.LOG, om.SQRT,
-             om.SQ, om.CB, om.INV, om.EXP, om.NEG_EXP, om.SIX_POW]
+             om.SQ, om.CB, om.INV, om.EXP, om.NEG_EXP, om.SIX_POW, om.CBRT]
 
 
 @pytest.mark.parametrize("op_id", OPS_SWEEP)
@@ -204,6 +204,45 @@ def test_l0_gather_kernel_matches_oracle(rng, m, s, tasks, width, block_t):
     assert np.argmin(got) == np.argmin(want)
 
 
+@pytest.mark.parametrize("collinearity", [1e-2, 1e-4, 1e-6])
+def test_l0_gather_bound_never_exceeds_exact_sse(rng, collinearity):
+    """The kernel's output is a certified lower bound: on near-collinear
+    features, fits from near-exact to noisy and feature scales over six
+    decades, no tuple's bound exceeds its exact (fp64 QR) SSE."""
+    import itertools
+
+    from repro.kernels.ref import l0_gather_sse_ref
+
+    m, s = 12, 80
+    layout = TaskLayout.from_task_ids(np.repeat([0, 1], [35, 45]))
+    for _ in range(8):
+        x = rng.uniform(0.5, 3.0, (m, s)) * 10.0 ** rng.uniform(-3, 3, (m, 1))
+        for j in range(4):
+            a, b, c = rng.choice(m, 3, replace=False)
+            x[c] = (rng.normal() * x[a] + rng.normal() * x[b] * (j % 2)
+                    + collinearity * x[c].std() * rng.normal(size=s))
+        y = (rng.normal() * x[3] / x[3].std()
+             + rng.normal() * x[7] / x[7].std() + 5.0
+             + 10.0 ** rng.uniform(-8, 0) * rng.normal(size=s))
+        pack = kops.pack_gram(compute_gram_stats(x, y, layout))
+        for width in (3, 4):
+            tuples = np.asarray(list(itertools.combinations(range(m), width)),
+                                np.int32)
+            bound = np.asarray(l0_gather_sse_ref(
+                pack["gram"], pack["fsum"], pack["bvec"], pack["scal"],
+                jnp.asarray(tuples)), np.float64)
+            exact = np.zeros(len(tuples))
+            for lo, hi in layout.slices:
+                design = np.concatenate(
+                    [x[tuples, lo:hi].transpose(0, 2, 1),
+                     np.ones((len(tuples), hi - lo, 1))], axis=2)
+                q, _ = np.linalg.qr(design)
+                fit = np.einsum("bij,bj->bi", q,
+                                np.einsum("bij,i->bj", q, y[lo:hi]))
+                exact += ((y[lo:hi] - fit) ** 2).sum(axis=1)
+            assert np.all(bound <= exact * (1 + 1e-9) + 1e-12 * exact.max())
+
+
 def test_l0_gather_padding_is_inert(rng):
     """Block sizes that don't divide block_t get benign padding tuples;
     results must be identical to an aligned call, sliced."""
@@ -302,18 +341,25 @@ def test_l0_topk_tuples_matches_full(rng, width):
     full = np.asarray(kops.l0_score_tuples(pack, jnp.asarray(tuples),
                                            block_t=128, interpret=True))
     order = np.argsort(full, kind="stable")[:10]
-    sses, idx = kops.l0_topk_tuples(pack, jnp.asarray(tuples), n_keep=10,
-                                    block_t=128, epilogue_k=32, interpret=True)
+    sses, idx, floor = kops.l0_topk_tuples(
+        pack, jnp.asarray(tuples), n_keep=10, block_t=128, epilogue_k=32,
+        interpret=True)
     # same fp32 math but a different XLA fusion graph: indices must agree
     # exactly, values up to FMA/fusion ulp noise (fp64 rescore is phase 2)
     assert np.array_equal(idx, order)
     np.testing.assert_allclose(sses, full[order], rtol=1e-4)
-    # padding tuples (131 over block_t=128) must never surface as winners
-    sses2, idx2 = kops.l0_topk_tuples(pack, jnp.asarray(tuples[:131]),
-                                      n_keep=131, block_t=128,
-                                      epilogue_k=128, interpret=True)
+    # the floor lies under every bound left out, and at the merge's cut
+    left_out = np.setdiff1d(np.arange(len(tuples)), idx)
+    assert floor <= full[left_out].min() * (1 + 1e-6)
+    assert floor == pytest.approx(sses[-1], rel=1e-6) or floor < sses[-1]
+    # padding tuples (131 over block_t=128) must never surface as winners;
+    # keeping all of them leaves nothing out
+    sses2, idx2, floor2 = kops.l0_topk_tuples(
+        pack, jnp.asarray(tuples[:131]), n_keep=131, block_t=128,
+        epilogue_k=128, interpret=True)
     assert np.all((idx2 >= 0) & (idx2 < 131))
     assert np.all(np.isfinite(sses2))
+    assert floor2 == np.inf
 
 
 def test_fused_sis_topk_bf16_winner_overlap(rng):
@@ -344,11 +390,18 @@ def test_pack_gram_dtype_variants(rng):
     p32 = kops.pack_gram(stats, jnp.float32)
     p16 = kops.pack_gram(stats, jnp.bfloat16)
     assert p32["dtype"] == "float32" and p16["dtype"] == "bfloat16"
-    assert p16["gram"].dtype == jnp.bfloat16
+    # bf16 planes: three carry the fp32 rounding exactly, one rounds to bf16
+    assert p32["gram"].shape[0] == 3 and p16["gram"].shape[0] == 1
+    assert p32["gram"].dtype == p16["gram"].dtype == jnp.bfloat16
     # scal stays fp32 in both: the solve epilogue accumulates in fp32
     assert p16["scal"].dtype == jnp.float32
-    np.testing.assert_allclose(np.asarray(p16["gram"], np.float32),
-                               np.asarray(p32["gram"]), rtol=2e-2, atol=1e-2)
+    g32 = np.asarray(stats.gram, np.float32)
+    summed = np.zeros_like(g32)
+    for plane in np.asarray(p32["gram"], np.float32):
+        summed = summed + plane[:, :m, :m]
+    np.testing.assert_array_equal(summed, g32)
+    np.testing.assert_allclose(np.asarray(p16["gram"][0], np.float32)[:, :m, :m],
+                               g32, rtol=2e-2, atol=1e-2)
 
 
 def test_l0_search_tiled_planted(rng):
