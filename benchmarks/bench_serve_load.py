@@ -2,9 +2,9 @@
 
 Drives a 2-replica :class:`repro.serve.ServingTier` holding two resident
 models with a mixed Poisson + bursty request trace (repro/serve/traffic.py),
-performs one **mid-load hot-swap** of a model, and records latency
+performs one **mid-load hot-swap** of a model, and prints latency
 percentiles, throughput, batch occupancy and per-status request
-accounting into ``BENCH_serve_load.json``.
+accounting.
 
 Hard invariants asserted on every run (the serving tier's contract, not
 just numbers): zero ``status="error"`` responses across the run — in
@@ -26,8 +26,6 @@ from repro.serve import (
     STATUS_ERROR, STATUS_OK, ServingTier, bursty_trace, merge_traces,
     poisson_trace,
 )
-
-from .common import emit, reset_bench_rows, write_bench_json
 
 #: primary-feature count shared by both synthetic models
 N_FEATURES = 5
@@ -64,8 +62,11 @@ def _drive(tier: ServingTier, events, swap_at: int, swap_fn, rng):
     return [(ev, p.result(timeout=30.0)) for ev, p in pending]
 
 
+def emit(name: str, value: float, derived: str = "") -> None:
+    print(f"{name},{value:.1f},{derived}")
+
+
 def main(quick: bool = False) -> None:
-    reset_bench_rows()
     rng = np.random.default_rng(7)
 
     alpha = _fit(lambda X: 2.5 * X[:, 0] * X[:, 1] + 0.7, seed=1)
@@ -144,7 +145,6 @@ def main(quick: bool = False) -> None:
          f"bounded bucket caches: "
          f"{[rep['jit_cache']['resident'] for rep in stats['replicas']]} "
          f"resident")
-    write_bench_json("serve_load")
 
 
 if __name__ == "__main__":

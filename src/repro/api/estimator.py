@@ -46,6 +46,7 @@ import numpy as np
 from ..core.descriptor import compile_features
 from ..core.solver import SissoConfig, SissoSolver
 from ..core.units import Unit
+from ..runtime import trace
 from .artifact import DescriptorModel, FittedSisso, _py
 
 try:  # optional: inherit sklearn's estimator plumbing (tags, HTML repr)
@@ -181,6 +182,14 @@ class _BaseSisso(_SkBase):
         tasks=None,             # (n_samples,) task labels, any hashables
         journal=None,
     ) -> "_BaseSisso":
+        # the whole fit is one record (runtime/trace.py): the solver joins
+        # it, and its timings and stats are final once it closes
+        with trace.collecting() as rec:
+            self._fit(X, y, names, units, tasks, journal)
+        rec.report(self.fit_result_)
+        return self
+
+    def _fit(self, X, y, names, units, tasks, journal) -> None:
         X = np.asarray(X, np.float64)
         y = np.asarray(y)
         if X.ndim != 2:
@@ -222,25 +231,9 @@ class _BaseSisso(_SkBase):
         # a CPU; a TPU emulates fp64, and its compiled program may round
         # the last bit differently from the per-op training path (7e-15 at
         # values ~50 on a v5e), while a wrong lineage is off by O(1)
-        xmat = fit.fspace.values_matrix()
-        models_by_dim = {}
-        for dim, models in fit.models_by_dim.items():
-            compiled = []
-            for mdl in models:
-                program = compile_features(mdl.features, fit.fspace)
-                got = solver.engine.eval_program(program, xp)
-                want = xmat[[f.row for f in mdl.features]]
-                scale = np.abs(want).max(axis=1, keepdims=True)
-                if not np.all(np.abs(got - want) <= _REPLAY_RTOL * scale):
-                    raise RuntimeError(
-                        f"compiled descriptor diverged from training values "
-                        f"for dim-{dim} model {list(program.exprs)} "
-                        f"(max |Δ| = {np.abs(got - want).max():g})"
-                    )
-                compiled.append(self._descriptor_model(
-                    mdl, program, class_labels
-                ))
-            models_by_dim[dim] = compiled
+        with trace.span("sisso.descriptor"):
+            models_by_dim = self._compile_models(fit, solver.engine, xp,
+                                                 class_labels)
 
         self.fitted_ = FittedSisso(
             names=names,
@@ -257,7 +250,30 @@ class _BaseSisso(_SkBase):
         self.fit_result_ = fit          # core SissoFit (fspace, raw models)
         self.n_features_in_ = p
         self.feature_names_in_ = np.asarray(names, object)
-        return self
+
+    def _compile_models(self, fit, engine, xp, class_labels) -> dict:
+        """dim -> [DescriptorModel]: every model's descriptor compiled and
+        replayed on the training primaries ``xp`` (P, S)."""
+        xmat = fit.fspace.values_matrix()
+        models_by_dim = {}
+        for dim, models in fit.models_by_dim.items():
+            compiled = []
+            for mdl in models:
+                program = compile_features(mdl.features, fit.fspace)
+                got = engine.eval_program(program, xp)
+                want = xmat[[f.row for f in mdl.features]]
+                scale = np.abs(want).max(axis=1, keepdims=True)
+                if not np.all(np.abs(got - want) <= _REPLAY_RTOL * scale):
+                    raise RuntimeError(
+                        f"compiled descriptor diverged from training values "
+                        f"for dim-{dim} model {list(program.exprs)} "
+                        f"(max |Δ| = {np.abs(got - want).max():g})"
+                    )
+                compiled.append(self._descriptor_model(
+                    mdl, program, class_labels
+                ))
+            models_by_dim[dim] = compiled
+        return models_by_dim
 
     def _descriptor_model(self, mdl, program, class_labels) -> DescriptorModel:
         """Core model -> serializable compiled model (problem-specific)."""

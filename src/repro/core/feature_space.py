@@ -32,6 +32,7 @@ from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from ..runtime import trace
 from . import operators as ops_mod
 from .operators import ChildMeta, Operator
 from .units import Unit
@@ -199,6 +200,7 @@ class FeatureSpace:
                     return True
         return False
 
+    @trace.span("sisso.fc.admit")
     def admit_block(
         self,
         rung: int,
@@ -331,6 +333,7 @@ class FeatureSpace:
     # ------------------------------------------------------------------
     # device evaluation + value rules (paper P2 "GPU side")
     # ------------------------------------------------------------------
+    @trace.span("sisso.fc.eval")
     def eval_candidates(
         self, op_id: int, rows_a: np.ndarray, rows_b: np.ndarray,
         values: Optional[np.ndarray] = None,

@@ -388,7 +388,7 @@ def l0_search(
         method, engine = engine, None
     from ..engine import get_engine
     from ..engine.streaming import BlockPrefetcher
-    from ..runtime import faults
+    from ..runtime import faults, trace
     from .problem import get_problem
 
     engine = get_engine(engine)
@@ -398,8 +398,9 @@ def l0_search(
     n_dim, n_keep, block = int(n_dim), int(n_keep), int(block)
     m = int(np.asarray(x).shape[0])
     if prob is None:
-        prob = engine.prepare_l0(x, y, layout, method=method, dtype=dtype,
-                                 problem=kind)
+        with trace.span("sisso.l0.prepare"):
+            prob = engine.prepare_l0(x, y, layout, method=method,
+                                     dtype=dtype, problem=kind)
     elif (
         prob.method != method
         or prob.problem != kind
@@ -493,39 +494,41 @@ def l0_search(
         )
 
     stream = BlockPrefetcher(
-        score_block, range(start_block, enum.n_blocks), depth=prefetch_depth
+        score_block, range(start_block, enum.n_blocks), depth=prefetch_depth,
+        span="sisso.l0",
     )
     for bi, (tuples, res) in stream:
         n_eval += len(tuples)
-        # merge block top-k into running top-k (host).  A block whose best
-        # SSE cannot beat the current k-th best contributes nothing — skip
-        # the concatenate+argsort (ties lose to incumbents either way).
-        # Negated comparison so a NaN block-min (a backend without the
-        # finite→inf guard) falls through to the merge, never to a skip.
-        blk_sse = blk_tup = None
-        if isinstance(res, ReducedBlock):
-            if len(res) and not (res.scores.min() >= best_sse[-1]):
-                blk_sse = res.scores
-                blk_tup = winners_of(tuples, bi, res.indices)
-        else:
-            sses = np.asarray(res)
-            if len(sses) and not (sses.min() >= best_sse[-1]):
-                k = min(n_keep, len(sses))
-                # stable selection: exact objective ties (routine for the
-                # classification overlap count) must resolve to the same
-                # winners as a device-reduced block's ordered top-k
-                part = np.argsort(sses, kind="stable")[:k]
-                blk_sse = sses[part]
-                blk_tup = np.asarray(tuples)[part].astype(np.int64)
-        if blk_sse is not None:
-            # scrub non-finite panel entries (NaN from a faulted device,
-            # ±inf sentinels) to +inf so a poisoned block loses to every
-            # finite incumbent instead of corrupting the top-k order
-            blk_sse = np.where(np.isfinite(blk_sse), blk_sse, np.inf)
-            cat_sse = np.concatenate([best_sse, blk_sse])
-            cat_tup = np.concatenate([best_tuples, blk_tup])
-            order = np.argsort(cat_sse, kind="stable")[:n_keep]
-            best_sse, best_tuples = cat_sse[order], cat_tup[order]
+        with trace.span("sisso.l0.merge"):
+            # merge block top-k into running top-k (host).  A block whose best
+            # SSE cannot beat the current k-th best contributes nothing — skip
+            # the concatenate+argsort (ties lose to incumbents either way).
+            # Negated comparison so a NaN block-min (a backend without the
+            # finite→inf guard) falls through to the merge, never to a skip.
+            blk_sse = blk_tup = None
+            if isinstance(res, ReducedBlock):
+                if len(res) and not (res.scores.min() >= best_sse[-1]):
+                    blk_sse = res.scores
+                    blk_tup = winners_of(tuples, bi, res.indices)
+            else:
+                sses = np.asarray(res)
+                if len(sses) and not (sses.min() >= best_sse[-1]):
+                    k = min(n_keep, len(sses))
+                    # stable selection: exact objective ties (routine for the
+                    # classification overlap count) must resolve to the same
+                    # winners as a device-reduced block's ordered top-k
+                    part = np.argsort(sses, kind="stable")[:k]
+                    blk_sse = sses[part]
+                    blk_tup = np.asarray(tuples)[part].astype(np.int64)
+            if blk_sse is not None:
+                # scrub non-finite panel entries (NaN from a faulted device,
+                # ±inf sentinels) to +inf so a poisoned block loses to every
+                # finite incumbent instead of corrupting the top-k order
+                blk_sse = np.where(np.isfinite(blk_sse), blk_sse, np.inf)
+                cat_sse = np.concatenate([best_sse, blk_sse])
+                cat_tup = np.concatenate([best_tuples, blk_tup])
+                order = np.argsort(cat_sse, kind="stable")[:n_keep]
+                best_sse, best_tuples = cat_sse[order], cat_tup[order]
         if journal is not None:
             journal.record(bi + 1, best_sse, best_tuples, meta=sweep)
         # fault site: a worker preemption between blocks ("kill" exits the

@@ -322,7 +322,7 @@ def sis_screen(
         )
 
     for blk, s in BlockPrefetcher(
-        score_deferred, fspace.iter_candidate_batches(batch)
+        score_deferred, fspace.iter_candidate_batches(batch), span="sisso.sis"
     ):
         if isinstance(s, ReducedBlock):
             top.push_reduced(

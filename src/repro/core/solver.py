@@ -15,13 +15,13 @@ from __future__ import annotations
 
 import dataclasses
 import logging
-import time
 import warnings
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
 from ..precision import set_precision
+from ..runtime import trace
 from .feature_space import FeatureSpace
 from .l0 import l0_search
 from .problem import get_problem
@@ -96,11 +96,17 @@ class SissoConfig:
 class SissoFit:
     models_by_dim: Dict[int, List]  # SissoModel / SissoClassificationModel
     fspace: FeatureSpace
+    #: seconds per phase, summed from the fit's spans
+    #: (``runtime.trace.TIMED_SPANS``)
     timings: Dict[str, float]
     problem: str = "regression"
-    #: runtime counters (e.g. ``stats["resilience"]`` retry/demotion
-    #: accounting when SissoConfig.resilient is on)
+    #: runtime counters: ``programs`` lowered/loaded/compiled per span,
+    #: ``l0_paths`` ℓ0 blocks per (width, scoring path), and
+    #: ``resilience`` retry/demotion accounting when SissoConfig.resilient
+    #: is on
     stats: Dict[str, dict] = dataclasses.field(default_factory=dict)
+    #: the fit's spans and counters (runtime/trace.py)
+    trace: Optional[trace.FitTrace] = None
 
     def best(self, dim: Optional[int] = None):
         if not self.models_by_dim:
@@ -169,6 +175,14 @@ class SissoSolver:
         task_ids: Optional[np.ndarray] = None,
         journal=None,
     ) -> SissoFit:
+        with trace.collecting() as rec:
+            fit = self._fit(primary_values, y, names, units, task_ids,
+                            journal)
+        rec.report(fit)
+        return fit
+
+    def _fit(self, primary_values, y, names, units, task_ids,
+             journal) -> SissoFit:
         cfg = self.cfg
         if journal is not None and getattr(journal, "path", None):
             # tuned launch configs persist next to the work journal so a
@@ -183,22 +197,19 @@ class SissoSolver:
             if task_ids is not None
             else TaskLayout.single(s)
         )
-        timings: Dict[str, float] = {}
-
         # ---- phase 1: feature creation -------------------------------
-        t0 = time.perf_counter()
-        fspace = FeatureSpace(
-            primary_values, names, units,
-            op_names=cfg.op_names, max_rung=cfg.max_rung,
-            l_bound=cfg.l_bound, u_bound=cfg.u_bound,
-            on_the_fly_last_rung=cfg.on_the_fly_last_rung,
-            max_pairs_per_op=cfg.max_pairs_per_op, seed=cfg.seed,
-            engine=self.engine,
-        ).generate()
-        timings["fc"] = time.perf_counter() - t0
+        with trace.span("sisso.fc"):
+            fspace = FeatureSpace(
+                primary_values, names, units,
+                op_names=cfg.op_names, max_rung=cfg.max_rung,
+                l_bound=cfg.l_bound, u_bound=cfg.u_bound,
+                on_the_fly_last_rung=cfg.on_the_fly_last_rung,
+                max_pairs_per_op=cfg.max_pairs_per_op, seed=cfg.seed,
+                engine=self.engine,
+            ).generate()
         log.info(
-            "FC: %d materialized + %d deferred candidates (%.3fs)",
-            len(fspace.features), fspace.n_candidates_deferred, timings["fc"],
+            "FC: %d materialized + %d deferred candidates",
+            len(fspace.features), fspace.n_candidates_deferred,
         )
 
         # ---- phases 2+3: SIS / ℓ0 over dimensions ---------------------
@@ -206,23 +217,20 @@ class SissoSolver:
         # builds the screening context, defines the ℓ0 tuple objective,
         # turns winners into model objects, and produces the next state
         # (residuals / ambiguity masks).  This loop owns only phase
-        # sequencing, the subspace bookkeeping and timings.
+        # sequencing, the subspace bookkeeping and the phase spans.
         problem = get_problem(cfg.problem)
         subspace: List[int] = []  # fids, in selection order
         selected: set = set()
         models_by_dim: Dict[int, List] = {}
         state = problem.initial_state(y, layout)  # Δ_0
-        timings["sis"] = 0.0
-        timings["l0"] = 0.0
 
         for dim in range(1, cfg.n_dim + 1):
-            t0 = time.perf_counter()
-            feats, scores = sis_screen(
-                fspace, state, layout, cfg.n_sis, selected,
-                batch=cfg.sis_batch, engine=self.engine,
-                problem=problem, y=y,
-            )
-            timings["sis"] += time.perf_counter() - t0
+            with trace.span("sisso.sis"):
+                feats, scores = sis_screen(
+                    fspace, state, layout, cfg.n_sis, selected,
+                    batch=cfg.sis_batch, engine=self.engine,
+                    problem=problem, y=y,
+                )
             for f in feats:
                 subspace.append(f.fid)
                 selected.add(f.fid)
@@ -233,26 +241,36 @@ class SissoSolver:
             )
 
             # ℓ0 over the accumulated subspace
-            t0 = time.perf_counter()
-            xmat = fspace.values_matrix()
-            xs = xmat[[fspace.features[fid].row for fid in subspace]]
-            res = l0_search(
-                xs, y, layout, n_dim=dim, n_keep=cfg.n_residual,
-                block=cfg.l0_block, method=cfg.l0_method,
-                engine=self.engine, journal=journal,
-                dtype=self.dtype, problem=problem,
-            )
-            if journal is not None:
-                # this dim's sweep is complete; stale state would otherwise be
-                # "restored" by the next dim's search (different tuple width)
-                journal.clear()
-            timings["l0"] += time.perf_counter() - t0
+            with trace.span("sisso.l0"):
+                xmat = fspace.values_matrix()
+                xs = xmat[[fspace.features[fid].row for fid in subspace]]
+                res = l0_search(
+                    xs, y, layout, n_dim=dim, n_keep=cfg.n_residual,
+                    block=cfg.l0_block, method=cfg.l0_method,
+                    engine=self.engine, journal=journal,
+                    dtype=self.dtype, problem=problem,
+                )
+                if journal is not None:
+                    # this dim's sweep is complete; stale state would
+                    # otherwise be "restored" by the next dim's search
+                    # (different tuple width)
+                    journal.clear()
 
-            models = problem.make_models(
-                xs, y, layout, res,
-                feature_of=lambda j: fspace.features[subspace[j]],
-                n_keep=cfg.n_residual, dtype=self.dtype,
-            )
+            with trace.span("sisso.models"):
+                models = problem.make_models(
+                    xs, y, layout, res,
+                    feature_of=lambda j: fspace.features[subspace[j]],
+                    n_keep=cfg.n_residual, dtype=self.dtype,
+                )
+                # the best n_residual models feed the next SIS pass
+                # (residuals for regression, still-ambiguous sample masks
+                # for classification)
+                state = problem.update_state(
+                    y, layout, models[: cfg.n_residual],
+                    values_of=lambda mdl: xmat[
+                        [fspace.features[f.fid].row for f in mdl.features]
+                    ],
+                )
             models_by_dim[dim] = models
             if not models:
                 log.warning(
@@ -266,26 +284,14 @@ class SissoSolver:
                 dim, res.n_evaluated, res.sses[0],
             )
 
-            # the best n_residual models feed the next SIS pass (residuals
-            # for regression, still-ambiguous sample masks for classification)
-            state = problem.update_state(
-                y, layout, models[: cfg.n_residual],
-                values_of=lambda mdl: xmat[
-                    [fspace.features[f.fid].row for f in mdl.features]
-                ],
-            )
-
         stats: Dict[str, dict] = {}
         # resilience accounting (reads through the DebugBackend proxy's
         # __getattr__ when the sanitizer wraps the resilient wrapper)
         fault_stats = getattr(self.engine.backend, "fault_stats", None)
         if fault_stats is not None:
             stats["resilience"] = dict(fault_stats)
-        # ℓ0 blocks per (tuple width, scoring path) — what actually ran
-        stats["l0_paths"] = {w: dict(p) for w, p in getattr(
-            self.engine.backend, "l0_paths", {}).items()}
         return SissoFit(models_by_dim=models_by_dim, fspace=fspace,
-                        timings=timings, problem=problem.kind, stats=stats)
+                        timings={}, problem=problem.kind, stats=stats)
 
 
 class SissoRegressor(SissoSolver):

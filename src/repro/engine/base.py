@@ -27,17 +27,13 @@ from __future__ import annotations
 
 import abc
 import dataclasses
-import threading
 from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
 
 from ..core.sis import ReducedBlock, ScoreContext, TaskLayout
 from ..core.l0 import GramStats
-
-
-#: guards :meth:`Backend.record_l0_path` (prefetch threads score blocks)
-_PATH_LOCK = threading.Lock()
+from ..runtime import trace
 
 
 @dataclasses.dataclass
@@ -270,13 +266,11 @@ class Backend(abc.ABC):
     def record_l0_path(self, width: int, path: str) -> None:
         """Count one ℓ0 block of tuple ``width`` scored by ``path``.
 
-        The solver reports the counts as ``SissoFit.stats["l0_paths"]``
-        (``{width: {path: blocks}}``), so a caller sees which path each
-        width took rather than predicting it."""
-        with _PATH_LOCK:
-            paths = self.__dict__.setdefault("l0_paths", {})
-            by_path = paths.setdefault(int(width), {})
-            by_path[path] = by_path.get(path, 0) + 1
+        The count belongs to the active fit (runtime/trace.py), which
+        reports it as ``SissoFit.stats["l0_paths"]`` (``{width: {path:
+        blocks}}``), so a caller sees which path each width took rather
+        than predicting it."""
+        trace.count(("l0_paths", int(width), path))
 
     # -- prediction: compiled descriptor programs ----------------------
     def eval_program(self, program, x: np.ndarray) -> np.ndarray:

@@ -55,6 +55,7 @@ from ..core.sis import (
 )
 from ..kernels import autotune
 from ..kernels import ops as kops
+from ..runtime import trace
 from .base import L0Problem
 from .jnp_backend import JnpBackend
 
@@ -240,6 +241,7 @@ class PallasBackend(JnpBackend):
                 pack = prob.cache["gram_pack"] = kops.pack_gram(stats)
         return pack
 
+    @trace.span("sisso.l0.rescore")
     def _exact_rescore(self, prob: L0Problem, tuples_dev) -> np.ndarray:
         """fp64 SSEs of candidate tuples (jitted, cached per problem)."""
         stats = self._fp64_stats(prob)

@@ -26,12 +26,22 @@ Thread-safety notes: JAX dispatch is thread-safe, and with the default
 ``depth=2`` at most ``depth`` worker calls are in flight, so device memory
 pressure is bounded by ``depth`` blocks.  Exceptions from workers re-raise
 at the consumer in block order; pending blocks are cancelled.
+
+Spans (runtime/trace.py): each block runs on its worker inside
+``<span>.block``, and the consumer's wait for a block is ``<span>.wait``,
+where ``span`` is the caller's base name (``sisso.l0``, ``sisso.sis``).  A
+block runs in a copy of the submitting context, so its spans and counters
+land in the fit that submitted it.  The workers' threads are named
+``block-prefetch``, which is also their line's name in a profiler trace.
 """
 from __future__ import annotations
 
+import contextvars
 from concurrent.futures import ThreadPoolExecutor
 from collections import deque
 from typing import Callable, Generic, Iterable, Iterator, Tuple, TypeVar
+
+from ..runtime import trace
 
 TItem = TypeVar("TItem")
 TOut = TypeVar("TOut")
@@ -43,6 +53,7 @@ class BlockPrefetcher(Generic[TItem, TOut]):
     Iterating yields ``(item, fn(item))`` pairs in the order ``items``
     produced them.  ``depth=1`` degenerates to eager single-buffering
     (still off-main-thread); ``depth=2`` is classic double buffering.
+    ``span`` is the base name of the block and wait spans.
     """
 
     def __init__(
@@ -50,12 +61,14 @@ class BlockPrefetcher(Generic[TItem, TOut]):
         fn: Callable[[TItem], TOut],
         items: Iterable[TItem],
         depth: int = 2,
+        span: str = "prefetch",
     ):
         if depth < 1:
             raise ValueError(f"prefetch depth must be >= 1, got {depth}")
         self.fn = fn
         self.items = iter(items)
         self.depth = depth
+        self.span = span
 
     def _fetch(self, item: TItem) -> TOut:
         # fault site ``prefetch.fetch``: a worker-thread dispatch failure
@@ -64,24 +77,31 @@ class BlockPrefetcher(Generic[TItem, TOut]):
         # is exactly the ordering contract this site exists to test.
         from ..runtime import faults
 
-        faults.check("prefetch.fetch")
-        return self.fn(item)
+        with trace.span(self.span + ".block"):
+            faults.check("prefetch.fetch")
+            return self.fn(item)
+
+    def _result(self, fut) -> TOut:
+        with trace.span(self.span + ".wait"):
+            return fut.result()
 
     def __iter__(self) -> Iterator[Tuple[TItem, TOut]]:
         pool = ThreadPoolExecutor(
-            max_workers=self.depth, thread_name_prefix="block-prefetch"
+            max_workers=self.depth, thread_name_prefix="block-prefetch",
+            initializer=trace.name_os_thread, initargs=("block-prefetch",),
         )
         inflight: deque = deque()
         try:
             for item in self.items:
-                inflight.append((item, pool.submit(self._fetch, item)))
+                ctx = contextvars.copy_context()
+                inflight.append((item, pool.submit(ctx.run, self._fetch, item)))
                 if len(inflight) < self.depth:
                     continue
                 item0, fut = inflight.popleft()
-                yield item0, fut.result()
+                yield item0, self._result(fut)
             while inflight:
                 item0, fut = inflight.popleft()
-                yield item0, fut.result()
+                yield item0, self._result(fut)
         finally:
             for _, fut in inflight:
                 fut.cancel()

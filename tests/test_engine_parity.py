@@ -16,6 +16,7 @@ from repro.core.feature_space import FeatureSpace
 from repro.core.l0 import l0_search
 from repro.core.sis import TaskLayout, build_score_context, sis_screen
 from repro.engine import BACKENDS, Engine, get_engine
+from repro.runtime import trace
 
 DEVICE_BACKENDS = ["jnp", "pallas", "sharded", "sharded:pallas"]
 ALL_BACKENDS = ["reference"] + DEVICE_BACKENDS
@@ -321,10 +322,12 @@ def test_l0_near_exact_width4_certified(rng, backend):
                     engine=get_engine("reference"))
     eng = get_engine(backend)
     getattr(eng.backend, "inner", eng.backend).rescore_k = 64
-    res = l0_search(x, y, layout, n_dim=4, n_keep=10, block=4096, engine=eng)
+    with trace.collecting() as rec:
+        res = l0_search(x, y, layout, n_dim=4, n_keep=10, block=4096,
+                        engine=eng)
     assert np.array_equal(res.tuples, ref.tuples)
     np.testing.assert_allclose(res.sses, ref.sses, rtol=1e-6, atol=1e-8)
-    paths = eng.backend.l0_paths[4]
+    paths = rec.stats()["l0_paths"][4]
     fallback = ("exact fp64 (window not certified)" if backend == "pallas"
                 else "one-device fallback")
     assert paths.get(fallback, 0) >= 1
