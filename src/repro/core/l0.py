@@ -265,7 +265,9 @@ class TupleEnumerator:
     (kernels/unrank.py) — the former host-side ``itertools`` generator
     serialized the dominant phase on single-core Python.  Spaces too large
     for exact device integer arithmetic fall back to host-exact unranking
-    of the block start plus C-speed sequential stepping.
+    of the block start plus C-speed sequential stepping.  Each width ≥ 3
+    block counts its path into the active fit, ``stats["l0_enum"][width]``
+    (``"device int32"``, ``"device int64"`` or ``"host"``).
     """
 
     def __init__(self, m: int, n_dim: int, block: int):
@@ -294,10 +296,14 @@ class TupleEnumerator:
         if self.n_dim == 2:
             return self._pairs[lo : lo + cnt]
         from ..kernels import unrank  # deferred: kernels package imports core
+        from ..runtime import trace
 
-        if unrank.device_unrank_ok(self.m, self.n_dim):
-            return unrank.unrank_block(lo, cnt, self.m, self.n_dim)
-        return self._host_block(lo, cnt)
+        dtype = unrank.rank_dtype(self.m, self.n_dim)
+        if dtype is None:
+            trace.count(("l0_enum", self.n_dim, "host"))
+            return self._host_block(lo, cnt)
+        trace.count(("l0_enum", self.n_dim, f"device {dtype.name}"))
+        return unrank.unrank_block(lo, cnt, self.m, self.n_dim)
 
     def _host_block(self, lo: int, cnt: int) -> np.ndarray:
         """Host-exact fallback: unrank the block start, then step."""

@@ -101,7 +101,8 @@ class SissoFit:
     timings: Dict[str, float]
     problem: str = "regression"
     #: runtime counters: ``programs`` lowered/loaded/compiled per span,
-    #: ``l0_paths`` ℓ0 blocks per (width, scoring path), and
+    #: ``l0_paths`` ℓ0 blocks per (width, scoring path), ``l0_enum``
+    #: width ≥ 3 blocks per (width, enumeration path), and
     #: ``resilience`` retry/demotion accounting when SissoConfig.resilient
     #: is on
     stats: Dict[str, dict] = dataclasses.field(default_factory=dict)

@@ -76,21 +76,65 @@ def test_tuple_blocks_cover_exactly_once(n_dim):
     assert len(seen) == n_models(m, n_dim)
 
 
+@pytest.mark.parametrize("dtype", [jnp.int32, jnp.int64],
+                         ids=["int32", "int64"])
 @pytest.mark.parametrize("m,n", [(5, 3), (9, 3), (9, 4), (12, 2), (7, 1),
                                  (16, 4), (6, 5)])
-def test_unranking_matches_itertools(m, n):
-    """Device unranking is the exact lexicographic bijection: rank r maps to
-    the r-th tuple of ``itertools.combinations(range(m), n)``."""
+def test_unranking_matches_itertools(m, n, dtype):
+    """Device unranking is the exact lexicographic bijection in either
+    integer width: rank r maps to the r-th tuple of
+    ``itertools.combinations(range(m), n)``."""
     from repro.kernels.unrank import comb_exact, unrank_lex, unrank_lex_host
 
     want = np.asarray(list(__import__("itertools").combinations(range(m), n)),
                       np.int32)
     total = comb_exact(m, n)
     assert total == len(want) == n_models(m, n)
-    got = np.asarray(unrank_lex(jnp.arange(total), m, n))
-    assert np.array_equal(got, want)
+    got = unrank_lex(jnp.arange(total, dtype=dtype), m, n)
+    assert got.dtype == jnp.int32
+    assert np.array_equal(np.asarray(got), want)
     for r in (0, 1, total // 2, total - 1):
         assert unrank_lex_host(r, m, n) == list(want[r])
+
+
+def test_rank_dtype_follows_the_space():
+    """int32 where the space fits it (thermal at rung 1), int64 where only
+    int64 does (C(6000, 3)), the host from 2**62 on; each width-3 block
+    counts the path it took."""
+    from repro.core.l0 import TupleEnumerator
+    from repro.kernels.unrank import (
+        comb_exact, rank_dtype, unrank_lex, unrank_lex_host,
+    )
+    from repro.runtime import trace
+
+    assert rank_dtype(600, 3) == np.int32
+    assert rank_dtype(6000, 3) == np.int64
+    m_host = round((6 * 2**62) ** (1 / 3))  # C(m, 3) ≈ m³/6 meets 2**62
+    while comb_exact(m_host, 3) < 2**62:
+        m_host += 1
+    while comb_exact(m_host - 1, 3) >= 2**62:
+        m_host -= 1
+    assert rank_dtype(m_host - 1, 3) == np.int64
+    assert rank_dtype(m_host, 3) is None
+    # a space past int32 is refused in int32, never wrapped
+    with pytest.raises(ValueError, match="int32"):
+        unrank_lex(jnp.arange(4, dtype=jnp.int32), 6000, 3)
+
+    total = comb_exact(6000, 3)
+    ranks = [0, 1, total // 2, total - 2, total - 1]
+    got = np.asarray(unrank_lex(jnp.asarray(ranks, jnp.int64), 6000, 3))
+    assert [list(t) for t in got] == [unrank_lex_host(r, 6000, 3)
+                                      for r in ranks]
+
+    with trace.collecting() as rec:
+        for m, block in ((600, 4096), (6000, 64), (m_host, 8)):
+            enum = TupleEnumerator(m, 3, block)
+            for bi in (0, enum.n_blocks // 2, enum.n_blocks - 1):
+                lo = bi * block
+                assert np.array_equal(np.asarray(enum.block_tuples(bi)),
+                                      enum._host_block(lo, enum.count(bi)))
+    assert rec.stats()["l0_enum"] == {
+        3: {"device int32": 3, "device int64": 3, "host": 3}}
 
 
 def test_enumerator_blocks_are_rank_addressable():

@@ -166,6 +166,17 @@ def test_width3_enumeration_and_exact_rescore_compile(one_chip):
              _shape(one_chip, (block, n), jnp.int32))
 
 
+def test_thermal_width_unranking_compiles_gather_free(one_chip):
+    """The thermal width-3 enumerator (600 features, 65,536-rank blocks)
+    decodes in int32 with compares and reductions: no gather, no
+    emulated 64-bit value in the compiled program."""
+    m, n, block = 600, 3, 65536
+    hlo = unrank_lex.lower(_shape(one_chip, (block,), jnp.int32), m, n) \
+        .compile().as_text()
+    assert "gather(" not in hlo
+    assert "s64[" not in hlo
+
+
 def test_fused_sis_shard_map_compiles_on_four_chips(topo):
     mesh = Mesh(np.asarray(topo.devices).reshape(-1), ("data",))
     t, r, s = SIS_WIDTHS["thermal"]

@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 from repro.api import SissoRegressor
-from repro.core import SissoConfig, SissoSolver
+from repro.core import SissoConfig, SissoSolver, n_models
 from repro.runtime import trace
 
 #: every span of an estimator fit and its parent (stored features, so no
@@ -184,6 +184,24 @@ def test_l0_paths_of_a_reused_solver_are_per_fit():
     paths = second.stats["l0_paths"]
     assert sum(sum(p.values()) for p in paths.values()) \
         == _names(second)["sisso.l0.block"]
+
+
+def test_width3_blocks_count_their_enumeration_path(fitted):
+    """Each width-3 block of the sweep over the 18-feature dim-3 subspace
+    is enumerated on device in int32 and counted once; widths 1 and 2
+    slice host arrays and count nothing; ``l0_paths`` still counts every
+    block's scoring path where the backend records one (pallas)."""
+    fit, backend = fitted
+    enum, paths = fit.stats["l0_enum"], fit.stats["l0_paths"]
+    n_blocks = -(-n_models(18, 3) // 97)
+    assert enum == {3: {"device int32": n_blocks}}
+    if backend == "jnp":
+        assert paths == {}
+        return
+    assert sorted(paths) == [1, 2, 3]
+    assert sum(paths[3].values()) == n_blocks
+    assert sum(sum(p.values()) for p in paths.values()) \
+        == _names(fit)["sisso.l0.block"]
 
 
 def test_profiler_trace_shows_the_spans(tmp_path):
