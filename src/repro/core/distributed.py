@@ -6,6 +6,9 @@ Mapping (DESIGN.md §4):
 
 The heavy inner loops are collective-free; only O(k) score/argmin payloads
 cross devices (vs the paper's serial gather/redistribute of features).
+Each k-sized merge is counted on the host from its static shapes
+(:func:`count_merge`): ``SissoFit.stats["sharded"]["merges"][phase]`` and
+the winner rows it all-gathered, ``["gathered_rows"][phase]``.
 """
 from __future__ import annotations
 
@@ -18,6 +21,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..runtime import trace
 from .sis import (
     ScoreContext, TaskLayout, scores_from_reductions, screen_reductions,
 )
@@ -35,6 +39,14 @@ def _sample_axis(mesh: Mesh) -> Optional[str]:
 def _n_dp(mesh: Mesh) -> int:
     dp = _dp_axes(mesh)
     return int(np.prod([mesh.shape[a] for a in dp])) if dp else 1
+
+
+def count_merge(phase: str, mesh: Mesh, k_local: int) -> None:
+    """Count one k-sized all-gather merge of ``phase`` (``sis`` or ``l0``)
+    in the active fit: every shard contributes ``k_local`` winner rows, so
+    the gather carries ``n_shards * k_local`` rows (score and index)."""
+    trace.count(("sharded", "merges", phase))
+    trace.count(("sharded", "gathered_rows", phase), _n_dp(mesh) * k_local)
 
 
 def _shard_index(dp: Tuple[str, ...]):
@@ -154,6 +166,7 @@ def sis_topk_sharded(
     k_local = min(int(n_keep), f // nd)
     k_merge = min(int(n_keep), nd * k_local)
     fn = _sis_topk_fn(mesh, ctx.n_residuals, k_local, k_merge)
+    count_merge("sis", mesh, k_local)
     vals, idx = fn(
         x,
         jnp.asarray(ctx.membership, x.dtype),
@@ -433,6 +446,7 @@ def overlap_sis_topk_sharded(
     k_local = min(int(n_keep), f // nd)
     k_merge = min(int(n_keep), nd * k_local)
     fn = _overlap_sis_fn(mesh, (k_local, k_merge))
+    count_merge("sis", mesh, k_local)
     vals, idx = fn(
         x,
         jnp.asarray(ctx.membership, x.dtype),
@@ -556,6 +570,7 @@ def fused_sis_topk_sharded(
         float(l_bound), float(u_bound), int(block_b), bool(interpret),
         int(k_epi),
     )
+    count_merge("sis", mesh, k_local)
     vals, idx = fn(a_p, b_p, m_p, yt_p, cnt, jnp.asarray(nv))
     return np.asarray(vals, np.float64), np.asarray(idx)
 

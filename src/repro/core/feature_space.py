@@ -266,61 +266,66 @@ class FeatureSpace:
     # ------------------------------------------------------------------
     # candidate enumeration (host rules only — paper P2 "CPU side")
     # ------------------------------------------------------------------
+    @trace.span("sisso.fc.enumerate")
     def _host_valid_children(
         self, op: Operator, rung: int
     ) -> Tuple[np.ndarray, np.ndarray, List[Unit]]:
-        """Enumerate child index pairs passing unit/domain/structural rules."""
+        """Enumerate child index pairs passing unit/domain/structural rules.
+
+        Each child set of rung ``rung`` comes once: a unary operator takes
+        each rung ``rung - 1`` feature; a binary operator pairs each rung
+        ``rung - 1`` feature ``fa`` with itself (where the operator allows
+        it), with each later rung ``rung - 1`` feature and with each
+        lower-rung feature, as ``(fa, fb)`` and, for a non-commutative
+        operator, also ``(fb, fa)``.  Unit rules are evaluated once per
+        pair of child units.
+        """
         feats = self.features
         prev = [f for f in feats if f.rung == rung - 1]
         lower = [f for f in feats if f.rung < rung - 1]
         ia: List[int] = []
         ib: List[int] = []
         units: List[Unit] = []
+        # the unit rule, once per pair of distinct child units
+        distinct: Dict[Unit, int] = {}
+        unit_id = {f.fid: distinct.setdefault(f.unit, len(distinct))
+                   for f in prev + lower}
+        unit_of: Dict[Tuple[int, int], Optional[Unit]] = {}
+        meta = {f.fid: f.meta for f in prev + lower}
+
+        def add(fa: Feature, fb: Feature) -> None:
+            key = (unit_id[fa.fid], unit_id[fb.fid])
+            if key not in unit_of:
+                unit_of[key] = op.unit_rule(fa.unit) if op.arity == 1 \
+                    else op.unit_rule(fa.unit, fb.unit)
+            u = unit_of[key]
+            if u is None:
+                self.n_rejected["unit"] += 1
+            elif not (op.domain_rule(meta[fa.fid]) if op.arity == 1
+                      else op.domain_rule(meta[fa.fid], meta[fb.fid])):
+                self.n_rejected["domain"] += 1
+            else:
+                ia.append(fa.fid)
+                ib.append(fb.fid)
+                units.append(u)
+
         if op.arity == 1:
             for f in prev:
                 if ops_mod.is_redundant_unary(op.op_id, f.op_id):
                     self.n_rejected["redundant"] += 1
                     continue
-                u = op.unit_rule(f.unit)
-                if u is None:
-                    self.n_rejected["unit"] += 1
-                    continue
-                if not op.domain_rule(f.meta):
-                    self.n_rejected["domain"] += 1
-                    continue
-                ia.append(f.fid)
-                ib.append(f.fid)
-                units.append(u)
+                add(f, f)
         else:
-            # max(rung_a, rung_b) == rung - 1  =>  at least one child in prev.
-            for fa in prev:
-                others = prev + lower
-                for fb in others:
-                    if op.commutative and fb.fid < fa.fid:
-                        continue  # canonical order for commutative ops
-                    if fa.fid == fb.fid and not op.allow_same_child:
+            # max(rung_a, rung_b) == rung - 1  =>  at least one child in prev
+            for i, fa in enumerate(prev):
+                for fb in prev[i:] + lower:
+                    if fb is fa:
+                        if op.allow_same_child:
+                            add(fa, fa)
                         continue
-                    u = op.unit_rule(fa.unit, fb.unit)
-                    if u is None:
-                        self.n_rejected["unit"] += 1
-                        continue
-                    if not op.domain_rule(fa.meta, fb.meta):
-                        self.n_rejected["domain"] += 1
-                        continue
-                    ia.append(fa.fid)
-                    ib.append(fb.fid)
-                    units.append(u)
-                    if not op.commutative and fa.fid != fb.fid:
-                        # also the swapped order if it is valid
-                        u2 = op.unit_rule(fb.unit, fa.unit)
-                        if u2 is not None and op.domain_rule(fb.meta, fa.meta):
-                            ia.append(fb.fid)
-                            ib.append(fa.fid)
-                            units.append(u2)
-                        elif u2 is None:
-                            self.n_rejected["unit"] += 1
-                        else:
-                            self.n_rejected["domain"] += 1
+                    add(fa, fb)
+                    if not op.commutative:
+                        add(fb, fa)
         ia_arr = np.asarray(ia, dtype=np.int32)
         ib_arr = np.asarray(ib, dtype=np.int32)
         if self.max_pairs_per_op is not None and len(ia_arr) > self.max_pairs_per_op:

@@ -286,11 +286,12 @@ def sis_screen(
         residuals, y, layout, dtype=engine.backend.score_ctx_dtype
     )
     x = fspace.values_matrix().astype(np.float64)
+    from ..engine.streaming import BlockPrefetcher
 
-    top = TopK(k=n_sis * overselect)
-
-    # 1) materialized features (all rungs kept in memory)
-    if len(x):
+    def window(k: int) -> TopK:
+        """The ``k`` best screenable features and deferred candidates."""
+        top = TopK(k=k)
+        # 1) materialized features (all rungs kept in memory)
         for lo in range(0, len(x), batch):
             hi = min(lo + batch, len(x))
             # mask of screenable rows: already-selected features must not
@@ -301,7 +302,7 @@ def sis_screen(
                 for fid in exclude:
                     if lo <= fid < hi:
                         blk_mask[fid - lo] = False
-            res = engine.sis_scores(x[lo:hi], ctx, n_keep=top.k, mask=blk_mask)
+            res = engine.sis_scores(x[lo:hi], ctx, n_keep=k, mask=blk_mask)
             if isinstance(res, ReducedBlock):
                 top.push_reduced(res, lambda i, lo=lo: ("feat", lo + i))
             else:
@@ -309,49 +310,65 @@ def sis_screen(
                 top.push(np.asarray(res, np.float64),
                          [("feat", fid) for fid in range(lo, hi)])
 
-    # 2) deferred last-rung candidates: generate -> score -> discard.
-    #    Double-buffered (engine/streaming.py): block k+1's child-row
-    #    gather and device dispatch overlap block k's scoring, and the
-    #    host top-k push runs off the critical path.
-    from ..engine.streaming import BlockPrefetcher
-
-    def score_deferred(blk: CandidateBlock):
-        return engine.sis_scores_deferred(
-            blk.op_id, x[blk.child_a], x[blk.child_b], ctx,
-            fspace.l_bound, fspace.u_bound, n_keep=top.k,
-        )
-
-    for blk, s in BlockPrefetcher(
-        score_deferred, fspace.iter_candidate_batches(batch), span="sisso.sis"
-    ):
-        if isinstance(s, ReducedBlock):
-            top.push_reduced(
-                s,
-                lambda i, blk=blk: (
-                    "cand", blk.op_id, int(blk.child_a[i]), int(blk.child_b[i])
-                ),
+        # 2) deferred last-rung candidates: generate -> score -> discard.
+        #    Double-buffered (engine/streaming.py): block k+1's child-row
+        #    gather and device dispatch overlap block k's scoring, and the
+        #    host top-k push runs off the critical path.
+        def score_deferred(blk: CandidateBlock):
+            return engine.sis_scores_deferred(
+                blk.op_id, x[blk.child_a], x[blk.child_b], ctx,
+                fspace.l_bound, fspace.u_bound, n_keep=k,
             )
-        else:
-            tags = [
-                ("cand", blk.op_id, int(a), int(b))
-                for a, b in zip(blk.child_a, blk.child_b)
-            ]
-            top.push(s, tags)
 
-    # 3) materialize winners, skipping dups, until n_sis collected
+        for blk, s in BlockPrefetcher(
+            score_deferred, fspace.iter_candidate_batches(batch),
+            span="sisso.sis",
+        ):
+            if isinstance(s, ReducedBlock):
+                top.push_reduced(
+                    s,
+                    lambda i, blk=blk: (
+                        "cand", blk.op_id, int(blk.child_a[i]),
+                        int(blk.child_b[i])
+                    ),
+                )
+            else:
+                tags = [
+                    ("cand", blk.op_id, int(a), int(b))
+                    for a, b in zip(blk.child_a, blk.child_b)
+                ]
+                top.push(s, tags)
+        return top
+
+    # 3) materialize winners in score order, skipping value-duplicates of
+    #    existing features, until n_sis are collected.  A deferred
+    #    candidate selected at an earlier dimension, and its duplicates,
+    #    are screened again and take window slots; while the window ran
+    #    out before n_sis were new, the screen runs again with a window
+    #    twice as wide, and only winners not examined before are taken.
     selected: List[Feature] = []
     sel_scores: List[float] = []
-    for score, tag in zip(top.scores, top.tags):
-        if len(selected) >= n_sis:
+    examined: Set[tuple] = set()
+    k = n_sis * overselect
+    while True:
+        top = window(k)
+        for score, tag in zip(top.scores, top.tags):
+            if len(selected) >= n_sis:
+                break
+            if tag in examined:
+                continue
+            examined.add(tag)
+            if tag[0] == "feat":
+                feat = fspace.features[tag[1]]
+                if feat.fid in exclude:
+                    continue
+            else:
+                feat = fspace.materialize_candidate(tag[1], tag[2], tag[3])
+                if feat is None:  # value-duplicate of an existing feature
+                    continue
+            selected.append(feat)
+            sel_scores.append(float(score))
+        if len(selected) >= n_sis or len(top.tags) < k:
             break
-        if tag[0] == "feat":
-            feat = fspace.features[tag[1]]
-            if feat.fid in exclude:
-                continue
-        else:
-            feat = fspace.materialize_candidate(tag[1], tag[2], tag[3])
-            if feat is None:  # value-duplicate of an existing feature
-                continue
-        selected.append(feat)
-        sel_scores.append(float(score))
+        k *= 2
     return selected, np.asarray(sel_scores)

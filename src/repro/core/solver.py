@@ -102,9 +102,11 @@ class SissoFit:
     problem: str = "regression"
     #: runtime counters: ``programs`` lowered/loaded/compiled per span,
     #: ``l0_paths`` ℓ0 blocks per (width, scoring path), ``l0_enum``
-    #: width ≥ 3 blocks per (width, enumeration path), and
-    #: ``resilience`` retry/demotion accounting when SissoConfig.resilient
-    #: is on
+    #: width ≥ 3 blocks per (width, enumeration path), ``sis_paths``
+    #: deferred SIS blocks per scoring path, ``sharded`` (distributed
+    #: engines: ``devices``, k-sized ``merges`` and ``gathered_rows`` per
+    #: phase), and ``resilience`` retry/demotion accounting when
+    #: SissoConfig.resilient is on
     stats: Dict[str, dict] = dataclasses.field(default_factory=dict)
     #: the fit's spans and counters (runtime/trace.py)
     trace: Optional[trace.FitTrace] = None
@@ -198,6 +200,10 @@ class SissoSolver:
             if task_ids is not None
             else TaskLayout.single(s)
         )
+        # the devices a distributed engine shards candidates over
+        shards = getattr(self.engine.backend, "n_shards", None)
+        if shards:
+            trace.count(("sharded", "devices"), shards)
         # ---- phase 1: feature creation -------------------------------
         with trace.span("sisso.fc"):
             fspace = FeatureSpace(

@@ -35,6 +35,11 @@ from ..core.sis import ReducedBlock, ScoreContext, TaskLayout
 from ..core.l0 import GramStats
 from ..runtime import trace
 
+#: ``stats["sis_paths"]`` names of the paths a deferred block can take
+SIS_FUSED = "fused kernel"
+SIS_FUSED_SHARDED = "fused kernel in shard_map"
+SIS_EVAL_SCREEN = "evaluate then screen"
+
 
 @dataclasses.dataclass
 class L0Problem:
@@ -163,6 +168,7 @@ class Backend(abc.ABC):
         Default composition: evaluate, apply value rules, score.  Backends
         with ``fused_deferred`` overrule this with a fused kernel.
         """
+        self.record_sis_path(SIS_EVAL_SCREEN)
         values, valid = self.eval_block(op_id, a, b, l_bound, u_bound)
         scores = self.sis_scores(values, ctx)
         return np.where(valid, scores, -np.inf)
@@ -262,6 +268,11 @@ class Backend(abc.ABC):
         for a top-``n_keep`` (backends whose ℓ0 kernel is an fp32 prescreen
         and the distribution wrapper composed over them)."""
         return 2 * int(n_keep)
+
+    def record_sis_path(self, path: str) -> None:
+        """Count one deferred-candidate block scored by ``path``, reported
+        as ``SissoFit.stats["sis_paths"]`` (``{path: blocks}``)."""
+        trace.count(("sis_paths", path))
 
     def record_l0_path(self, width: int, path: str) -> None:
         """Count one ℓ0 block of tuple ``width`` scored by ``path``.

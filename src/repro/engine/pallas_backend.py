@@ -56,7 +56,7 @@ from ..core.sis import (
 from ..kernels import autotune
 from ..kernels import ops as kops
 from ..runtime import trace
-from .base import L0Problem
+from .base import SIS_FUSED, L0Problem
 from .jnp_backend import JnpBackend
 
 
@@ -137,6 +137,7 @@ class PallasBackend(JnpBackend):
             return super().sis_scores_deferred(
                 op_id, a, b, ctx, l_bound, u_bound
             )
+        self.record_sis_path(SIS_FUSED)
         scores = kops.fused_gen_sis(
             int(op_id), jnp.asarray(a), jnp.asarray(b),
             ctx, l_bound=l_bound, u_bound=u_bound,
@@ -173,6 +174,7 @@ class PallasBackend(JnpBackend):
             return super().sis_topk_deferred(
                 op_id, a, b, ctx, l_bound, u_bound, n_keep
             )
+        self.record_sis_path(SIS_FUSED)
         a = jnp.asarray(a)
         b = jnp.asarray(b)
         block_b, k_epi = self._tuned_sis_cfg(

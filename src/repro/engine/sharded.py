@@ -35,16 +35,17 @@ import numpy as np
 from jax.sharding import Mesh
 
 from ..core.distributed import (
-    _dp_axes, _sample_axis, fused_sis_topk_sharded, gram_operands,
-    gram_topk_scorer, l0_pair_sses_sharded, make_l0_topk_fn,
+    _dp_axes, _sample_axis, count_merge, fused_sis_topk_sharded,
+    gram_operands, gram_topk_scorer, l0_pair_sses_sharded, make_l0_topk_fn,
     make_l0_topk_reduced_fn, overlap_operands, overlap_sis_scores_sharded,
-    overlap_sis_topk_sharded,
-    overlap_topk_scorer, qr_topk_scorer, sis_scores_sharded,
-    sis_topk_sharded,
+    overlap_sis_topk_sharded, overlap_topk_scorer, qr_topk_scorer,
+    sis_scores_sharded, sis_topk_sharded,
 )
 from ..core.l0 import compute_gram_stats
 from ..core.sis import ReducedBlock, ScoreContext
-from .base import Backend, Engine, L0Problem
+from .base import (
+    SIS_EVAL_SCREEN, SIS_FUSED_SHARDED, Backend, Engine, L0Problem,
+)
 
 
 def default_mesh() -> Mesh:
@@ -106,6 +107,11 @@ class ShardedExecution(Backend):
         self._nd = int(np.prod([self.mesh.shape[a] for a in dp]))
         # guards per-problem compiled-reducer fills (prefetch worker threads)
         self._cache_lock = threading.Lock()
+
+    @property
+    def n_shards(self) -> int:
+        """Devices the candidate axes shard over."""
+        return self._nd
 
     def set_precision(self, precision: str) -> "ShardedExecution":
         super().set_precision(precision)
@@ -189,6 +195,7 @@ class ShardedExecution(Backend):
     def sis_scores_deferred(self, op_id, a, b, ctx, l_bound, u_bound):
         # full-vector compose path (host-merge callers): inner eval,
         # sharded scoring
+        self.record_sis_path(SIS_EVAL_SCREEN)
         values, valid = self.inner.eval_block(op_id, a, b, l_bound, u_bound)
         scores = self.sis_scores(values, ctx)
         return np.where(valid, scores, -np.inf)
@@ -197,6 +204,7 @@ class ShardedExecution(Backend):
                           n_keep) -> ReducedBlock:
         if self.inner.fused_deferred and _sample_axis(self.mesh) is None \
                 and ctx.problem in self.inner.kernel_problems:
+            self.record_sis_path(SIS_FUSED_SHARDED)
             vals, idx = fused_sis_topk_sharded(
                 self.mesh, op_id, jnp.asarray(a), jnp.asarray(b), ctx,
                 n_keep, l_bound, u_bound,
@@ -210,6 +218,7 @@ class ShardedExecution(Backend):
                 indices=idx[keep].astype(np.int64), scores=vals[keep],
                 n_source=len(a),
             )
+        self.record_sis_path(SIS_EVAL_SCREEN)
         values, valid = self.inner.eval_block(op_id, a, b, l_bound, u_bound)
         return self.sis_topk(values, ctx, n_keep, mask=valid)
 
@@ -247,7 +256,7 @@ class ShardedExecution(Backend):
         fp32 bound, so the wrapper rescores merged survivors in fp64 before
         the final ranking.  Falls back to the full-vector traceable scorers
         (overlap / Gram closed form / QR) when the inner backend has none.
-        Returns ``(fn, operands, prescreen, k_merge)``.
+        Returns ``(fn, operands, prescreen, k_local)``.
         """
         key = ("sharded_l0_topk", width, int(n_keep), int(b_shard))
         with self._cache_lock:
@@ -261,7 +270,7 @@ class ShardedExecution(Backend):
                     k_merge = min(survivors, self._nd * k_local)
                     fn = make_l0_topk_reduced_fn(
                         self.mesh, reducer, k_local, k_merge, len(operands))
-                    entry = prob.cache[key] = (fn, operands, True, k_merge)
+                    entry = prob.cache[key] = (fn, operands, True, k_local)
                 else:
                     if prob.problem == "classification":
                         scorer = overlap_topk_scorer()
@@ -277,7 +286,7 @@ class ShardedExecution(Backend):
                     k_merge = min(int(n_keep), self._nd * k_local)
                     fn = make_l0_topk_fn(self.mesh, scorer, k_local, k_merge,
                                          len(operands))
-                    entry = prob.cache[key] = (fn, operands, False, k_merge)
+                    entry = prob.cache[key] = (fn, operands, False, k_local)
         return entry
 
     def l0_topk(self, prob: L0Problem, tuples, n_keep: int) -> ReducedBlock:
@@ -296,8 +305,9 @@ class ShardedExecution(Backend):
             tuples = jnp.concatenate([tuples, fill], axis=0)
         valid = np.zeros((bp,), bool)
         valid[:b] = True
-        fn, operands, prescreen, _ = self._l0_reducer(
+        fn, operands, prescreen, k_local = self._l0_reducer(
             prob, width, int(n_keep), bp // self._nd)
+        count_merge("l0", self.mesh, k_local)
         out = fn(tuples, jnp.asarray(valid), *operands)
         sses, idx = np.asarray(out[0], np.float64), np.asarray(out[1])
         keep = np.isfinite(sses)

@@ -33,25 +33,33 @@ open in the calling thread, as ``stats["programs"][span][kind]``.
 
 The spans of a fit (PERF.md lists them):
 
-=====================  ================================================
-span                   interval
-=====================  ================================================
-``sisso.fit``          the estimator's whole ``fit`` (or the solver's)
-``sisso.fc``           ``FeatureSpace`` construction and ``generate()``
-``sisso.fc.eval``      one ``eval_candidates`` call (device + read-back)
-``sisso.fc.admit``     one ``admit_block`` call
-``sisso.sis``          one dimension's screen
-``sisso.sis.block``    one deferred-candidate block, on a block worker
-``sisso.sis.wait``     the screen waiting on a block worker
-``sisso.l0``           one dimension's ℓ0 search
-``sisso.l0.prepare``   the Gram statistics (``engine.prepare_l0``)
-``sisso.l0.block``     one tuple block's scoring, on a block worker
-``sisso.l0.rescore``   one exact fp64 rescore (pallas backend)
-``sisso.l0.wait``      the merge loop waiting on a block worker
-``sisso.l0.merge``     one block's host top-k merge
-``sisso.models``       one dimension's models and next residuals
-``sisso.descriptor``   compiling and replaying every model's descriptor
-=====================  ================================================
+=======================  ================================================
+span                     interval
+=======================  ================================================
+``sisso.fit``            the estimator's whole ``fit`` (or the solver's)
+``sisso.fc``             ``FeatureSpace`` construction and ``generate()``
+``sisso.fc.enumerate``   one operator's child sets at one rung (host rules)
+``sisso.fc.eval``        one ``eval_candidates`` call (device + read-back)
+``sisso.fc.admit``       one ``admit_block`` call
+``sisso.sis``            one dimension's screen
+``sisso.sis.block``      one deferred-candidate block, on a block worker
+``sisso.sis.wait``       the screen waiting on a block worker
+``sisso.l0``             one dimension's ℓ0 search
+``sisso.l0.prepare``     the Gram statistics (``engine.prepare_l0``)
+``sisso.l0.block``       one tuple block's scoring, on a block worker
+``sisso.l0.rescore``     one exact fp64 rescore (pallas backend)
+``sisso.l0.wait``        the merge loop waiting on a block worker
+``sisso.l0.merge``       one block's host top-k merge
+``sisso.models``         one dimension's models and next residuals
+``sisso.descriptor``     compiling and replaying every model's descriptor
+=======================  ================================================
+
+The counters of a fit: ``programs`` (above); ``l0_paths`` and ``l0_enum``
+(ℓ0 blocks per width and scoring or enumeration path); ``sis_paths``
+(deferred SIS blocks per scoring path: ``fused kernel``, ``fused kernel
+in shard_map`` or ``evaluate then screen``); ``sharded`` (a distributed
+engine: ``devices``, and per phase the k-sized ``merges`` and the winner
+rows they all-gathered, ``gathered_rows``).
 """
 from __future__ import annotations
 
@@ -75,6 +83,8 @@ TIMED_SPANS = {
     "models": "sisso.models",
     "descriptor": "sisso.descriptor",
     "l0_wait": "sisso.l0.wait",
+    "fc_enumerate": "sisso.fc.enumerate",
+    "sis_wait": "sisso.sis.wait",
 }
 PROGRAM_KINDS = ("lowered", "cache_loads", "compiled")
 #: how many finished fits ``recent_fits()`` keeps

@@ -56,6 +56,17 @@ def test_sharded_execution_engine_8dev():
     assert "reduced-block contract (O(k) winners): OK" in out
 
 
+def test_sharded_pallas_fit_4dev_gives_the_reference_models():
+    """A whole fit on sharded:pallas (interpret) over a forced 4-device
+    mesh, rung 2 with the last rung on the fly: every dimension keeps
+    n_sis features and the models are the plain reference's; every
+    deferred block runs the fused kernel inside shard_map and each ℓ0
+    block is one k-sized merge."""
+    out = _run("check_sharded_fit.py")
+    assert "sharded:pallas fit (4) == reference models: OK" in out
+    assert "sharded counters (4 devices): OK" in out
+
+
 # ---------------------------------------------------------------------------
 # in-process (1-device mesh) regression + contract tests for the
 # distribution layer: the same code path as multi-shard, minus the padding

@@ -122,3 +122,68 @@ def test_eval_candidates_validity_flags(rng):
     from repro.core.operators import DIV
     vals, valid = fs.eval_candidates(DIV, np.array([1]), np.array([0]))
     assert not valid[0]  # b/a crosses a zero denominator -> inf values
+
+
+def _reference_child_sets(space, op_names):
+    """Rung-2 child index sets of each operator of the plain reference
+    (benchmarks/suite/reference.py) over its rung 0-1 ``space``, after its
+    unit, domain and simplification rules and before value rules."""
+    from benchmarks.suite import reference
+    from benchmarks.suite.expr import OPS, SIMPLIFIES
+
+    prev = np.nonzero(space.rungs == 1)[0]
+    lower = np.nonzero(space.rungs == 0)[0]
+    lo, hi = space.values.min(axis=1), space.values.max(axis=1)
+    out = {}
+    for name in op_names:
+        op = OPS[name]
+        out[name] = [
+            c for c in reference._candidates(op, prev, lower)
+            if not (op.arity == 1 and (name, space.roots[c[0]]) in SIMPLIFIES)
+            and op.unit(*(space.units[i] for i in c)) is not None
+            and op.domain(*((lo[i], hi[i]) for i in c))]
+    return out
+
+
+def test_rung2_child_sets_match_the_reference():
+    """Before value rules, each operator's rung-2 child sets are the plain
+    reference's, each once: every unordered pair of a commutative
+    operator, pairs of a rung-1 feature with a primary among them, and
+    every ordered pair of the others (five primaries with units)."""
+    import _reference_case as case
+    from benchmarks.suite import reference
+    from repro.core import operators
+
+    d = case.data()
+    ops = case.CASE["op_names"]
+    fs = FeatureSpace(d.x, d.names, [Unit(tuple(u), d.basis) for u in d.units],
+                      op_names=ops, max_rung=2, l_bound=case.CASE["l_bound"],
+                      u_bound=case.CASE["u_bound"], on_the_fly_last_rung=True,
+                      engine="jnp").generate()
+    space = reference.build_space(d.x, d.names, d.units, ops, 1,
+                                  case.CASE["l_bound"], case.CASE["u_bound"])
+    # the rung 0-1 spaces hold the same features: the reference's index of
+    # each of the program's rows, by value
+    x = fs.values_matrix()
+    assert x.shape == space.values.shape
+    near = np.abs(x[:, None, :] - space.values[None, :, :]).max(axis=2) \
+        <= 1e-12 * np.abs(x).max(axis=1)[:, None]
+    assert (near.sum(axis=1) == 1).all()
+    ref_index = near.argmax(axis=1)
+    assert sorted(ref_index) == list(range(len(x)))
+    want = _reference_child_sets(space, ops)
+    got = {name: [] for name in ops}
+    for blk in fs.candidates:
+        name = operators.OPS[blk.op_id].name
+        arity = operators.OPS[blk.op_id].arity
+        got[name] += [tuple(int(ref_index[r]) for r in (a, b)[:arity])
+                      for a, b in zip(blk.child_a, blk.child_b)]
+    for name in ops:
+        commutative = operators.OP_BY_NAME[name].commutative
+        canon = [tuple(sorted(c)) if commutative else c for c in got[name]]
+        assert len(set(canon)) == len(canon), name        # each once
+        assert set(canon) == {tuple(sorted(c)) if commutative else c
+                              for c in want[name]}, name
+    # the pairs of a rung-1 feature with a primary are there
+    primaries = set(np.nonzero(space.rungs == 0)[0])
+    assert any(a in primaries or b in primaries for a, b in got["mul"])

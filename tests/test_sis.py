@@ -105,3 +105,62 @@ def test_sis_screen_otf_matches_materialized(rng):
     fo, so = sis_screen(fs_o, y[None, :], TaskLayout.single(80), 8, set())
     assert [f.expr for f in fm] == [f.expr for f in fo]
     np.testing.assert_allclose(sm, so, rtol=1e-9)
+
+
+def _value_classes(selected):
+    """Each selected feature's values up to scale, sign and offset (one
+    model span), rounded: the set a selection stands for."""
+    out = set()
+    for s in selected:
+        z = s.values - s.values.mean()
+        z = z / np.linalg.norm(z)
+        z = z * np.sign(z[np.argmax(np.abs(z) > 1e-3)])
+        out.add(tuple(np.round(z, 8)))
+    return out
+
+
+@pytest.fixture(scope="module")
+def otf_and_stored_fits():
+    import _reference_case as case
+
+    d = case.data()
+    ref = case.reference_campaign(case.CASE, d)
+    fits = {}
+    for otf in (True, False):
+        settings = dict(case.CASE, on_the_fly_last_rung=otf)
+        record, _ = case.fit(settings, d)
+        fits[otf] = (record, case.readings(settings, d, record.answers, ref))
+    return fits, ref
+
+
+@pytest.mark.parametrize("on_the_fly", [True, False],
+                         ids=["on-the-fly", "stored"])
+def test_each_dimension_keeps_n_sis_features_as_the_reference(
+        otf_and_stored_fits, on_the_fly):
+    """Rung 2, last rung on the fly or stored: every dimension keeps
+    ``n_sis`` new features, the ones the plain reference selects (no
+    reference feature it keeps scores below the reference's cut), and
+    the models are the reference's.  On the fly, features selected at an
+    earlier dimension and their value-duplicates are screened again and
+    take window slots, so the screen has to widen its window."""
+    import _reference_case as case
+
+    fits, ref = otf_and_stored_fits
+    record, readings = fits[on_the_fly]
+    n_sis = case.CASE["n_sis"]
+    assert {d: len(s) for d, s in record.answers.selected.items()} \
+        == {d: n_sis for d in range(1, case.CASE["n_dim"] + 1)}
+    assert {d: len(dim.selected) for d, dim in ref.dims.items()} \
+        == {d: n_sis for d in ref.dims}
+    # fp64 on both sides, so only rounding separates them
+    assert readings["sis_gap"] <= 1e-12
+    assert readings["fc_gap"] <= 1e-12
+    assert readings["l0_gap"] <= 1e-9
+
+
+def test_on_the_fly_and_stored_select_the_same_features(otf_and_stored_fits):
+    fits, _ = otf_and_stored_fits
+    otf, stored = fits[True][0].answers, fits[False][0].answers
+    for d in stored.selected:
+        assert _value_classes(otf.selected[d]) \
+            == _value_classes(stored.selected[d]), d

@@ -86,5 +86,5 @@ def test_timings_recorded(rng):
     # a solver run alone records its own fit; the descriptor stage
     # belongs to the estimator
     assert set(fit.timings) == {"fit", "fc", "sis", "l0", "models",
-                                "l0_wait"}
+                                "l0_wait", "fc_enumerate"}
     assert all(v >= 0 for v in fit.timings.values())

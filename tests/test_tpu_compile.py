@@ -205,6 +205,34 @@ def test_fused_sis_shard_map_compiles_on_four_chips(topo):
     assert "all-gather" in hlo or "all-reduce" in hlo
 
 
+@pytest.mark.parametrize("k_merge", [400, 800])
+def test_fused_sis_shard_map_compiles_for_a_full_block(topo, k_merge):
+    """A full deferred block of the on-the-fly thermal screen (65,536
+    candidates, a quarter per chip) at the window of 2 n_sis = 400 and at
+    the widened window of 800 that a dimension falls back on: each grid
+    step keeps all of its 256 rows and the merge takes the window."""
+    mesh = Mesh(np.asarray(topo.devices).reshape(-1), ("data",))
+    t, r, s = SIS_WIDTHS["thermal"]
+    s_pad, block_b, b_pad = kops._pad_to(s, 128), 256, 65536
+    fn = _fused_sis_topk_fn(mesh, om.DIV, r, block_b, k_merge, 1e-5, 1e8,
+                            block_b, False, block_b)
+
+    def on(spec, shape, dtype):
+        return _shape(NamedSharding(mesh, spec), shape, dtype)
+
+    f32 = jnp.float32
+    hlo = fn.lower(
+        on(P("data", None), (b_pad, s_pad), f32),
+        on(P("data", None), (b_pad, s_pad), f32),
+        on(P(None, None), (t, s_pad), f32),
+        on(P(None, None), (r * t, s_pad), f32),
+        on(P(None, None), (1, t), f32),
+        on(P("data"), (4,), jnp.int32),
+    ).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    assert f"f32[{b_pad // 4},{s_pad}]" in hlo.split("\n", 1)[0]
+
+
 def test_l0_reduced_shard_map_compiles_on_four_chips(topo):
     """The ``sharded:pallas`` width-3 ℓ0 reducer at the thermal width: the
     Gram-gather top-k kernel per chip, the winners' all-gather merge and

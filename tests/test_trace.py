@@ -17,6 +17,7 @@ from repro.runtime import trace
 PARENTS = {
     "sisso.fit": None,
     "sisso.fc": "sisso.fit",
+    "sisso.fc.enumerate": "sisso.fc",
     "sisso.fc.eval": "sisso.fc",
     "sisso.fc.admit": "sisso.fc",
     "sisso.sis": "sisso.fit",
@@ -202,6 +203,69 @@ def test_width3_blocks_count_their_enumeration_path(fitted):
     assert sum(paths[3].values()) == n_blocks
     assert sum(sum(p.values()) for p in paths.values()) \
         == _names(fit)["sisso.l0.block"]
+
+
+def test_enumeration_spans_one_per_operator_and_rung(fitted):
+    """``sisso.fc.enumerate`` spans each operator's child enumeration at
+    each rung (three operators, rung 1), summed as ``fc_enumerate``; a
+    stored space screens no deferred block, so nothing waits on one and no
+    SIS path or distribution counter is recorded."""
+    fit, _ = fitted
+    assert _names(fit)["sisso.fc.enumerate"] == 3
+    assert fit.timings["fc_enumerate"] == pytest.approx(
+        fit.trace.seconds("sisso.fc.enumerate"))
+    assert 0 <= fit.timings["fc_enumerate"] <= fit.timings["fc"]
+    assert "sis_wait" not in fit.timings
+    assert "sis_paths" not in fit.stats and "sharded" not in fit.stats
+
+
+OTF_PATHS = {"jnp": "evaluate then screen", "pallas": "fused kernel",
+             "sharded:pallas": "fused kernel in shard_map"}
+
+
+@pytest.fixture(scope="module", params=sorted(OTF_PATHS))
+def otf_fitted(request):
+    X, y = _data()
+    est = SissoRegressor(max_rung=1, n_dim=2, n_sis=6, n_residual=3,
+                         op_names=("add", "sub", "mul"),
+                         on_the_fly_last_rung=True, backend=request.param,
+                         l0_block=97)
+    return est.fit(X, y).fit_result_, request.param
+
+
+def test_deferred_blocks_count_their_scoring_path(otf_fitted):
+    """Every deferred-candidate block is counted once, under the path that
+    scored it, and the screen's waits on the block workers sum to
+    ``sis_wait``."""
+    fit, backend = otf_fitted
+    names = _names(fit)
+    assert names["sisso.sis.block"] == names["sisso.sis.wait"] >= 2 * 3
+    assert fit.stats["sis_paths"] == {
+        OTF_PATHS[backend]: names["sisso.sis.block"]}
+    assert fit.timings["sis_wait"] == pytest.approx(
+        fit.trace.seconds("sisso.sis.wait"))
+    assert 0 <= fit.timings["sis_wait"] <= fit.timings["sis"]
+
+
+def test_sharded_merges_are_counted_from_static_shapes(otf_fitted):
+    """A distributed fit records its devices, one k-sized merge per
+    screened block and per ℓ0 block, and the winner rows each merge
+    gathered from every shard; other engines record none."""
+    fit, backend = otf_fitted
+    if backend != "sharded:pallas":
+        assert "sharded" not in fit.stats
+        return
+    sharded, names = fit.stats["sharded"], _names(fit)
+    devices = jax.device_count()
+    assert sharded["devices"] == devices
+    assert set(sharded["merges"]) == set(sharded["gathered_rows"]) \
+        == {"sis", "l0"}
+    assert sharded["merges"]["l0"] == names["sisso.l0.block"]
+    # deferred blocks, plus one block of stored features per screen
+    assert sharded["merges"]["sis"] > names["sisso.sis.block"]
+    for phase, merges in sharded["merges"].items():
+        rows = sharded["gathered_rows"][phase]
+        assert rows % devices == 0 and merges <= rows
 
 
 def test_profiler_trace_shows_the_spans(tmp_path):
