@@ -277,6 +277,7 @@ def make_l0_topk_fn(mesh: Mesh, scorer, k_local: int, k_merge: int,
     return jax.jit(local)
 
 
+@functools.lru_cache(maxsize=None)
 def make_l0_topk_reduced_fn(mesh: Mesh, reducer, k_local: int, k_merge: int,
                             n_operands: int):
     """Reduced-epilogue variant of :func:`make_l0_topk_fn`.
@@ -293,6 +294,8 @@ def make_l0_topk_reduced_fn(mesh: Mesh, reducer, k_local: int, k_merge: int,
     (k_merge,), floor)``, the floor taken over every shard and the merge.
     Because the reducer is an fp32 prescreen, the caller rescores the
     merged survivors in fp64 and certifies them against the floor.
+    Cached per argument tuple: the same reducer object gives the same
+    compiled program.
     """
     dp = _dp_axes(mesh)
 

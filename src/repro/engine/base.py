@@ -222,16 +222,20 @@ class Backend(abc.ABC):
         """Optional traceable per-shard reducer for composed distribution.
 
         A backend whose ℓ0 kernel has a reduced top-k epilogue returns
-        ``(reducer, operands)`` where ``reducer(tup_blk, vld_blk,
-        *operands)`` is jit/shard_map-traceable and yields ``(sse
-        (k_local,) ascending fp32 with +inf sentinels, local_idx (k_local,)
-        int32)`` — the distribution wrapper (engine/sharded.py) then merges
-        the O(k) winner panels across shards without ever materializing a
-        per-shard SSE vector.  ``None`` (the default) means "no device
-        reducer for this problem/width"; the wrapper falls back to its
-        full-vector scorer + per-shard ``top_k``.  Reducer outputs are a
-        fp32 prescreen: the wrapper must rescore the merged survivors in
-        fp64 before final ranking.
+        ``(specialize, operands)``.  ``specialize(tuples, n_valid,
+        n_shards)`` looks at one padded tuple block (the Gram-gather
+        kernel reads back its per-shard run counts) and returns a
+        ``reducer(tup_blk, vld_blk, *operands)``, jit/shard_map-traceable,
+        that yields ``(sse (k_local,) ascending fp32 with +inf sentinels,
+        local_idx (k_local,) int32, floor)``; it returns the same object
+        for the same static shape, so the wrapper's compiled program (cached
+        per reducer) is lowered once per shape.  The distribution wrapper (engine/sharded.py)
+        merges the O(k) winner panels across shards without ever
+        materializing a per-shard SSE vector on the host.  ``None`` (the
+        default) means "no device reducer for this problem/width"; the
+        wrapper falls back to its full-vector scorer + per-shard
+        ``top_k``.  Reducer outputs are a fp32 prescreen: the wrapper must
+        rescore the merged survivors in fp64 before final ranking.
         """
         return None
 

@@ -8,9 +8,10 @@
 * ℓ0 pairs — ``kernels/ops.py:l0_score_pairs``: closed-form SSE gathered
   from Gram statistics (the tile kernel's math, XLA-gather form, in the
   compute dtype).
-* ℓ0 widths ≥ 3 — ``kernels/l0_gather.py``: blocked Gram-gather kernel
-  over VMEM-resident Gram statistics (one-hot MXU gathers + unrolled
-  elimination), **two-phase and certified**: the fp32 kernel bounds every
+* ℓ0 widths ≥ 3 — ``kernels/l0_gather.py``: Gram-gather kernel over
+  VMEM-resident Gram statistics (one run of prefix-sharing tuples per
+  panel row, unrolled elimination along the lanes), **two-phase and
+  certified**: the fp32 kernel bounds every
   tuple's SSE from below (with a reduced epilogue on the ``n_keep``
   path), the lowest bounds are rescored from fp64 Gram statistics, and
   the block's top-k is exact whenever its n_keep-th rescored SSE lies
@@ -55,6 +56,9 @@ from ..core.sis import (
 )
 from ..kernels import autotune
 from ..kernels import ops as kops
+from ..kernels.l0_gather import (
+    count_runs, l0_gather_topk_pallas, l0_gather_tuples_pallas, panel_rows,
+)
 from ..runtime import trace
 from .base import SIS_FUSED, L0Problem
 from .jnp_backend import JnpBackend
@@ -74,12 +78,31 @@ def _sis_topk_jit(values, membership, y_tilde, counts, mask, n_residuals, k):
     return vals, idx
 
 
+@functools.lru_cache(maxsize=None)
+def _l0_shard_reducer(n: int, k: int, block_t: int, rows: int,
+                      interpret: bool):
+    """One shard's reduced Gram-gather at one static shape.  The same
+    object for the same shape, in every fit, so the ``shard_map`` program
+    built over it (``core/distributed.make_l0_topk_reduced_fn``, cached by
+    its arguments) is lowered once per process and panel height."""
+
+    def reducer(tup_blk, vld_blk, gram, fsum, bvec, scal):
+        # valid rows form a global prefix, hence a prefix of each
+        # contiguous shard chunk — the count is the local boundary
+        nv = jnp.sum(vld_blk.astype(jnp.int32))
+        return l0_gather_topk_pallas(
+            jnp.asarray(tup_blk, jnp.int32).T, gram, fsum, bvec, scal, nv,
+            n=n, k=k, block_t=block_t, rows=rows, interpret=interpret)
+
+    return reducer
+
+
 class PallasBackend(JnpBackend):
     name = "pallas"
     fused_deferred = True
     reduces_blocks = True
     # width 2 = closed-form pair gather; widths >= 3 = the Gram-gather
-    # kernel, whose one-hot gather and unrolled SPD elimination are
+    # kernel, whose prefix gathers and unrolled SPD elimination are
     # width-generic (8 is a compile-time sanity ceiling, not a kernel
     # limit: the elimination unrolls (n+1)^2 lanes per step)
     l0_widths = tuple(range(2, 9))
@@ -96,9 +119,10 @@ class PallasBackend(JnpBackend):
         # per-block candidate count re-scored exactly in fp64 (phase 2 of
         # the gather path); must comfortably exceed any caller's n_keep
         self.rescore_k = int(rescore_k)
+        # Gram-gather panel rows (runs of tuples) per grid step
         self.block_t = int(block_t)
-        # per-grid-step winner count of the reduced top-k epilogues; grown
-        # automatically to cover a caller's n_keep
+        # per-grid-step winner count of the fused-SIS reduced epilogue;
+        # grown automatically to cover a caller's n_keep
         self.epilogue_k = int(epilogue_k)
         # measure block/epilogue shapes on the first batch per (kernel,
         # device, padded shape, dtype) — kernels/autotune.py
@@ -330,12 +354,10 @@ class PallasBackend(JnpBackend):
         pack = self._gram_pack(prob)
         tuples = jnp.asarray(tuples, jnp.int32)
         n_total = int(tuples.shape[0])
-        block_t, epi = self._tuned_l0_topk_cfg(pack, tuples, n_keep)
         r = min(n_total, self.rescore_window(n_keep))
-        k_epi = min(block_t, max(epi, 2 * int(n_keep), 1))
         _, gidx, floor = kops.l0_topk_tuples(
-            pack, tuples, n_keep=r, block_t=block_t,
-            epilogue_k=k_epi, interpret=self.interpret,
+            pack, tuples, n_keep=r, block_t=self._tuned_l0_block(pack, tuples),
+            interpret=self.interpret,
         )
         # order candidates by global index before the stable rescore sort
         # so exact-SSE ties resolve to the lowest index — the order a
@@ -357,11 +379,14 @@ class PallasBackend(JnpBackend):
     def l0_device_reducer(self, prob: L0Problem, width: int, k_local: int):
         """Traceable per-shard reduced Gram-gather for engine/sharded.py.
 
-        Returns a closure running the reduced-epilogue kernel on one
-        shard's tuple block and extracting its ``k_local`` lowest SSE
-        bounds and the floor under every bound it left out
-        (``kops.excluded_floor``) — the wrapper rescores merged survivors
-        via :meth:`_exact_rescore` and certifies them against the floor.
+        Returns ``(specialize, operands)``: ``specialize(tuples, n_valid,
+        n_shards)`` reads the padded block's per-shard run counts back and
+        returns the reducer for that panel height (one object per static
+        shape, :func:`_l0_shard_reducer`), which runs the kernel on one
+        shard's tuple block and keeps its ``k_local`` lowest SSE bounds and
+        the floor under every bound it left out — the wrapper rescores
+        merged survivors via :meth:`_exact_rescore` and certifies them
+        against the floor.
         ``None`` when the gather kernel does not cover this problem/width.
         """
         if width < 3 or not self._gather_eligible(prob, width):
@@ -369,62 +394,32 @@ class PallasBackend(JnpBackend):
         pack = self._gram_pack(prob)
         operands = (pack["gram"], pack["fsum"], pack["bvec"], pack["scal"])
         block_t = self.block_t
-        k_epi = min(block_t, max(self.epilogue_k, min(int(k_local), block_t)))
         interpret = self.resolved_interpret
-        n = int(width)
-        from ..kernels.l0_gather import l0_gather_topk_pallas
+        n, k = int(width), int(k_local)
 
-        def reducer(tup_blk, vld_blk, gram, fsum, bvec, scal):
-            b_local = tup_blk.shape[0]
-            # valid rows form a global prefix, hence a prefix of each
-            # contiguous shard chunk — the count is the local boundary
-            nv = jnp.sum(vld_blk.astype(jnp.int32))
-            b_pad = kops._pad_to(max(b_local, block_t), block_t)
-            tb = jnp.asarray(tup_blk, jnp.int32)
-            if b_pad != b_local:
-                fill = jnp.broadcast_to(
-                    jnp.arange(n, dtype=jnp.int32)[None, :],
-                    (b_pad - b_local, n),
-                )
-                tb = jnp.concatenate([tb, fill], axis=0)
-            vals, gidx = l0_gather_topk_pallas(
-                tb.T, gram, fsum, bvec, scal, nv, n=n, k=k_epi,
-                block_t=block_t, interpret=interpret,
-            )
-            neg, sel = jax.lax.top_k(-vals.reshape(-1), int(k_local))
-            return (-neg, gidx.reshape(-1)[sel],
-                    kops.excluded_floor(vals, k_epi, block_t, -neg))
+        def specialize(tuples, n_valid: int, n_shards: int):
+            rows = kops.l0_panel_rows(jnp.asarray(tuples, jnp.int32).T,
+                                      n_valid, n_shards, pack["m_pad"])
+            return _l0_shard_reducer(n, k, block_t, rows, interpret)
 
-        return reducer, operands
-
-    def _tuned_l0_topk_cfg(self, pack: dict, tuples, n_keep):
-        """Tuned ``(block_t, epilogue_k)`` for the reduced ℓ0 path."""
-        if not self.autotune:
-            return self.block_t, self.epilogue_k
-        width = int(tuples.shape[1])
-        key = ("l0_gather_topk", autotune.device_kind(),
-               (pack["m_pad"], width), pack.get("dtype", "float32"))
-        cands = [(bt, ke) for bt in autotune.L0_TILE_BLOCKS
-                 for ke in autotune.EPILOGUE_KS]
-
-        def run(cfg):
-            bt, ke = cfg
-            return kops.l0_topk_tuples(
-                pack, tuples, n_keep=int(n_keep), block_t=int(bt),
-                epilogue_k=int(ke), interpret=self.interpret)
-
-        bt, ke = autotune.pick_config(key, cands, run)
-        return int(bt), int(ke)
+        return specialize, operands
 
     def _tuned_l0_block(self, pack: dict, tuples) -> int:
+        """Panel rows per grid step of the Gram-gather kernel."""
         if not self.autotune:
             return self.block_t
-        width = int(tuples.shape[1])
-        key = ("l0_gather", autotune.device_kind(),
-               (pack["m_pad"], width), pack.get("dtype", "float32"))
+        tuples_t = jnp.asarray(tuples, jnp.int32).T
+        n, b = tuples_t.shape
+        key = ("l0_gather_panel", autotune.device_kind(),
+               (pack["m_pad"], n), pack.get("dtype", "float32"))
+        # the probes time the kernel alone: no l0_rows counts, one
+        # run-count read-back for all of them
+        rows = panel_rows(int(count_runs(tuples_t, b)[0]))
 
         def run(bt):
-            return kops.l0_score_tuples(pack, tuples, block_t=bt,
-                                        interpret=self.interpret)
+            return l0_gather_tuples_pallas(
+                tuples_t, pack["gram"], pack["fsum"], pack["bvec"],
+                pack["scal"], n=n, block_t=bt, rows=rows,
+                interpret=self.resolved_interpret)
 
         return autotune.pick_config(key, autotune.L0_TILE_BLOCKS, run)

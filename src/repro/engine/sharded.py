@@ -250,13 +250,17 @@ class ShardedExecution(Backend):
         """Compiled sharded ℓ0 reducer for one (width, n_keep, shard) shape.
 
         Prefers the inner backend's device-side reduced epilogue
-        (``Backend.l0_device_reducer``, e.g. the Pallas Gram-gather top-k
-        panels), keeping as many survivors as the inner backend rescores
-        per block (``Backend.rescore_window``) — the kernel screen is an
-        fp32 bound, so the wrapper rescores merged survivors in fp64 before
-        the final ranking.  Falls back to the full-vector traceable scorers
+        (``Backend.l0_device_reducer``, e.g. the Pallas Gram-gather top-k),
+        keeping as many survivors as the inner backend rescores per block
+        (``Backend.rescore_window``) — the kernel screen is an fp32 bound,
+        so the wrapper rescores merged survivors in fp64 before the final
+        ranking.  Falls back to the full-vector traceable scorers
         (overlap / Gram closed form / QR) when the inner backend has none.
-        Returns ``(fn, operands, prescreen, k_local)``.
+        Returns ``(build, operands, prescreen, k_local)``; ``build(tuples,
+        b)`` gives the compiled reducer for one padded block of ``b`` live
+        tuples (the inner backend specializes its device reducer to what it
+        reads off the block; the program is cached per reducer, across
+        fits).
         """
         key = ("sharded_l0_topk", width, int(n_keep), int(b_shard))
         with self._cache_lock:
@@ -266,11 +270,15 @@ class ShardedExecution(Backend):
                 k_local = min(survivors, b_shard)
                 dev = self.inner.l0_device_reducer(prob, width, k_local)
                 if dev is not None:
-                    reducer, operands = dev
+                    specialize, operands = dev
                     k_merge = min(survivors, self._nd * k_local)
-                    fn = make_l0_topk_reduced_fn(
-                        self.mesh, reducer, k_local, k_merge, len(operands))
-                    entry = prob.cache[key] = (fn, operands, True, k_local)
+
+                    def build(tuples, b):
+                        return make_l0_topk_reduced_fn(
+                            self.mesh, specialize(tuples, b, self._nd),
+                            k_local, k_merge, len(operands))
+
+                    entry = prob.cache[key] = (build, operands, True, k_local)
                 else:
                     if prob.problem == "classification":
                         scorer = overlap_topk_scorer()
@@ -286,7 +294,8 @@ class ShardedExecution(Backend):
                     k_merge = min(int(n_keep), self._nd * k_local)
                     fn = make_l0_topk_fn(self.mesh, scorer, k_local, k_merge,
                                          len(operands))
-                    entry = prob.cache[key] = (fn, operands, False, k_local)
+                    entry = prob.cache[key] = (
+                        lambda tuples, b: fn, operands, False, k_local)
         return entry
 
     def l0_topk(self, prob: L0Problem, tuples, n_keep: int) -> ReducedBlock:
@@ -305,8 +314,9 @@ class ShardedExecution(Backend):
             tuples = jnp.concatenate([tuples, fill], axis=0)
         valid = np.zeros((bp,), bool)
         valid[:b] = True
-        fn, operands, prescreen, k_local = self._l0_reducer(
+        build, operands, prescreen, k_local = self._l0_reducer(
             prob, width, int(n_keep), bp // self._nd)
+        fn = build(tuples, b)
         count_merge("l0", self.mesh, k_local)
         out = fn(tuples, jnp.asarray(valid), *operands)
         sses, idx = np.asarray(out[0], np.float64), np.asarray(out[1])

@@ -9,11 +9,13 @@ fused_sis.py — P1+P2+P3: generate candidate values, validate, project against
               residuals entirely in VMEM (never materializes the last rung).
 l0_tile.py   — P4: blocked Gram-tile pair scorer (MXU matmul + VPU closed-form
               solve + tile argmin), scalar-prefetched upper-triangle tiles.
-l0_gather.py — P4 for any width ≥ 3: blocked Gram-gather scorer over
-              VMEM-resident Gram statistics (one-hot MXU gathers + unrolled
-              elimination), fp32 phase of the two-phase exact top-k.
+l0_gather.py — P4 for any width ≥ 3: Gram-gather scorer over
+              VMEM-resident Gram statistics, one run of prefix-sharing
+              tuples per panel row (prefix columns by one-hot MXU matmul,
+              unrolled elimination along the lanes), fp32 phase of the
+              two-phase exact top-k.
 topk.py      — in-kernel per-block top-k epilogue (iterative extraction) +
-              the device-side tree merge across block panels.
+              the device-side tree merge across block panels (fused SIS).
 unrank.py    — device-side combinatorial unranking: ℓ0 tuple blocks
               materialize from rank ranges, no host enumeration.
 autotune.py  — P6: launch-config auto-tuning (block shapes, epilogue k).

@@ -3,8 +3,8 @@
 The paper times a predefined set of Kokkos team sizes on the first batch and
 reuses the winner (warp 32 vs 64 across vendors).  The TPU analogue tunes
 Pallas *block shapes* — candidate feature-block sizes for the fused SIS
-kernel, tile sizes for the ℓ0 kernel — and, for the reduced-epilogue
-variants, the per-block top-k width.  Cost is one extra evaluation of the
+kernel, panel rows per grid step for the ℓ0 kernel — and, for the fused
+SIS reduced epilogue, the per-block top-k width.  Cost is one extra evaluation of the
 first batch per candidate — "a few seconds ... negligible compared to the
 total runtime" (§II.D).
 
@@ -44,9 +44,10 @@ _PATH: Optional[str] = None
 
 #: candidate block shapes (candidate axis) for the fused SIS kernel
 FUSED_SIS_BLOCKS: Tuple[int, ...] = (128, 256, 512, 1024)
-#: candidate tile widths for the ℓ0 Gram-gather kernel
-L0_TILE_BLOCKS: Tuple[int, ...] = (128, 256, 512)
-#: candidate per-block epilogue widths for the reduced top-k variants
+#: candidate panel rows (runs of tuples) per grid step for the ℓ0
+#: Gram-gather kernel
+L0_TILE_BLOCKS: Tuple[int, ...] = (64, 128, 256)
+#: candidate per-block epilogue widths for the fused SIS reduced top-k
 EPILOGUE_KS: Tuple[int, ...] = (32, 64, 128)
 
 

@@ -15,9 +15,11 @@ import numpy as np
 
 from ..core.l0 import GramStats
 from ..core.sis import ScoreContext, TaskLayout
-from ..runtime import faults
+from ..runtime import faults, trace
 from .fused_sis import fused_gen_sis_pallas, fused_gen_sis_topk_pallas
-from .l0_gather import l0_gather_topk_pallas, l0_gather_tuples_pallas
+from .l0_gather import (
+    count_runs, l0_gather_topk_pallas, l0_gather_tuples_pallas, panel_rows,
+)
 from .l0_tile import l0_pairs_tiled_pallas
 from .ref import solve3_sse
 from .topk import merge_block_topk
@@ -253,6 +255,25 @@ def pack_gram_fp32(stats: GramStats) -> dict:
     return pack_gram(stats, jnp.float32)
 
 
+def l0_panel_rows(tuples_t: jnp.ndarray, n_valid: int, shards: int,
+                  m_pad: int) -> int:
+    """Panel height of the Gram-gather kernel for one ``(n, B)`` tuple
+    block, or for each of its ``shards`` contiguous chunks: the block's
+    run count read back (one device scalar per shard), rounded up to a
+    power of two.  Counts the block into ``stats["l0_rows"][width]``:
+    ``blocks``, panel ``rows`` in use (one per run), live ``tuples`` and
+    the ``lanes`` those rows span (``tuples / lanes`` is the panel's
+    occupancy)."""
+    runs = np.asarray(count_runs(tuples_t, n_valid, shards=shards))
+    width = int(tuples_t.shape[0])
+    used = int(runs.sum())
+    trace.count(("l0_rows", width, "blocks"))
+    trace.count(("l0_rows", width, "rows"), used)
+    trace.count(("l0_rows", width, "tuples"), int(n_valid))
+    trace.count(("l0_rows", width, "lanes"), used * int(m_pad))
+    return panel_rows(int(runs.max()))
+
+
 def l0_score_tuples(
     pack: dict,
     tuples: jnp.ndarray,     # (B, n) int32 — may live on device (unrank.py)
@@ -262,25 +283,18 @@ def l0_score_tuples(
     """fp32 total-SSE lower bounds (B,) for width-n tuples via the
     Gram-gather kernel (each at most the tuple's exact SSE).
 
-    Padding tuples are the benign (0, 1, …, n-1) combination, sliced off
-    before returning.  The result stays on device so the caller can fuse
-    the top-k / rescore selection without an extra transfer.
+    The result stays on device so the caller can fuse the top-k / rescore
+    selection without an extra transfer.
     """
     faults.check("kernel.l0")
     interpret = _interpret_default() if interpret is None else interpret
-    tuples = jnp.asarray(tuples, jnp.int32)
-    b, n = tuples.shape
-    b_pad = _pad_to(max(b, block_t), block_t)
-    if b_pad != b:
-        fill = jnp.broadcast_to(
-            jnp.arange(n, dtype=jnp.int32)[None, :], (b_pad - b, n)
-        )
-        tuples = jnp.concatenate([tuples, fill], axis=0)
-    sse = l0_gather_tuples_pallas(
-        tuples.T, pack["gram"], pack["fsum"], pack["bvec"], pack["scal"],
-        n=n, block_t=block_t, interpret=interpret,
+    tuples_t = jnp.asarray(tuples, jnp.int32).T
+    b = tuples_t.shape[1]
+    rows = l0_panel_rows(tuples_t, b, 1, pack["m_pad"])
+    return l0_gather_tuples_pallas(
+        tuples_t, pack["gram"], pack["fsum"], pack["bvec"], pack["scal"],
+        n=tuples_t.shape[0], block_t=block_t, rows=rows, interpret=interpret,
     )
-    return sse[:b]
 
 
 def l0_topk_tuples(
@@ -288,58 +302,30 @@ def l0_topk_tuples(
     tuples: jnp.ndarray,     # (B, n) int32 — may live on device (unrank.py)
     n_keep: int,
     block_t: int = 256,
-    epilogue_k: int = 64,
     interpret: Optional[bool] = None,
-) -> Tuple[np.ndarray, np.ndarray]:
+) -> Tuple[np.ndarray, np.ndarray, float]:
     """Reduced-epilogue Gram-gather: the ``n_keep`` lowest SSE bounds.
 
-    Per-tile top-k panels (window grown to cover ``n_keep``) merged on
-    device; only the O(k) winners cross the host boundary.  Returns
-    ``(bounds (k',) f64 ascending, indices (k',) i64, floor)`` — indices
-    are positions into ``tuples``; padding tuples can never appear.
-    ``floor`` is at most the bound of every tuple left out (a full tile's
-    last panel entry, the merge's last kept entry when it cut), +inf when
-    nothing was left out.
+    Selected on device; only the O(k) winners cross the host boundary.
+    Returns ``(bounds (k',) f64 ascending, indices (k',) i64, floor)`` —
+    indices are positions into ``tuples``, equal bounds in position order.
+    ``floor`` is at most the bound of every tuple left out (the last kept
+    bound when the selection cut), +inf when nothing was left out.
     """
     faults.check("kernel.l0")
     interpret = _interpret_default() if interpret is None else interpret
-    tuples = jnp.asarray(tuples, jnp.int32)
-    b, n = tuples.shape
-    b_pad = _pad_to(max(b, block_t), block_t)
-    if b_pad != b:
-        fill = jnp.broadcast_to(
-            jnp.arange(n, dtype=jnp.int32)[None, :], (b_pad - b, n)
-        )
-        tuples = jnp.concatenate([tuples, fill], axis=0)
-    k_epi = min(block_t, max(int(epilogue_k), min(int(n_keep), block_t)))
-    vals, gidx = l0_gather_topk_pallas(
-        tuples.T, pack["gram"], pack["fsum"], pack["bvec"], pack["scal"],
-        b, n=n, k=k_epi, block_t=block_t, interpret=interpret,
+    tuples_t = jnp.asarray(tuples, jnp.int32).T
+    n, b = tuples_t.shape
+    rows = l0_panel_rows(tuples_t, b, 1, pack["m_pad"])
+    v, i, floor = l0_gather_topk_pallas(
+        tuples_t, pack["gram"], pack["fsum"], pack["bvec"], pack["scal"],
+        b, n=n, k=min(int(n_keep), b), block_t=block_t, rows=rows,
+        interpret=interpret,
     )
-    k_merge = min(int(n_keep), vals.shape[0] * k_epi, b)
-    v, i = merge_block_topk(vals, gidx, k=k_merge, largest=False)
-    floor = float(excluded_floor(vals, k_epi, block_t, v))
     v = np.asarray(v, np.float64)
     i = np.asarray(i)
     keep = np.isfinite(v)
-    return v[keep], i[keep].astype(np.int64), floor
-
-
-def excluded_floor(panels: jnp.ndarray, k: int, block_t: int,
-                   merged: jnp.ndarray):
-    """Lower bound on the prescreen value of every tuple a reduced epilogue
-    left out (+inf when it left none out).
-
-    ``panels`` are the per-tile ``(ntiles, k_pad)`` winners (``k`` of each
-    ``block_t``-tuple tile, ascending, +inf sentinels) and ``merged`` the
-    ascending merge of them.  A tile with a full panel (finite k-th entry)
-    may have cut tuples at or above that entry, unless it keeps its whole
-    tile; a merge that kept fewer entries than the panels hold cut tuples
-    at or above its last kept entry.  Traceable.
-    """
-    floor = jnp.min(panels[:, k - 1]) if k < block_t else jnp.inf
-    cut = jnp.sum(jnp.isfinite(panels)) > jnp.sum(jnp.isfinite(merged))
-    return jnp.where(cut, jnp.minimum(floor, merged[-1]), floor)
+    return v[keep], i[keep].astype(np.int64), float(floor)
 
 
 def _task_padded_layout(

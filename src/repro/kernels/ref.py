@@ -124,6 +124,22 @@ def gather_planes(planes, onehot):
     return out
 
 
+def gather_plane_columns(planes, onehot):
+    """fp32 ``Σ_i onehot @ planes[i]ᵀ``: row r holds Gram column
+    ``idx_r`` (``out[r, k] = G[k, idx_r]``) for a ``(rows, m_pad)`` one-hot
+    of column indices.  The same exact selection as :func:`gather_planes`,
+    laid out along lanes, so a panel row reads the entries the column
+    gather reads — bit for bit, whether or not the stored G is exactly
+    symmetric."""
+    dims = (((1,), (1,)), ((), ()))
+    out = jax.lax.dot_general(onehot, planes[0], dims,
+                              preferred_element_type=jnp.float32)
+    for i in range(1, planes.shape[0]):
+        out = out + jax.lax.dot_general(onehot, planes[i], dims,
+                                        preferred_element_type=jnp.float32)
+    return out
+
+
 #: least pivot, relative to its diagonal entry, of a system the bound
 #: trusts: below it the computed coefficients (which the bound scales
 #: with) may be far from the exact ones, and the tuple bounds at 0
@@ -204,6 +220,40 @@ def gathered_system(g_cols, onehots, s_vals, b_vals, count, ysum):
         a[p][n] = s_vals[p]
         a[n][p] = s_vals[p]
         rhs[p] = b_vals[p]
+    a[n][n] = count
+    rhs[n] = ysum
+    return a, rhs
+
+
+def panel_system(cols, hits, gkk, s, b, count, ysum):
+    """Assemble the (n+1)×(n+1) normal equations for a panel of tuples:
+    one run per row (its (n-1)-prefix ``i_0..i_{n-2}``), the last index k
+    along the lanes.
+
+    ``cols[p]`` is the ``(rows, m_pad)`` panel of Gram columns ``G[k,
+    i_p]`` (:func:`gather_plane_columns`) and ``hits[p]`` the mask ``lane
+    == i_p``; ``gkk``/``s``/``b`` are the ``(1, m_pad)`` lane vectors
+    ``G_kk``, ``s_k``, ``b_k``.  Prefix entries are per-row ``(rows, 1)``
+    scalars read out of those panels, so every entry is the value
+    :func:`gathered_system` assembles for the same tuple.  Returns (a,
+    rhs) in the nested-list form ``eliminate_spd_sse`` takes.
+    """
+    def pick(x, hit):
+        return jnp.sum(jnp.where(hit, x, 0.0), axis=1, keepdims=True)
+
+    n = len(cols) + 1
+    last = n - 1
+    a = [[None] * (n + 1) for _ in range(n + 1)]
+    rhs = [None] * (n + 1)
+    for p in range(last):
+        for q in range(p, last):
+            a[p][q] = a[q][p] = pick(cols[p], hits[q])
+        a[p][last] = a[last][p] = cols[p]
+        a[p][n] = a[n][p] = pick(s, hits[p])
+        rhs[p] = pick(b, hits[p])
+    a[last][last] = gkk
+    a[last][n] = a[n][last] = s
+    rhs[last] = b
     a[n][n] = count
     rhs[n] = ysum
     return a, rhs

@@ -9,8 +9,9 @@ its top-``k`` (score, global index) pairs *before* anything leaves VMEM:
     HBM writes per block:   O(block)  ->  O(k_pad)
     host transfer per call: O(B)      ->  O(k)   (after the device merge)
 
-Two pieces, shared by the fused-SIS kernel (largest=True) and the ℓ0
-Gram-gather kernel (largest=False):
+Two pieces, used by the fused-SIS kernel (``largest=True``; the ℓ0
+Gram-gather kernel selects with one ``lax.top_k`` over its position-order
+bounds instead, kernels/l0_gather.py):
 
 * :func:`block_topk` — runs *inside* a Pallas kernel.  Iterative extraction
   (k rounds of masked max/min + first-occurrence argpos) instead of

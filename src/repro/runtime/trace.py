@@ -55,7 +55,11 @@ span                     interval
 =======================  ================================================
 
 The counters of a fit: ``programs`` (above); ``l0_paths`` and ``l0_enum``
-(ℓ0 blocks per width and scoring or enumeration path); ``sis_paths``
+(ℓ0 blocks per width and scoring or enumeration path); ``l0_rows`` (per
+width, the Gram-gather kernel's ``blocks``, its panel ``rows`` in use —
+one per run of tuples sharing a prefix —, the ``tuples`` they hold and
+the ``lanes`` they span: ``tuples / lanes`` is the panels' occupancy);
+``sis_paths``
 (deferred SIS blocks per scoring path: ``fused kernel``, ``fused kernel
 in shard_map`` or ``evaluate then screen``); ``sharded`` (a distributed
 engine: ``devices``, and per phase the k-sized ``merges`` and the winner

@@ -44,6 +44,11 @@ def main() -> int:
     m = settings["n_dim"] * settings["n_sis"]
     width3 = -(-n_models(m, 3) // settings["l0_block"])
     assert stats["l0_paths"][3] == {"sharded Gram-gather kernel": width3}
+    # every tuple in the kernel's panels once, each block counted once
+    # (its four shards' runs together)
+    rows = stats["l0_rows"][3]
+    assert rows["blocks"] == width3 and rows["tuples"] == n_models(m, 3)
+    assert rows["lanes"] == 128 * rows["rows"] >= rows["tuples"]
     print("sharded counters (4 devices): OK", stats["sharded"], flush=True)
     return 0
 

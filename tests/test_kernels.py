@@ -167,6 +167,23 @@ def test_l0_search_tiled_exact_topk(rng, m, s, tasks, block):
 # ℓ0 Gram-gather kernel (widths >= 3)
 # ---------------------------------------------------------------------------
 
+def _ordered(tuples, order, rng):
+    """The tuple block in one of the orders a kernel must score: the
+    whole lex space, a mid-space rank range whose first and last runs are
+    cut, reversed (every run of length one) or shuffled."""
+    if order == "lex":
+        return tuples
+    if order == "mid":
+        return tuples[5 : len(tuples) - 3]
+    if order == "reversed":
+        return tuples[::-1]
+    return tuples[rng.permutation(len(tuples))]
+
+
+TUPLE_ORDERS = ["lex", "mid", "reversed", "shuffled"]
+
+
+@pytest.mark.parametrize("order", TUPLE_ORDERS)
 @pytest.mark.parametrize("m,s,tasks,width,block_t", [
     (10, 40, 1, 3, 128),     # minimal single-task
     (14, 156, 2, 3, 128),    # thermal-like multi-task
@@ -176,15 +193,17 @@ def test_l0_search_tiled_exact_topk(rng, m, s, tasks, block):
     (12, 60, 1, 5, 128),     # width 5 (generic unrolled elimination)
     (10, 50, 2, 6, 128),     # width 6, multi-task
 ])
-def test_l0_gather_kernel_matches_oracle(rng, m, s, tasks, width, block_t):
+def test_l0_gather_kernel_matches_oracle(rng, m, s, tasks, width, block_t,
+                                         order):
     from repro.kernels.ref import l0_gather_sse_ref
 
     x = rng.uniform(0.5, 3.0, (m, s))
     y = 2.0 * x[3] - x[7] + 0.1 * rng.normal(size=s)
     ids = np.sort(rng.integers(0, tasks, s))
     layout = TaskLayout.from_task_ids(ids) if tasks > 1 else TaskLayout.single(s)
-    tuples = np.asarray(
-        list(__import__("itertools").combinations(range(m), width)), np.int32)
+    tuples = _ordered(np.asarray(
+        list(__import__("itertools").combinations(range(m), width)), np.int32),
+        order, rng)
     stats = compute_gram_stats(jnp.asarray(x), jnp.asarray(y), layout)
     pack = kops.pack_gram_fp32(stats)
     got = np.asarray(kops.l0_score_tuples(pack, jnp.asarray(tuples),
@@ -202,6 +221,36 @@ def test_l0_gather_kernel_matches_oracle(rng, m, s, tasks, width, block_t):
     rel = np.abs(got - want) / np.maximum(np.abs(want), 1e-9)
     assert np.quantile(rel, 0.99) < 2e-2
     assert np.argmin(got) == np.argmin(want)
+
+
+def test_l0_run_table_matches_lex_runs():
+    """A lex rank range's run table: one row per (n-1)-prefix, the runs at
+    both ends cut, each row's window its stretch of last indices."""
+    import itertools
+
+    from repro.kernels.l0_gather import count_runs, panel_rows, run_table
+
+    m, n = 9, 3
+    tuples = np.asarray(list(itertools.combinations(range(m), n)), np.int32)
+    blk = tuples[4:61]
+    runs = int(count_runs(jnp.asarray(blk.T), len(blk))[0])
+    prefixes = [tuple(p) for p in blk[:, :2]]
+    assert runs == len(set(prefixes))
+    table, run_id = run_table(jnp.asarray(blk.T), len(blk), panel_rows(runs))
+    table, run_id = np.asarray(table), np.asarray(run_id)
+    assert np.all(np.diff(run_id) >= 0) and run_id[-1] == runs - 1
+    for r in range(runs):
+        rows = blk[run_id == r]
+        assert len({tuple(p) for p in rows[:, :2]}) == 1
+        assert tuple(table[r, :2]) == tuple(rows[0, :2])
+        assert (table[r, 2], table[r, 3]) == (rows[0, 2], rows[-1, 2] + 1)
+    assert np.all(table[runs:, 2] == table[runs:, 3])     # empty windows
+    # a chunked count sees each chunk's first tuple open a run
+    per = np.asarray(count_runs(jnp.asarray(tuples[:56].T), 50, shards=4))
+    want = [len({tuple(p) for p in c[:, :2]})
+            for c in np.split(tuples[:56], 4)]
+    want[-1] = len({tuple(p) for p in tuples[42:50, :2]})
+    assert list(per) == want
 
 
 @pytest.mark.parametrize("collinearity", [1e-2, 1e-4, 1e-6])
@@ -244,8 +293,8 @@ def test_l0_gather_bound_never_exceeds_exact_sse(rng, collinearity):
 
 
 def test_l0_gather_padding_is_inert(rng):
-    """Block sizes that don't divide block_t get benign padding tuples;
-    results must be identical to an aligned call, sliced."""
+    """A block cut mid-run (131 of the tuples) scores each of its tuples
+    as the whole block does."""
     m, s = 11, 50
     x = rng.uniform(0.5, 3.0, (m, s))
     y = rng.normal(size=s)
@@ -259,6 +308,38 @@ def test_l0_gather_padding_is_inert(rng):
     ragged = np.asarray(kops.l0_score_tuples(pack, jnp.asarray(tuples[:131]),
                                              block_t=128, interpret=True))
     np.testing.assert_array_equal(ragged, full[:131])
+
+
+def test_l0_gather_short_panel_leaves_no_bound(rng):
+    """A panel lower than the block's run count scores the runs it holds
+    as a tall enough one does, and gives the runs past it the bound -inf
+    (no bound), never another tuple's entry."""
+    import itertools
+
+    from repro.kernels.l0_gather import (
+        count_runs, l0_gather_tuples_pallas, panel_rows, run_table,
+    )
+
+    m, s, n = 11, 50, 3
+    x = rng.uniform(0.5, 3.0, (m, s))
+    pack = kops.pack_gram_fp32(compute_gram_stats(
+        jnp.asarray(x), jnp.asarray(rng.normal(size=s)), TaskLayout.single(s)))
+    tt = jnp.asarray(np.asarray(list(itertools.combinations(range(m), n)),
+                                np.int32).T)
+    b = tt.shape[1]
+    runs = int(count_runs(tt, b)[0])
+    assert runs > 8
+
+    def score(rows):
+        return np.asarray(l0_gather_tuples_pallas(
+            tt, pack["gram"], pack["fsum"], pack["bvec"], pack["scal"], n=n,
+            block_t=128, rows=rows, interpret=True))
+
+    full, short = score(panel_rows(runs)), score(8)
+    held = np.asarray(run_table(tt, b, 8)[1]) < 8
+    assert 0 < held.sum() < b
+    np.testing.assert_array_equal(short[held], full[held])
+    assert np.all(short[~held] == -np.inf)
 
 
 # ---------------------------------------------------------------------------
@@ -342,8 +423,7 @@ def test_l0_topk_tuples_matches_full(rng, width):
                                            block_t=128, interpret=True))
     order = np.argsort(full, kind="stable")[:10]
     sses, idx, floor = kops.l0_topk_tuples(
-        pack, jnp.asarray(tuples), n_keep=10, block_t=128, epilogue_k=32,
-        interpret=True)
+        pack, jnp.asarray(tuples), n_keep=10, block_t=128, interpret=True)
     # same fp32 math but a different XLA fusion graph: indices must agree
     # exactly, values up to FMA/fusion ulp noise (fp64 rescore is phase 2)
     assert np.array_equal(idx, order)
@@ -352,14 +432,41 @@ def test_l0_topk_tuples_matches_full(rng, width):
     left_out = np.setdiff1d(np.arange(len(tuples)), idx)
     assert floor <= full[left_out].min() * (1 + 1e-6)
     assert floor == pytest.approx(sses[-1], rel=1e-6) or floor < sses[-1]
-    # padding tuples (131 over block_t=128) must never surface as winners;
-    # keeping all of them leaves nothing out
+    # a block cut mid-run (131 tuples): keeping all of them leaves nothing
+    # out, and no position past the block surfaces
     sses2, idx2, floor2 = kops.l0_topk_tuples(
         pack, jnp.asarray(tuples[:131]), n_keep=131, block_t=128,
-        epilogue_k=128, interpret=True)
+        interpret=True)
     assert np.all((idx2 >= 0) & (idx2 < 131))
     assert np.all(np.isfinite(sses2))
     assert floor2 == np.inf
+
+
+@pytest.mark.parametrize("order", ["lex", "mid", "shuffled"])
+def test_l0_topk_tuples_tie_order_matches_stable_sort(rng, order):
+    """Survivors, floor and tie order of the reduced kernel path equal a
+    stable sort of the full bound vector, on blocks whose first and last
+    runs are cut and with exact ties (two identical features)."""
+    import itertools
+
+    m, s, n_keep = 13, 64, 40
+    x = rng.uniform(0.5, 3.0, (m, s))
+    x[9] = x[4]                      # every tuple through 4 ties its twin
+    y = x[4] - 0.5 * x[7] + 0.1 * rng.normal(size=s)
+    pack = kops.pack_gram_fp32(compute_gram_stats(
+        jnp.asarray(x), jnp.asarray(y), TaskLayout.single(s)))
+    tuples = _ordered(np.asarray(list(itertools.combinations(range(m), 3)),
+                                 np.int32), order, rng)
+    blk = jnp.asarray(tuples)
+    full = np.asarray(kops.l0_score_tuples(pack, blk, block_t=8,
+                                           interpret=True))
+    want = np.argsort(full, kind="stable")[:n_keep]
+    vals, idx, floor = kops.l0_topk_tuples(pack, blk, n_keep=n_keep,
+                                           block_t=8, interpret=True)
+    assert len(np.unique(full[want])) < n_keep   # ties among the winners
+    assert np.array_equal(idx, want)
+    np.testing.assert_array_equal(vals, full[want])
+    assert floor == full[want[-1]]
 
 
 def test_fused_sis_topk_bf16_winner_overlap(rng):

@@ -21,15 +21,16 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import operators as om
 from repro.core.distributed import _fused_sis_topk_fn
 from repro.core.l0 import GramStats, score_tuples_gram
-from repro.engine.pallas_backend import _sis_topk_jit
+from repro.engine.pallas_backend import PallasBackend, _sis_topk_jit
 from repro.kernels import ops as kops
 from repro.kernels.fused_sis import (
     fused_gen_sis_pallas, fused_gen_sis_topk_pallas,
 )
 from repro.kernels.l0_gather import (
-    l0_gather_topk_pallas, l0_gather_tuples_pallas,
+    STEP_ENTRIES, count_runs, l0_gather_topk_pallas, l0_gather_tuples_pallas,
+    panel_rows,
 )
-from repro.kernels.unrank import unrank_lex
+from repro.kernels.unrank import unrank_block, unrank_lex
 
 
 @pytest.fixture(scope="module")
@@ -112,27 +113,79 @@ def _largest_m_pad(n_tasks: int) -> int:
     return m
 
 
-@pytest.mark.parametrize("topk", [False, True], ids=["full", "topk"])
-@pytest.mark.parametrize("n_tasks", [1, 2])
-def test_gram_gather_compiles_at_vmem_budget(one_chip, n_tasks, topk):
-    m_pad, n, block_t = _largest_m_pad(n_tasks), 3, 256
-    planes = kops.GRAM_PLANES["float32"]
-    bf16 = jnp.bfloat16
-    args = (_shape(one_chip, (n, 4 * block_t), jnp.int32),
-            _shape(one_chip, (planes, n_tasks, m_pad, m_pad), bf16),
-            _shape(one_chip, (planes, n_tasks, m_pad), bf16),
-            _shape(one_chip, (planes, n_tasks, m_pad), bf16),
-            _shape(one_chip, (n_tasks, 8), jnp.float32))
+#: panel rows per grid step the program runs (``PallasBackend.block_t``)
+BLOCK_T = PallasBackend().block_t
 
+
+def _gram_gather(topk, n, nv, rows, block_t=BLOCK_T):
     def run(tup, gram, fsum, bvec, scal):
         if topk:
-            return l0_gather_topk_pallas(tup, gram, fsum, bvec, scal,
-                                         4 * block_t - 5, n=n, k=128,
-                                         block_t=block_t)
+            return l0_gather_topk_pallas(tup, gram, fsum, bvec, scal, nv,
+                                         n=n, k=512, block_t=block_t,
+                                         rows=rows)
         return l0_gather_tuples_pallas(tup, gram, fsum, bvec, scal, n=n,
-                                       block_t=block_t)
+                                       block_t=block_t, rows=rows)
+    return run
 
-    assert "tpu_custom_call" in _compile(run, *args)
+
+def _gram_gather_args(sharding, n, block, n_tasks, m_pad):
+    planes = kops.GRAM_PLANES["float32"]
+    bf16 = jnp.bfloat16
+    return (_shape(sharding, (n, block), jnp.int32),
+            _shape(sharding, (planes, n_tasks, m_pad, m_pad), bf16),
+            _shape(sharding, (planes, n_tasks, m_pad), bf16),
+            _shape(sharding, (planes, n_tasks, m_pad), bf16),
+            _shape(sharding, (n_tasks, 8), jnp.float32))
+
+
+def _step_rows(n, m_pad, rows, block_t=BLOCK_T):
+    """Panel rows a grid step of the kernel holds (``STEP_ENTRIES``)."""
+    step = min(block_t, rows)
+    while step > 8 and step * m_pad * (n + 1) ** 2 > STEP_ENTRIES:
+        step //= 2
+    return step
+
+
+@pytest.mark.parametrize("block_t", [BLOCK_T, 128])
+@pytest.mark.parametrize("topk", [False, True], ids=["full", "topk"])
+@pytest.mark.parametrize("n_tasks", [1, 2])
+def test_gram_gather_compiles_at_vmem_budget(one_chip, n_tasks, topk,
+                                             block_t):
+    """The largest subspace the VMEM budget admits, a block in any order
+    (one panel row per tuple), at the program's own rows per step."""
+    m_pad, n, block = _largest_m_pad(n_tasks), 3, 1024
+    if block_t == BLOCK_T:  # a whole step: nothing halves at width 3
+        assert _step_rows(n, m_pad, block) == BLOCK_T
+    hlo = _compile(_gram_gather(topk, n, block - 5, None, block_t),
+                   *_gram_gather_args(one_chip, n, block, n_tasks, m_pad))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("topk", [False, True], ids=["full", "topk"])
+def test_gram_gather_compiles_at_thermal_shape(one_chip, topk):
+    """The thermal width-3 sweep: 600 features (m_pad 640) in 2 tasks,
+    65,536-tuple blocks, on the tallest panel its lex blocks take (at
+    most 1,826 runs a block)."""
+    n, block = 3, 65536
+    hlo = _compile(_gram_gather(topk, n, block, 2048),
+                   *_gram_gather_args(one_chip, n, block, 2, 640))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("width,step", [(4, 256), (5, 128), (6, 128),
+                                        (8, 64)])
+def test_gram_gather_compiles_for_wide_systems(one_chip, width, step):
+    """Wider systems hold more live panels a grid step, at the largest
+    two-task subspace: width 4 runs a whole step at the edge of
+    ``STEP_ENTRIES``, and from width 5 on the kernel halves its rows per
+    step until the step fits."""
+    m_pad, n_tasks, block = _largest_m_pad(2), 2, 1024
+    assert _step_rows(width, m_pad, 512) == step
+    entries = step * m_pad * (width + 1) ** 2
+    assert entries <= STEP_ENTRIES < 2 * entries
+    hlo = _compile(_gram_gather(False, width, block, 512),
+                   *_gram_gather_args(one_chip, width, block, n_tasks, m_pad))
+    assert "tpu_custom_call" in hlo
 
 
 def test_materialized_sis_topk_compiles_at_kaggle_width(one_chip):
@@ -239,7 +292,6 @@ def test_l0_reduced_shard_map_compiles_on_four_chips(topo):
     the exclusion floor's ``pmin``."""
     from repro.core.distributed import make_l0_topk_reduced_fn
     from repro.core.sis import TaskLayout
-    from repro.engine.pallas_backend import PallasBackend
 
     mesh = Mesh(np.asarray(topo.devices).reshape(-1), ("data",))
     m, s, n, block = 600, 156, 3, 65536
@@ -249,7 +301,14 @@ def test_l0_reduced_shard_map_compiles_on_four_chips(topo):
         rng.normal(size=(m, s)), rng.normal(size=s),
         TaskLayout.from_task_ids(np.repeat([0, 1], [75, 81])))
     k_local = backend.rescore_window(10)
-    reducer, operands = backend.l0_device_reducer(prob, n, k_local)
+    specialize, operands = backend.l0_device_reducer(prob, n, k_local)
+    # the panel height comes from the block's own runs, read back here:
+    # the quarters of block 0 hold 29 to 34 runs
+    tuples = unrank_block(0, block, m, n)
+    runs = np.asarray(count_runs(tuples.T, block, shards=4))
+    assert panel_rows(int(runs.max())) == 64
+    reducer = specialize(tuples, block, 4)
+    assert reducer is specialize(tuples, block, 4)
     fn = make_l0_topk_reduced_fn(mesh, reducer, k_local, k_local,
                                  len(operands))
 

@@ -205,6 +205,45 @@ def test_width3_blocks_count_their_enumeration_path(fitted):
         == _names(fit)["sisso.l0.block"]
 
 
+def test_width3_blocks_count_their_kernel_panel_rows(fitted):
+    """``l0_rows`` counts the Gram-gather kernel's panels over the lex
+    sweep of the 18-feature dim-3 subspace in 97-tuple blocks: one row per
+    (i, j) prefix run, plus one for each run a block end cuts; every tuple
+    once; 128 lanes (m_pad) a row."""
+    import itertools
+    import math
+
+    fit, backend = fitted
+    if backend == "jnp":
+        assert "l0_rows" not in fit.stats
+        return
+    m, n, block = 18, 3, 97
+    tuples = list(itertools.combinations(range(m), n))
+    cut = sum(tuples[r][:-1] == tuples[r - 1][:-1]
+              for r in range(block, len(tuples), block))
+    rows = math.comb(m - 1, n - 1) + cut
+    assert fit.stats["l0_rows"] == {3: {
+        "blocks": -(-len(tuples) // block), "rows": rows,
+        "tuples": n_models(m, n), "lanes": rows * 128}}
+
+
+def test_sharded_l0_programs_outlive_the_fit():
+    """A second ``sharded:pallas`` fit finds the ℓ0 shard programs of the
+    first: one per panel height, built once per process, whatever fit
+    asks for it."""
+    from repro.core.distributed import make_l0_topk_reduced_fn
+
+    X, y = _data()
+    _estimator("sharded:pallas").fit(X, y)
+    before = make_l0_topk_reduced_fn.cache_info()
+    fit = _estimator("sharded:pallas").fit(X, y).fit_result_
+    after = make_l0_topk_reduced_fn.cache_info()
+    blocks = fit.stats["l0_paths"][3]["sharded Gram-gather kernel"]
+    assert blocks == fit.stats["l0_rows"][3]["blocks"] > 1
+    assert after.misses == before.misses
+    assert after.hits - before.hits == blocks
+
+
 def test_enumeration_spans_one_per_operator_and_rung(fitted):
     """``sisso.fc.enumerate`` spans each operator's child enumeration at
     each rung (three operators, rung 1), summed as ``fc_enumerate``; a
