@@ -1,11 +1,16 @@
-"""Distributed SISSO phases over a (pod, data, model) mesh.
+"""Device-merged top-k programs over a (pod, data, model) mesh.
 
-Mapping (DESIGN.md §4):
+These are the shard_map programs :class:`~repro.engine.sharded.
+ShardedExecution` runs.  Mapping:
+
 * `data` (+`pod`)  — candidate axis: SIS feature blocks / ℓ0 tuple blocks.
-* `model`          — sample axis: Gram & projection partial sums, `psum`ed.
+* `model`          — sample axis: projection partial sums, `psum`ed (the
+  materialized regression screen); ℓ0 operands are replicated.
 
-The heavy inner loops are collective-free; only O(k) score/argmin payloads
-cross devices (vs the paper's serial gather/redistribute of features).
+Each program scores its shard, keeps a local top-k and merges the k-sized
+(score, index) panels with one all-gather, so only O(k) winners cross
+devices or reach the host (vs the paper's serial gather/redistribute of
+features).
 Each k-sized merge is counted on the host from its static shapes
 (:func:`count_merge`): ``SissoFit.stats["sharded"]["merges"][phase]`` and
 the winner rows it all-gathered, ``["gathered_rows"][phase]``.
@@ -55,64 +60,6 @@ def _shard_index(dp: Tuple[str, ...]):
 
 
 @functools.lru_cache(maxsize=None)
-def _sis_sharded_fn(mesh: Mesh, n_residuals: int):
-    """Compiled sharded SIS scorer, cached per (mesh, n_residuals).
-
-    The cache keeps the jitted closure alive across blocks — a fresh
-    closure per call would retrace and recompile every block.
-    """
-    dp = _dp_axes(mesh)
-    sample_ax = _sample_axis(mesh)
-
-    @functools.partial(
-        shard_map, mesh=mesh,
-        in_specs=(P(dp, sample_ax), P(None, sample_ax), P(None, sample_ax),
-                  P(None), P(dp)),
-        out_specs=P(dp),
-    )
-    def local(x_blk, m_blk, yt_blk, counts, mask_blk):
-        sums, sumsq, dots = screen_reductions(x_blk, m_blk, yt_blk)
-        if sample_ax is not None:
-            sums = jax.lax.psum(sums, sample_ax)
-            sumsq = jax.lax.psum(sumsq, sample_ax)
-            dots = jax.lax.psum(dots, sample_ax)
-        scores = scores_from_reductions(sums, sumsq, dots, counts, n_residuals)
-        # padding/masked rows are killed *inside* the sharded fn so a
-        # device-side top-k can never select one — host slice-off is not a
-        # defense once only winners cross the boundary
-        return jnp.where(mask_blk, scores, -jnp.inf)
-
-    return jax.jit(local)
-
-
-def sis_scores_sharded(
-    mesh: Mesh,
-    x: jnp.ndarray,  # (F, S) candidate values; F % n_data_shards == 0
-    ctx: ScoreContext,
-    row_mask: Optional[jnp.ndarray] = None,  # (F,) bool; False -> -inf
-) -> jnp.ndarray:
-    """Full score vector (F,) with features sharded over data(+pod).
-
-    Unlike :func:`sis_topk_sharded` (which merges a local top-k on device),
-    this returns every score so the engine layer can apply the same
-    host-side TopK policy as every other backend.  Samples shard over
-    'model' when the mesh has that axis (partial sums psum'ed); otherwise
-    they are replicated and the screen is collective-free.  ``row_mask``
-    marks real rows: padding (and excluded) rows score ``-inf`` on device.
-    """
-    fn = _sis_sharded_fn(mesh, ctx.n_residuals)
-    if row_mask is None:
-        row_mask = jnp.ones((x.shape[0],), bool)
-    return fn(
-        x,
-        jnp.asarray(ctx.membership, x.dtype),
-        jnp.asarray(ctx.y_tilde, x.dtype),
-        jnp.asarray(ctx.counts, x.dtype),
-        jnp.asarray(row_mask, bool),
-    )
-
-
-@functools.lru_cache(maxsize=None)
 def _sis_topk_fn(mesh: Mesh, n_residuals: int, k_local: int, k_merge: int):
     """Compiled sharded SIS screen with the merge *on device*: per-shard
     scores -> local top-``k_local`` -> ``all_gather`` of k-sized (score,
@@ -159,6 +106,8 @@ def sis_topk_sharded(
     over data(+pod), samples over 'model' when present.  Masked rows can
     never win (in-shard ``-inf``); the host receives exactly
     ``min(n_keep, nd·k_local)`` entries and the caller drops ``-inf`` tails.
+    Sample columns pad with zeros to a multiple of the 'model' axis: a
+    zero membership and residual column adds nothing to any partial sum.
     """
     f = int(x.shape[0])
     nd = _n_dp(mesh)
@@ -167,76 +116,22 @@ def sis_topk_sharded(
     k_merge = min(int(n_keep), nd * k_local)
     fn = _sis_topk_fn(mesh, ctx.n_residuals, k_local, k_merge)
     count_merge("sis", mesh, k_local)
+    sample_ax = _sample_axis(mesh)
+    nm = int(mesh.shape[sample_ax]) if sample_ax is not None else 1
+    cols = -(-int(x.shape[1]) // nm) * nm
+
+    def pad(a):
+        a = jnp.asarray(a, x.dtype)
+        if a.shape[1] == cols:
+            return a
+        return jnp.pad(a, ((0, 0), (0, cols - a.shape[1])))
+
     vals, idx = fn(
-        x,
-        jnp.asarray(ctx.membership, x.dtype),
-        jnp.asarray(ctx.y_tilde, x.dtype),
+        pad(x), pad(ctx.membership), pad(ctx.y_tilde),
         jnp.asarray(ctx.counts, x.dtype),
         jnp.asarray(row_mask, bool),
     )
     return np.asarray(vals, np.float64), np.asarray(idx)
-
-
-@functools.lru_cache(maxsize=None)
-def _l0_pairs_sharded_fn(mesh: Mesh, n_tasks: int):
-    """Compiled sharded pair scorer, cached per (mesh, n_tasks)."""
-    from ..kernels.ref import solve3_sse
-
-    dp = _dp_axes(mesh)
-    sample_ax = _sample_axis(mesh)
-
-    @functools.partial(
-        shard_map, mesh=mesh,
-        in_specs=(P(None, sample_ax), P(sample_ax), P(None, sample_ax),
-                  P(dp, None), P(dp)),
-        out_specs=P(dp),
-    )
-    def local(x_blk, y_blk, mem_blk, prs, vld):
-        def ps(v):
-            return jax.lax.psum(v, sample_ax) if sample_ax is not None else v
-
-        i, j = prs[:, 0], prs[:, 1]
-        total = jnp.zeros((prs.shape[0],), x_blk.dtype)
-        for ti in range(n_tasks):
-            w = mem_blk[ti]
-            xw = x_blk * w[None, :]
-            gii = ps((xw * x_blk).sum(axis=1))
-            fsum = ps(xw.sum(axis=1))
-            bv = ps(xw @ y_blk)
-            n = ps(w.sum())
-            ysum = ps(w @ y_blk)
-            yty = ps((w * y_blk) @ y_blk)
-            gij = ps((xw[i] * x_blk[j]).sum(axis=1))
-            total = total + solve3_sse(
-                gii[i], gii[j], n, gij, fsum[i], fsum[j],
-                bv[i], bv[j], ysum, yty)
-        # padding pairs are +inf *inside* the sharded fn: a device-side
-        # top-k must never pick a benign-padding solve as a winner
-        return jnp.where(vld, total, jnp.inf)
-
-    return jax.jit(local)
-
-
-def l0_pair_sses_sharded(
-    mesh: Mesh,
-    x: jnp.ndarray,      # (m, S) subspace features
-    y: jnp.ndarray,      # (S,)
-    layout: TaskLayout,
-    pairs: jnp.ndarray,  # (B, 2) int32; B % n_data_shards == 0
-    valid: Optional[jnp.ndarray] = None,  # (B,) bool; False -> +inf
-) -> jnp.ndarray:
-    """Total SSE (B,) for explicit pairs, tuple space sharded over data(+pod).
-
-    The per-shard math is the same closed-form solve as the Pallas tile
-    kernel (kernels/ref.py:solve3_sse); per-task Gram partials psum over
-    'model' when the mesh shards samples.  Rows where ``valid`` is False
-    (padding pairs) come back ``+inf`` — masked on device, not host-sliced.
-    """
-    mem = jnp.asarray(layout.membership(x.shape[1], np.float64), x.dtype)
-    fn = _l0_pairs_sharded_fn(mesh, layout.n_tasks)
-    if valid is None:
-        valid = jnp.ones((pairs.shape[0],), bool)
-    return fn(x, y, mem, pairs, jnp.asarray(valid, bool))
 
 
 # ---------------------------------------------------------------------------
@@ -373,17 +268,14 @@ def overlap_operands(cstats) -> Tuple[jnp.ndarray, ...]:
 # classification SIS: sharded 1D class-domain overlap screen.  Candidates
 # shard over data(+pod) exactly like the regression screen; the overlap
 # score needs whole sample rows (per-class minima/maxima + in-interval
-# counts), so these paths require a sample-replicated mesh — the sharded
-# wrapper falls back to the inner backend + host merge on 'model' meshes.
+# counts), so this path requires a sample-replicated mesh — the sharded
+# wrapper leaves the screen to the inner backend on 'model' meshes.
 # ---------------------------------------------------------------------------
 
 @functools.lru_cache(maxsize=None)
-def _overlap_sis_fn(mesh: Mesh, topk: Optional[Tuple[int, int]]):
-    """Compiled sharded classification screen, cached per (mesh, k-config).
-
-    ``topk=None`` returns the full per-shard score vectors (host-merge
-    callers); ``topk=(k_local, k_merge)`` merges on device with the same
-    k-sized all-gather discipline as the regression screen."""
+def _overlap_sis_fn(mesh: Mesh, k_local: int, k_merge: int):
+    """Compiled sharded classification screen, cached per (mesh, k-config):
+    the same k-sized all-gather merge on device as the regression screen."""
     from .problem import overlap_scores_ops
 
     dp = _dp_axes(mesh)
@@ -391,21 +283,17 @@ def _overlap_sis_fn(mesh: Mesh, topk: Optional[Tuple[int, int]]):
         "classification SIS needs whole sample rows; use a "
         "sample-replicated mesh or the inner-backend fallback"
     )
-    out_specs = P(dp) if topk is None else (P(None), P(None))
 
     @functools.partial(
         shard_map, mesh=mesh,
         in_specs=(P(dp, None), P(None, None), P(None, None), P(None, None),
                   P(dp)),
-        out_specs=out_specs,
-        check_vma=topk is None,
+        out_specs=(P(None), P(None)),
+        check_vma=False,
     )
     def local(x_blk, task_mem, class_mem, masks, mask_blk):
         scores = overlap_scores_ops(x_blk, task_mem, class_mem, masks)
         scores = jnp.where(mask_blk, scores, -jnp.inf)
-        if topk is None:
-            return scores
-        k_local, k_merge = topk
         vals, sel = jax.lax.top_k(scores, k_local)
         gidx = scores.shape[0] * _shard_index(dp) + sel
         gv = jax.lax.all_gather(vals, dp, tiled=True)
@@ -414,25 +302,6 @@ def _overlap_sis_fn(mesh: Mesh, topk: Optional[Tuple[int, int]]):
         return v2, gi[s2]
 
     return jax.jit(local)
-
-
-def overlap_sis_scores_sharded(
-    mesh: Mesh,
-    x: jnp.ndarray,                # (F, S); F % n_data_shards == 0
-    ctx: ScoreContext,
-    row_mask: Optional[jnp.ndarray] = None,
-) -> jnp.ndarray:
-    """Full classification score vector (F,), features sharded over dp."""
-    fn = _overlap_sis_fn(mesh, None)
-    if row_mask is None:
-        row_mask = jnp.ones((x.shape[0],), bool)
-    return fn(
-        x,
-        jnp.asarray(ctx.membership, x.dtype),
-        jnp.asarray(ctx.class_members, x.dtype),
-        jnp.asarray(ctx.state_masks, x.dtype),
-        jnp.asarray(row_mask, bool),
-    )
 
 
 def overlap_sis_topk_sharded(
@@ -448,7 +317,7 @@ def overlap_sis_topk_sharded(
     assert f % nd == 0, (f, nd)
     k_local = min(int(n_keep), f // nd)
     k_merge = min(int(n_keep), nd * k_local)
-    fn = _overlap_sis_fn(mesh, (k_local, k_merge))
+    fn = _overlap_sis_fn(mesh, k_local, k_merge)
     count_merge("sis", mesh, k_local)
     vals, idx = fn(
         x,
@@ -576,117 +445,3 @@ def fused_sis_topk_sharded(
     count_merge("sis", mesh, k_local)
     vals, idx = fn(a_p, b_p, m_p, yt_p, cnt, jnp.asarray(nv))
     return np.asarray(vals, np.float64), np.asarray(idx)
-
-
-def sis_scores_distributed(
-    mesh: Mesh,
-    x: jnp.ndarray,          # (F, S) candidate feature values
-    ctx: ScoreContext,
-    n_top: int,
-) -> Tuple[np.ndarray, np.ndarray]:
-    """Top-k (scores, indices) with features sharded over data(+pod) and
-    samples sharded over model.
-
-    Inside each shard: three local matmuls (the SIS reductions) on the
-    sample sub-axis; one psum over 'model' combines them; local top-k over
-    the feature shard; a single all-gather of k-sized payloads merges.
-    """
-    dp = _dp_axes(mesh)
-    f, s = x.shape
-    nd = int(np.prod([mesh.shape[a] for a in dp]))
-    nm = int(mesh.shape["model"])
-    assert f % nd == 0 and s % nm == 0, (f, nd, s, nm)
-    k_local = min(n_top, f // nd)
-
-    m = jnp.asarray(ctx.membership, x.dtype)
-    yt = jnp.asarray(ctx.y_tilde, x.dtype)
-    counts = jnp.asarray(ctx.counts, x.dtype)
-
-    @functools.partial(
-        shard_map, mesh=mesh,
-        in_specs=(P(dp, "model"), P(None, "model"), P(None, "model")),
-        out_specs=(P(dp), P(dp)),
-    )
-    def local(x_blk, m_blk, yt_blk):
-        sums, sumsq, dots = jax.lax.psum(
-            screen_reductions(x_blk, m_blk, yt_blk), "model")
-        scores = scores_from_reductions(sums, sumsq, dots, counts,
-                                        ctx.n_residuals)
-        vals, idx = jax.lax.top_k(scores, k_local)
-        base = f // nd * jax.lax.axis_index(dp[0] if len(dp) == 1 else dp)
-        return vals, idx + base
-
-    vals, idx = jax.jit(local)(x, m, yt)
-    vals, idx = np.asarray(vals), np.asarray(idx)
-    order = np.argsort(-vals, kind="stable")[:n_top]
-    return vals[order], idx[order]
-
-
-def l0_pairs_distributed(
-    mesh: Mesh,
-    x: jnp.ndarray,      # (m, S) subspace features
-    y: jnp.ndarray,      # (S,)
-    task_slices,
-    pairs: np.ndarray,   # (B, 2) — padded & sharded over data(+pod)
-    n_keep: int = 10,
-) -> Tuple[np.ndarray, np.ndarray]:
-    """Distributed exhaustive pair scoring: tuple space over data(+pod),
-    samples over model (per-task Gram partials psum'ed), top-k merge."""
-    from ..kernels.ref import solve3_sse
-
-    dp = _dp_axes(mesh)
-    nd = int(np.prod([mesh.shape[a] for a in dp]))
-    b = len(pairs)
-    b_pad = ((b + nd - 1) // nd) * nd
-    pairs_pad = np.zeros((b_pad, 2), np.int32)
-    pairs_pad[:b] = pairs
-    valid = np.zeros((b_pad,), bool)
-    valid[:b] = True
-    nm = int(mesh.shape["model"])
-    s = x.shape[1]
-    s_pad = ((s + nm - 1) // nm) * nm
-    x_p = jnp.zeros((x.shape[0], s_pad), x.dtype).at[:, :s].set(x)
-    y_p = jnp.zeros((s_pad,), y.dtype).at[:s].set(y)
-    k_local = min(n_keep, b_pad // nd)
-
-    # per-task membership rows for sample-sharded Gram partials
-    t = len(task_slices)
-    mem = np.zeros((t, s_pad), np.float64)
-    for ti, (lo, hi) in enumerate(task_slices):
-        mem[ti, lo:hi] = 1.0
-    mem = jnp.asarray(mem, x.dtype)
-
-    @functools.partial(
-        shard_map, mesh=mesh,
-        in_specs=(P(None, "model"), P("model"), P(None, "model"),
-                  P(dp, None), P(dp)),
-        out_specs=(P(dp), P(dp)),
-    )
-    def local(x_blk, y_blk, mem_blk, prs, vld):
-        i, j = prs[:, 0], prs[:, 1]
-        total = jnp.zeros((prs.shape[0],), x_blk.dtype)
-        for ti in range(t):
-            w = mem_blk[ti]
-            xw = x_blk * w[None, :]
-            gii = jax.lax.psum((xw * x_blk).sum(axis=1), "model")
-            fsum = jax.lax.psum(xw.sum(axis=1), "model")
-            bv = jax.lax.psum(xw @ y_blk, "model")
-            n = jax.lax.psum(w.sum(), "model")
-            ysum = jax.lax.psum(w @ y_blk, "model")
-            yty = jax.lax.psum((w * y_blk) @ y_blk, "model")
-            gij = jax.lax.psum((xw[i] * x_blk[j]).sum(axis=1), "model")
-            total = total + solve3_sse(
-                gii[i], gii[j], n, gij, fsum[i], fsum[j],
-                bv[i], bv[j], ysum, yty)
-        total = jnp.where(vld, total, jnp.inf)
-        neg, idx = jax.lax.top_k(-total, k_local)
-        base = prs.shape[0] * 0 + idx  # local indices within the shard
-        shard = jax.lax.axis_index(dp[0] if len(dp) == 1 else dp)
-        return -neg, base + shard * (b_pad // nd)
-
-    sses, idx = jax.jit(local)(x_p, y_p, mem, jnp.asarray(pairs_pad),
-                               jnp.asarray(valid))
-    sses, idx = np.asarray(sses), np.asarray(idx)
-    order = np.argsort(sses, kind="stable")[:n_keep]
-    keep = np.isfinite(sses[order])
-    return pairs_pad[idx[order][keep]].astype(np.int64), sses[order][keep]

@@ -465,22 +465,17 @@ def l0_search(
         # this block's score panel (the NaN scrub below must absorb it)
         kind = faults.check("l0.block_scores")
         tuples = enum.block_tuples(bi)
-        # a reducing backend (engine/sharded.py) hands back a ReducedBlock
-        # of O(n_keep) winners — only they cross the host boundary; every
-        # other backend returns the block's full SSE vector
-        res = engine.l0_scores(prob, tuples, n_keep=n_keep)
+        # the block's O(n_keep) winners — only they cross the host boundary
+        rb = engine.l0_topk(prob, tuples, n_keep)
         if kind == "nan":
-            if isinstance(res, ReducedBlock):
-                # deliberately non-finite: this *is* the faulted panel the
-                # merge loop's isfinite scrub must absorb
-                res = ReducedBlock(  # reprolint: disable=RL007
-                    indices=np.asarray(res.indices),
-                    scores=np.full(len(res), np.nan),
-                    n_source=res.n_source,
-                )
-            else:
-                res = np.full((len(tuples),), np.nan)
-        return tuples, res
+            # deliberately non-finite: this *is* the faulted panel the
+            # merge loop's isfinite scrub must absorb
+            rb = ReducedBlock(  # reprolint: disable=RL007
+                indices=np.asarray(rb.indices),
+                scores=np.full(len(rb), np.nan),
+                n_source=rb.n_source,
+            )
+        return tuples, rb
 
     def winners_of(tuples, bi: int, indices: np.ndarray) -> np.ndarray:
         """Block-local winner indices -> (k, n_dim) int64 tuples.
@@ -503,34 +498,20 @@ def l0_search(
         score_block, range(start_block, enum.n_blocks), depth=prefetch_depth,
         span="sisso.l0",
     )
-    for bi, (tuples, res) in stream:
+    for bi, (tuples, rb) in stream:
         n_eval += len(tuples)
         with trace.span("sisso.l0.merge"):
             # merge block top-k into running top-k (host).  A block whose best
             # SSE cannot beat the current k-th best contributes nothing — skip
             # the concatenate+argsort (ties lose to incumbents either way).
-            # Negated comparison so a NaN block-min (a backend without the
-            # finite→inf guard) falls through to the merge, never to a skip.
-            blk_sse = blk_tup = None
-            if isinstance(res, ReducedBlock):
-                if len(res) and not (res.scores.min() >= best_sse[-1]):
-                    blk_sse = res.scores
-                    blk_tup = winners_of(tuples, bi, res.indices)
-            else:
-                sses = np.asarray(res)
-                if len(sses) and not (sses.min() >= best_sse[-1]):
-                    k = min(n_keep, len(sses))
-                    # stable selection: exact objective ties (routine for the
-                    # classification overlap count) must resolve to the same
-                    # winners as a device-reduced block's ordered top-k
-                    part = np.argsort(sses, kind="stable")[:k]
-                    blk_sse = sses[part]
-                    blk_tup = np.asarray(tuples)[part].astype(np.int64)
-            if blk_sse is not None:
-                # scrub non-finite panel entries (NaN from a faulted device,
-                # ±inf sentinels) to +inf so a poisoned block loses to every
-                # finite incumbent instead of corrupting the top-k order
-                blk_sse = np.where(np.isfinite(blk_sse), blk_sse, np.inf)
+            # Negated comparison so a NaN block-min (a faulted panel) falls
+            # through to the merge, never to a skip.
+            if len(rb) and not (rb.scores.min() >= best_sse[-1]):
+                # scrub non-finite panel entries (NaN from a faulted device)
+                # to +inf so a poisoned block loses to every finite
+                # incumbent instead of corrupting the top-k order
+                blk_sse = np.where(np.isfinite(rb.scores), rb.scores, np.inf)
+                blk_tup = winners_of(tuples, bi, rb.indices)
                 cat_sse = np.concatenate([best_sse, blk_sse])
                 cat_tup = np.concatenate([best_tuples, blk_tup])
                 order = np.argsort(cat_sse, kind="stable")[:n_keep]

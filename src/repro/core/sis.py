@@ -163,16 +163,16 @@ def score_block(values: jnp.ndarray, ctx: ScoreContext) -> jnp.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# top-k merge.  Two block shapes flow into the merge: full score vectors
-# (host-side ranking, the paper's "transferred back to CPU ... used to rank
-# the features") and *pre-reduced* blocks — a backend that merges on device
-# (engine/sharded.py) returns only the block's top-k (index, score) winners,
-# so O(k) payloads cross the host boundary instead of O(B) score vectors.
+# top-k merge.  Every backend answers a score block with its top-k (index,
+# score) winners (engine/base.py:Backend), so O(k) payloads cross the host
+# boundary instead of O(B) score vectors; the host merges those winners into
+# a running top-k (the paper's "transferred back to CPU ... used to rank the
+# features").
 # ---------------------------------------------------------------------------
 
 @dataclasses.dataclass(frozen=True)
 class ReducedBlock:
-    """Device-reduced top-k of one score block.
+    """Top-k of one score block: what every backend answers a block with.
 
     ``indices`` are positions *within the submitted block* (0 ≤ i < the
     block length the caller dispatched); ``scores`` are sorted best-first
@@ -197,7 +197,8 @@ class ReducedBlock:
         mask: Optional[np.ndarray] = None,
         largest: bool = True,
     ) -> "ReducedBlock":
-        """Host-side reference reduction (backends without a device merge).
+        """Host-side reduction of a full score vector (backends that score
+        whole blocks, engine/base.py:ScoringBackend).
 
         Stable first-occurrence tie order — the same order a stable
         descending/ascending sort of the full vector would produce, so a
@@ -230,8 +231,8 @@ class TopK:
         all_tags = self.tags + tags
         # stable first-occurrence tie order: exact score ties are routine
         # for the classification problem (mirror candidates share overlap
-        # counts), and an unstable partition would let the full-vector and
-        # device-reduced merge paths pick *different* tied winners
+        # counts), and an unstable partition would let a host-reduced and a
+        # device-reduced block pick *different* tied winners
         idx = np.argsort(-all_scores, kind="stable")[: self.k]
         self.scores = all_scores[idx]
         self.tags = [all_tags[i] for i in idx]
@@ -266,11 +267,9 @@ def sis_screen(
     Screens both materialized features and deferred last-rung candidates
     (paper P3 on-the-fly path).  All screening math runs on the supplied
     execution ``engine`` (engine/) — this function only owns batching and
-    the top-k merge policy, so there is no per-backend branching here: a
-    backend that merges on device (``engine.reduces_blocks``) hands back
-    :class:`ReducedBlock` winners and the push indexes tags lazily; every
-    other backend returns full score vectors and the classic host merge
-    runs.
+    the top-k merge policy, so there is no per-backend branching here:
+    every backend hands back a block's :class:`ReducedBlock` winners and
+    the push builds tags for those winners only.
 
     ``problem`` selects the screening objective (core/problem.py; default
     regression): the problem builds the tagged :class:`ScoreContext` from
@@ -295,49 +294,37 @@ def sis_screen(
         for lo in range(0, len(x), batch):
             hi = min(lo + batch, len(x))
             # mask of screenable rows: already-selected features must not
-            # occupy winner slots (applied on device on reducing backends)
+            # occupy winner slots
             blk_mask = None
             if exclude:
                 blk_mask = np.ones(hi - lo, bool)
                 for fid in exclude:
                     if lo <= fid < hi:
                         blk_mask[fid - lo] = False
-            res = engine.sis_scores(x[lo:hi], ctx, n_keep=k, mask=blk_mask)
-            if isinstance(res, ReducedBlock):
-                top.push_reduced(res, lambda i, lo=lo: ("feat", lo + i))
-            else:
-                # the Engine already applied blk_mask (-inf) on this path
-                top.push(np.asarray(res, np.float64),
-                         [("feat", fid) for fid in range(lo, hi)])
+            rb = engine.sis_topk(x[lo:hi], ctx, k, mask=blk_mask)
+            top.push_reduced(rb, lambda i, lo=lo: ("feat", lo + i))
 
         # 2) deferred last-rung candidates: generate -> score -> discard.
         #    Double-buffered (engine/streaming.py): block k+1's child-row
         #    gather and device dispatch overlap block k's scoring, and the
         #    host top-k push runs off the critical path.
         def score_deferred(blk: CandidateBlock):
-            return engine.sis_scores_deferred(
+            return engine.sis_topk_deferred(
                 blk.op_id, x[blk.child_a], x[blk.child_b], ctx,
-                fspace.l_bound, fspace.u_bound, n_keep=k,
+                fspace.l_bound, fspace.u_bound, k,
             )
 
-        for blk, s in BlockPrefetcher(
+        for blk, rb in BlockPrefetcher(
             score_deferred, fspace.iter_candidate_batches(batch),
             span="sisso.sis",
         ):
-            if isinstance(s, ReducedBlock):
-                top.push_reduced(
-                    s,
-                    lambda i, blk=blk: (
-                        "cand", blk.op_id, int(blk.child_a[i]),
-                        int(blk.child_b[i])
-                    ),
-                )
-            else:
-                tags = [
-                    ("cand", blk.op_id, int(a), int(b))
-                    for a, b in zip(blk.child_a, blk.child_b)
-                ]
-                top.push(s, tags)
+            top.push_reduced(
+                rb,
+                lambda i, blk=blk: (
+                    "cand", blk.op_id, int(blk.child_a[i]),
+                    int(blk.child_b[i])
+                ),
+            )
         return top
 
     # 3) materialize winners in score order, skipping value-duplicates of

@@ -6,6 +6,9 @@ call boundary:
 
 * shape/dtype conformance of each method's returns (``eval_block``'s
   ``(values (B, S), valid (B,) bool)``, score vectors of length B, ...);
+  a full score vector is checked wherever one is formed: when the wrapped
+  backend reduces on the host through ``ScoringBackend``'s default, the
+  sanitizer forms that vector itself and reduces it the same way;
 * :class:`ReducedBlock` well-formedness — at most ``n_keep`` winners,
   unique in-range indices, **finite** scores (no ±inf sentinel lane may
   cross the host boundary — the dynamic half of RL007), best-first
@@ -15,10 +18,11 @@ call boundary:
   Device-resident outputs are checked *inside jit* via
   ``jax.experimental.checkify`` so the check itself stays on the jit
   path; host arrays use plain numpy.
-* at verify level, a cross-check of every reduced top-k against the
-  wrapped backend's own full-vector scorer reduced on host — which is
-  exactly the ``k_epi >= min(n_keep, block)`` coverage invariant plus
-  stable-tie winner parity.
+* at verify level, a cross-check of every device-reduced top-k against
+  a host reduction of a full vector — from the wrapped backend when it is
+  a ``ScoringBackend``, else from the reference oracle on the same
+  operands — which is exactly the ``k_epi >= min(n_keep, block)``
+  coverage invariant plus stable-tie winner parity.
 
 Enablement (``maybe_wrap_engine``): ``SissoConfig.debug_checks`` wins
 when set; otherwise the ``REPRO_DEBUG`` environment variable — ``1`` for
@@ -35,7 +39,7 @@ from typing import Any, Optional
 import numpy as np
 
 from ..core.sis import ReducedBlock
-from ..engine.base import Backend, Engine
+from ..engine.base import Backend, Engine, ScoringBackend
 
 LEVEL_OFF = 0
 LEVEL_STRUCTURAL = 1
@@ -209,8 +213,8 @@ class DebugBackend(Backend):
 
     Transparent by construction — capability flags and backend-specific
     attributes (autotune hooks, kernel config) read through to the
-    wrapped backend, so the Engine routes identically with or without
-    the sanitizer.
+    wrapped backend, so a fit takes the same paths with or without the
+    sanitizer.
     """
 
     def __init__(self, inner: Backend, level: int = LEVEL_STRUCTURAL):
@@ -229,14 +233,6 @@ class DebugBackend(Backend):
     @property
     def l0_widths(self):  # type: ignore[override]
         return self._inner.l0_widths
-
-    @property
-    def reduces_blocks(self):  # type: ignore[override]
-        return self._inner.reduces_blocks
-
-    @property
-    def bit_exact_oracle(self):  # type: ignore[override]
-        return self._inner.bit_exact_oracle
 
     @property
     def kernel_problems(self):  # type: ignore[override]
@@ -288,52 +284,59 @@ class DebugBackend(Backend):
             )
         return values, valid
 
-    # -- phase 2 -------------------------------------------------------
-    def sis_scores(self, values, ctx):
-        scores = self._inner.sis_scores(values, ctx)
-        _assert_scores(
-            "sis_scores", scores, np.shape(values)[0],
-            allow_pos_inf=False, allow_neg_inf=True,
-        )
-        return scores
+    # -- reduced entries -----------------------------------------------
+    def _reduce(self, entry: str, n_source: int, n_keep: int, *,
+                largest: bool, topk, full, mask=None) -> ReducedBlock:
+        """One reduced entry with its checks.
 
-    def sis_scores_deferred(self, op_id, a, b, ctx, l_bound, u_bound):
-        scores = self._inner.sis_scores_deferred(
-            op_id, a, b, ctx, l_bound, u_bound
-        )
-        _assert_scores(
-            "sis_scores_deferred", scores, np.shape(a)[0],
-            allow_pos_inf=False, allow_neg_inf=True,
-        )
-        return scores
-
-    def sis_topk(self, values, ctx, n_keep, mask=None):
-        rb = self._inner.sis_topk(values, ctx, n_keep, mask=mask)
-        n_source = np.shape(values)[0]
-        _assert_reduced_block("sis_topk", rb, n_keep, n_source, largest=True)
+        ``topk()`` runs the inner backend's ``entry``; ``full(be)`` forms
+        the block's full vector on the :class:`ScoringBackend` ``be``.
+        When the inner backend reduces on the host through
+        ``ScoringBackend``'s default (its class does not override
+        ``entry``), the vector is formed here, its NaN/Inf policy checked,
+        and reduced the same way — bit-identical to the inner's answer.
+        Otherwise the inner's winners are checked structurally and, at
+        verify level, against a host reduction of a full vector from the
+        inner backend if it scores whole blocks, else from the reference
+        oracle on the same operands (a distribution wrapper, say).
+        """
+        inner = self._inner
+        scoring = isinstance(inner, ScoringBackend)
+        if scoring and getattr(type(inner), entry) is getattr(ScoringBackend,
+                                                              entry):
+            scores = full(inner)
+            _assert_scores(entry.replace("_topk", "_scores"), scores,
+                           n_source, allow_pos_inf=not largest,
+                           allow_neg_inf=largest)
+            return ReducedBlock.reduce_host(scores, n_keep, mask=mask,
+                                            largest=largest)
+        rb = topk()
+        _assert_reduced_block(entry, rb, n_keep, n_source, largest=largest)
         if self._level >= LEVEL_VERIFY:
+            from ..engine.reference import ReferenceBackend
+
             _assert_topk_matches(
-                "sis_topk", rb, self._inner.sis_scores(values, ctx), n_keep,
-                largest=True, mask=mask,
-            )
+                entry, rb, full(inner if scoring else ReferenceBackend()),
+                n_keep, largest=largest, mask=mask)
         return rb
+
+    # -- phase 2 -------------------------------------------------------
+    def sis_topk(self, values, ctx, n_keep, mask=None):
+        return self._reduce(
+            "sis_topk", np.shape(values)[0], n_keep, largest=True, mask=mask,
+            topk=lambda: self._inner.sis_topk(values, ctx, n_keep,
+                                              mask=mask),
+            full=lambda be: be.sis_scores(values, ctx),
+        )
 
     def sis_topk_deferred(self, op_id, a, b, ctx, l_bound, u_bound, n_keep):
-        rb = self._inner.sis_topk_deferred(
-            op_id, a, b, ctx, l_bound, u_bound, n_keep
+        return self._reduce(
+            "sis_topk_deferred", np.shape(a)[0], n_keep, largest=True,
+            topk=lambda: self._inner.sis_topk_deferred(
+                op_id, a, b, ctx, l_bound, u_bound, n_keep),
+            full=lambda be: be.sis_scores_deferred(
+                op_id, a, b, ctx, l_bound, u_bound),
         )
-        _assert_reduced_block(
-            "sis_topk_deferred", rb, n_keep, np.shape(a)[0], largest=True
-        )
-        if self._level >= LEVEL_VERIFY:
-            _assert_topk_matches(
-                "sis_topk_deferred", rb,
-                self._inner.sis_scores_deferred(
-                    op_id, a, b, ctx, l_bound, u_bound
-                ),
-                n_keep, largest=True,
-            )
-        return rb
 
     # -- phase 3 -------------------------------------------------------
     def prepare_l0(self, x, y, layout, method="gram", dtype=np.float64,
@@ -352,25 +355,18 @@ class DebugBackend(Backend):
             )
         return prob
 
-    def l0_scores(self, prob, tuples):
-        scores = self._inner.l0_scores(prob, tuples)
-        _assert_scores(
-            "l0_scores", scores, np.shape(tuples)[0],
-            allow_pos_inf=True, allow_neg_inf=False,
-        )
-        return scores
-
     def l0_topk(self, prob, tuples, n_keep):
-        rb = self._inner.l0_topk(prob, tuples, n_keep)
-        _assert_reduced_block(
-            "l0_topk", rb, n_keep, np.shape(tuples)[0], largest=False
+        def full(be):
+            p = prob if be is self._inner else be.prepare_l0(
+                prob.x, prob.y, prob.layout, method=prob.method,
+                dtype=prob.dtype, problem=prob.problem)
+            return be.l0_scores(p, tuples)
+
+        return self._reduce(
+            "l0_topk", np.shape(tuples)[0], n_keep, largest=False,
+            topk=lambda: self._inner.l0_topk(prob, tuples, n_keep),
+            full=full,
         )
-        if self._level >= LEVEL_VERIFY:
-            _assert_topk_matches(
-                "l0_topk", rb, self._inner.l0_scores(prob, tuples), n_keep,
-                largest=False,
-            )
-        return rb
 
     def l0_device_reducer(self, prob, width, k_local):
         # traceable closure: wrapping its returns would break shard_map

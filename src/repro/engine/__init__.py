@@ -18,7 +18,7 @@ from __future__ import annotations
 
 from typing import Union
 
-from .base import Backend, Engine, L0Problem, ReducedBlock
+from .base import Backend, Engine, L0Problem, ReducedBlock, ScoringBackend
 from .streaming import BlockPrefetcher
 from .reference import ReferenceBackend
 from .jnp_backend import JnpBackend
@@ -78,6 +78,6 @@ def get_engine(spec: Union[str, Engine, Backend, None] = None, **opts) -> Engine
 __all__ = [
     "Backend", "Engine", "L0Problem", "ReducedBlock", "BACKENDS",
     "BlockPrefetcher", "DEFAULT_BACKEND", "get_engine", "ReferenceBackend",
-    "JnpBackend", "PallasBackend", "ResilientExecution", "ShardedBackend",
-    "ShardedExecution",
+    "JnpBackend", "PallasBackend", "ResilientExecution", "ScoringBackend",
+    "ShardedBackend", "ShardedExecution",
 ]

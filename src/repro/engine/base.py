@@ -19,9 +19,10 @@ sharded     composable distribution wrapper over any inner backend
 
 Core code (``core/sis.py``, ``core/l0.py``, ``core/feature_space.py``)
 never branches on *how* a phase executes; it calls the :class:`Engine` it
-was handed.  Capability flags let a backend decline a (phase, shape) combo
-— the class hierarchy then falls back to the jnp path, so every backend
-accepts every request.
+was handed and gets each block's winners back (:class:`Backend`).
+Capability flags let a backend decline a (phase, shape) combo — the class
+hierarchy then falls back to the jnp path, so every backend accepts every
+request.
 """
 from __future__ import annotations
 
@@ -71,21 +72,22 @@ class L0Problem:
 class Backend(abc.ABC):
     """One execution strategy for the three hot phases.
 
+    The contract between the engine and core is the *reduced block*: every
+    screening and ℓ0 entry takes the number of winners the caller keeps
+    (``n_keep``) and answers with a :class:`~repro.core.sis.ReducedBlock`
+    of at most that many (index, score) winners, best-first, in the stable
+    first-occurrence tie order of a sort of the full block.  No full score
+    vector crosses this boundary; backends that form one reduce it
+    themselves (:class:`ScoringBackend`).
+
     Capability flags:
 
-    * ``fused_deferred`` — :meth:`sis_scores_deferred` generates, validates
-      and scores candidate values without materializing them (paper P3); if
-      False the default eval→score→mask composition is used.
-    * ``l0_widths`` — tuple widths :meth:`l0_scores` accelerates with a
+    * ``fused_deferred`` — :meth:`sis_topk_deferred` generates, validates
+      and scores candidate values without materializing them (paper P3).
+    * ``l0_widths`` — tuple widths :meth:`l0_topk` accelerates with a
       backend-native kernel; other widths delegate to the generic (jnp)
       implementation.  ``None`` means the backend's one implementation
-      covers every width (reference, jnp).  Replaces the former boolean
-      ``l0_pairs_only`` flag now that the Pallas path covers widths 2–4.
-    * ``reduces_blocks`` — the backend merges score blocks *on device*:
-      when a caller passes ``n_keep`` through the :class:`Engine`, the
-      ``*_topk`` entry points return a
-      :class:`~repro.core.sis.ReducedBlock` of O(k) winners instead of a
-      full block-length vector (engine/sharded.py).
+      covers every width (reference, jnp).
     * ``kernel_problems`` — problem kinds (core/problem.py) the backend's
       *native* fast paths cover; a problem-tagged context/L0Problem whose
       kind is outside this set routes to the generic jnp / compose
@@ -93,7 +95,6 @@ class Backend(abc.ABC):
       kernels are regression-only, so ``PallasBackend`` declares
       ``("regression",)`` and classification falls through to its jnp
       parent — semantics stay canonical, only the acceleration differs).
-    * ``bit_exact_oracle`` — results define the parity baseline.
 
     Precision: ``compute_dtype`` (set via :meth:`set_precision` from the
     ``precision.py`` registry) is the dtype device backends run the
@@ -105,8 +106,6 @@ class Backend(abc.ABC):
     name: str = "abstract"
     fused_deferred: bool = False
     l0_widths: Optional[Tuple[int, ...]] = None
-    reduces_blocks: bool = False
-    bit_exact_oracle: bool = False
     compute_dtype: Any = np.float64
     kernel_problems: Tuple[str, ...] = ("regression", "classification")
 
@@ -151,37 +150,6 @@ class Backend(abc.ABC):
 
     # -- phase 2: SIS screening ----------------------------------------
     @abc.abstractmethod
-    def sis_scores(self, values: np.ndarray, ctx: ScoreContext) -> np.ndarray:
-        """Projection scores (B,) of materialized candidate values."""
-
-    def sis_scores_deferred(
-        self,
-        op_id: int,
-        a: np.ndarray,
-        b: np.ndarray,
-        ctx: ScoreContext,
-        l_bound: float,
-        u_bound: float,
-    ) -> np.ndarray:
-        """Scores (B,) of *deferred* candidates; invalid -> -inf.
-
-        Default composition: evaluate, apply value rules, score.  Backends
-        with ``fused_deferred`` overrule this with a fused kernel.
-        """
-        self.record_sis_path(SIS_EVAL_SCREEN)
-        values, valid = self.eval_block(op_id, a, b, l_bound, u_bound)
-        scores = self.sis_scores(values, ctx)
-        return np.where(valid, scores, -np.inf)
-
-    # -- pre-reduced blocks: device-merged top-k entry points ----------
-    #
-    # The Engine routes through these (instead of the full-vector methods
-    # above) when the caller supplies ``n_keep`` and the backend declares
-    # ``reduces_blocks``.  The defaults reduce on host with the stable
-    # tie order the full-vector TopK merge would produce, so a reducing
-    # wrapper (engine/sharded.py) and a plain backend are interchangeable
-    # winner-for-winner.
-
     def sis_topk(
         self,
         values: np.ndarray,
@@ -190,10 +158,8 @@ class Backend(abc.ABC):
         mask: Optional[np.ndarray] = None,
     ) -> ReducedBlock:
         """Top-``n_keep`` of a materialized block; ``mask`` rows excluded."""
-        return ReducedBlock.reduce_host(
-            self.sis_scores(values, ctx), n_keep, mask=mask, largest=True
-        )
 
+    @abc.abstractmethod
     def sis_topk_deferred(
         self,
         op_id: int,
@@ -204,18 +170,8 @@ class Backend(abc.ABC):
         u_bound: float,
         n_keep: int,
     ) -> ReducedBlock:
-        """Top-``n_keep`` of a deferred candidate block."""
-        return ReducedBlock.reduce_host(
-            self.sis_scores_deferred(op_id, a, b, ctx, l_bound, u_bound),
-            n_keep, largest=True,
-        )
-
-    def l0_topk(self, prob: "L0Problem", tuples: np.ndarray,
-                n_keep: int) -> ReducedBlock:
-        """Best-``n_keep`` (ascending SSE) of one tuple block."""
-        return ReducedBlock.reduce_host(
-            self.l0_scores(prob, tuples), n_keep, largest=False
-        )
+        """Top-``n_keep`` of a *deferred* candidate block; candidates the
+        value rules reject never win."""
 
     def l0_device_reducer(self, prob: "L0Problem", width: int,
                           k_local: int):
@@ -233,7 +189,7 @@ class Backend(abc.ABC):
         merges the O(k) winner panels across shards without ever
         materializing a per-shard SSE vector on the host.  ``None`` (the
         default) means "no device reducer for this problem/width"; the
-        wrapper falls back to its full-vector scorer + per-shard
+        wrapper falls back to a traceable exact scorer + per-shard
         ``top_k``.  Reducer outputs are a fp32 prescreen: the wrapper must
         rescore the merged survivors in fp64 before final ranking.
         """
@@ -261,8 +217,9 @@ class Backend(abc.ABC):
         return prob
 
     @abc.abstractmethod
-    def l0_scores(self, prob: L0Problem, tuples: np.ndarray) -> np.ndarray:
-        """Tuple objectives (B,), ascending-is-better, for (B, n) tuples.
+    def l0_topk(self, prob: "L0Problem", tuples: np.ndarray,
+                n_keep: int) -> ReducedBlock:
+        """Best-``n_keep`` (ascending objective) of one (B, n) tuple block.
 
         Regression: total SSE of the per-task LSQ fits; classification:
         domain-overlap count + tie term (core/problem.py)."""
@@ -303,22 +260,73 @@ class Backend(abc.ABC):
         return eval_program_host(program, x)
 
 
+class ScoringBackend(Backend):
+    """A backend that does the screening math itself: reference, jnp and,
+    through jnp, pallas.
+
+    It scores whole blocks (:meth:`sis_scores`, :meth:`sis_scores_deferred`,
+    :meth:`l0_scores`) and, unless it overrides a reduced entry with a
+    device reduction, answers the :class:`Backend` contract by reducing
+    its own full vector on the host (:meth:`ReducedBlock.reduce_host`).
+    That reduction keeps the stable first-occurrence tie order of a sort
+    of the full vector, so a host-reducing backend and a device-merging
+    one (engine/sharded.py) are interchangeable winner for winner.
+    """
+
+    @abc.abstractmethod
+    def sis_scores(self, values: np.ndarray, ctx: ScoreContext) -> np.ndarray:
+        """Projection scores (B,) of materialized candidate values."""
+
+    def sis_scores_deferred(
+        self,
+        op_id: int,
+        a: np.ndarray,
+        b: np.ndarray,
+        ctx: ScoreContext,
+        l_bound: float,
+        u_bound: float,
+    ) -> np.ndarray:
+        """Scores (B,) of *deferred* candidates; invalid -> -inf.
+
+        Default composition: evaluate, apply value rules, score.  Backends
+        with ``fused_deferred`` overrule this with a fused kernel.
+        """
+        self.record_sis_path(SIS_EVAL_SCREEN)
+        values, valid = self.eval_block(op_id, a, b, l_bound, u_bound)
+        scores = self.sis_scores(values, ctx)
+        return np.where(valid, scores, -np.inf)
+
+    @abc.abstractmethod
+    def l0_scores(self, prob: L0Problem, tuples: np.ndarray) -> np.ndarray:
+        """Tuple objectives (B,), ascending-is-better, for (B, n) tuples."""
+
+    def sis_topk(self, values, ctx, n_keep, mask=None) -> ReducedBlock:
+        return ReducedBlock.reduce_host(
+            self.sis_scores(values, ctx), n_keep, mask=mask, largest=True
+        )
+
+    def sis_topk_deferred(self, op_id, a, b, ctx, l_bound, u_bound,
+                          n_keep) -> ReducedBlock:
+        return ReducedBlock.reduce_host(
+            self.sis_scores_deferred(op_id, a, b, ctx, l_bound, u_bound),
+            n_keep, largest=True,
+        )
+
+    def l0_topk(self, prob, tuples, n_keep) -> ReducedBlock:
+        return ReducedBlock.reduce_host(
+            self.l0_scores(prob, tuples), n_keep, largest=False
+        )
+
+
 class Engine:
     """Phase→backend dispatcher threaded through the whole SISSO pipeline.
 
     A thin façade over one :class:`Backend`: the solver, feature space, SIS
     screen and ℓ0 search all hold the same ``Engine`` and never ask *how*
-    their math runs.  Exists as its own object (rather than passing the
-    backend around) so cross-phase policy — streaming, async double
-    buffering, multi-host merges — lands here without touching core code.
-
-    The ``n_keep`` keywords are how distribution composes in: when the
-    caller states how many winners it will keep *and* the backend merges
-    on device (``reduces_blocks``), the call returns a
-    :class:`~repro.core.sis.ReducedBlock` of O(n_keep) winners instead of
-    a block-length score vector — the host boundary carries k-sized
-    payloads, never full scores.  Callers that omit ``n_keep`` always get
-    the classic full vectors.
+    their math runs.  Every screening and ℓ0 call states how many winners
+    the caller keeps and gets a :class:`~repro.core.sis.ReducedBlock` of
+    at most that many back — the host boundary carries k-sized payloads,
+    never full scores.
     """
 
     def __init__(self, backend: Backend):
@@ -327,10 +335,6 @@ class Engine:
     @property
     def name(self) -> str:
         return self.backend.name
-
-    @property
-    def reduces_blocks(self) -> bool:
-        return self.backend.reduces_blocks
 
     def set_precision(self, precision: str) -> "Engine":
         self.backend.set_precision(precision)
@@ -342,24 +346,13 @@ class Engine:
     def eval_block(self, op_id, a, b, l_bound, u_bound):
         return self.backend.eval_block(op_id, a, b, l_bound, u_bound)
 
-    def sis_scores(self, values, ctx, n_keep=None, mask=None):
-        if n_keep is not None and self.backend.reduces_blocks:
-            return self.backend.sis_topk(values, ctx, n_keep, mask=mask)
-        scores = self.backend.sis_scores(values, ctx)
-        if mask is not None:
-            # honor the exclusion mask on the full-vector path too — the
-            # kwarg must mean the same thing on every backend
-            scores = np.where(np.asarray(mask, bool), scores, -np.inf)
-        return scores
+    def sis_topk(self, values, ctx, n_keep, mask=None) -> ReducedBlock:
+        return self.backend.sis_topk(values, ctx, n_keep, mask=mask)
 
-    def sis_scores_deferred(self, op_id, a, b, ctx, l_bound, u_bound,
-                            n_keep=None):
-        if n_keep is not None and self.backend.reduces_blocks:
-            return self.backend.sis_topk_deferred(
-                op_id, a, b, ctx, l_bound, u_bound, n_keep
-            )
-        return self.backend.sis_scores_deferred(
-            op_id, a, b, ctx, l_bound, u_bound
+    def sis_topk_deferred(self, op_id, a, b, ctx, l_bound, u_bound,
+                          n_keep) -> ReducedBlock:
+        return self.backend.sis_topk_deferred(
+            op_id, a, b, ctx, l_bound, u_bound, n_keep
         )
 
     def prepare_l0(self, x, y, layout, method="gram", dtype=None,
@@ -368,10 +361,8 @@ class Engine:
         return self.backend.prepare_l0(x, y, layout, method=method,
                                        dtype=dtype, problem=problem)
 
-    def l0_scores(self, prob, tuples, n_keep=None):
-        if n_keep is not None and self.backend.reduces_blocks:
-            return self.backend.l0_topk(prob, tuples, n_keep)
-        return self.backend.l0_scores(prob, tuples)
+    def l0_topk(self, prob, tuples, n_keep) -> ReducedBlock:
+        return self.backend.l0_topk(prob, tuples, n_keep)
 
     def eval_program(self, program, x):
         return self.backend.eval_program(program, x)

@@ -24,7 +24,7 @@ from ..core.sis import (
     ScoreContext, scores_from_reductions, screen_reductions,
 )
 from ..core.validity import value_rules_jnp
-from .base import Backend, L0Problem
+from .base import L0Problem, ScoringBackend
 
 
 @functools.partial(jax.jit, static_argnames=("op_id",))
@@ -44,7 +44,7 @@ def _score_jit(values, membership, y_tilde, counts, n_residuals):
 _overlap_score_jit = jax.jit(overlap_scores_ops)
 
 
-class JnpBackend(Backend):
+class JnpBackend(ScoringBackend):
     name = "jnp"
 
     def __init__(self):

@@ -2,9 +2,8 @@
 
 * deferred SIS — ``kernels/fused_sis.py``: candidates are generated,
   validated and scored in VMEM, never materialized to HBM (paper P3,
-  deepened).  With ``n_keep`` routing (``reduces_blocks``) the kernel's
-  reduced top-k epilogue + device merge return O(k) winners — full score
-  vectors never exist, in HBM or on the host.
+  deepened).  The kernel's reduced top-k epilogue + device merge return
+  O(k) winners — full score vectors never exist, in HBM or on the host.
 * ℓ0 pairs — ``kernels/ops.py:l0_score_pairs``: closed-form SSE gathered
   from Gram statistics (the tile kernel's math, XLA-gather form, in the
   compute dtype).
@@ -100,7 +99,6 @@ def _l0_shard_reducer(n: int, k: int, block_t: int, rows: int,
 class PallasBackend(JnpBackend):
     name = "pallas"
     fused_deferred = True
-    reduces_blocks = True
     # width 2 = closed-form pair gather; widths >= 3 = the Gram-gather
     # kernel, whose prefix gathers and unrolled SPD elimination are
     # width-generic (8 is a compile-time sanity ceiling, not a kernel

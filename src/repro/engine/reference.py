@@ -15,12 +15,11 @@ import jax.numpy as jnp
 from ..core.operators import apply_op
 from ..core.sis import ScoreContext
 from ..core.validity import value_rules_host
-from .base import Backend, L0Problem
+from .base import L0Problem, ScoringBackend
 
 
-class ReferenceBackend(Backend):
+class ReferenceBackend(ScoringBackend):
     name = "reference"
-    bit_exact_oracle = True
 
     def eval_block(self, op_id, a, b, l_bound, u_bound):
         a = np.asarray(a, np.float64)

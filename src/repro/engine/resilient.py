@@ -108,14 +108,6 @@ class ResilientExecution(Backend):
         return self._inner.l0_widths
 
     @property
-    def reduces_blocks(self):  # type: ignore[override]
-        return self._inner.reduces_blocks
-
-    @property
-    def bit_exact_oracle(self):  # type: ignore[override]
-        return self._inner.bit_exact_oracle
-
-    @property
     def kernel_problems(self):  # type: ignore[override]
         return self._inner.kernel_problems
 
@@ -277,19 +269,6 @@ class ResilientExecution(Backend):
         )
 
     # -- phase 2 -------------------------------------------------------
-    def sis_scores(self, values, ctx):
-        return self._dispatch(
-            "sis_scores", lambda be: be.sis_scores(values, ctx)
-        )
-
-    def sis_scores_deferred(self, op_id, a, b, ctx, l_bound, u_bound):
-        return self._dispatch(
-            "sis_scores_deferred",
-            lambda be: be.sis_scores_deferred(
-                op_id, a, b, ctx, l_bound, u_bound
-            ),
-        )
-
     def sis_topk(self, values, ctx, n_keep, mask=None):
         return self._dispatch(
             "sis_topk",
@@ -311,12 +290,6 @@ class ResilientExecution(Backend):
         # a fault — delegate without retry/demotion
         return self._inner.prepare_l0(
             x, y, layout, method=method, dtype=dtype, problem=problem
-        )
-
-    def l0_scores(self, prob, tuples):
-        return self._dispatch(
-            "l0_scores",
-            lambda be: be.l0_scores(self._prob_for(prob, be), tuples),
         )
 
     def l0_topk(self, prob, tuples, n_keep):

@@ -12,8 +12,7 @@ what the parity suite needs before a multi-host run is attempted.
 
 The merge discipline is the paper's: each shard keeps only its local top
 candidates and a k-sized all-gather combines them (SISSO++ never ships
-full score vectors off-device).  Through the :class:`~.base.Engine`
-``n_keep`` routing, ``sis_scores``/``l0_scores`` return
+full score vectors off-device), so ``sis_topk``/``l0_topk`` return
 :class:`~repro.core.sis.ReducedBlock` winners — O(k) payloads across the
 host boundary.  When the inner backend brings the fused Pallas deferred
 kernel (pallas), the wrapper runs it *inside* ``shard_map``
@@ -36,10 +35,9 @@ from jax.sharding import Mesh
 
 from ..core.distributed import (
     _dp_axes, _sample_axis, count_merge, fused_sis_topk_sharded,
-    gram_operands, gram_topk_scorer, l0_pair_sses_sharded, make_l0_topk_fn,
-    make_l0_topk_reduced_fn, overlap_operands, overlap_sis_scores_sharded,
-    overlap_sis_topk_sharded, overlap_topk_scorer, qr_topk_scorer,
-    sis_scores_sharded, sis_topk_sharded,
+    gram_operands, gram_topk_scorer, make_l0_topk_fn,
+    make_l0_topk_reduced_fn, overlap_operands, overlap_sis_topk_sharded,
+    overlap_topk_scorer, qr_topk_scorer, sis_topk_sharded,
 )
 from ..core.l0 import compute_gram_stats
 from ..core.sis import ReducedBlock, ScoreContext
@@ -67,12 +65,7 @@ class ShardedExecution(Backend):
     * ``sis_topk_deferred`` — the shard_map-wrapped fused Pallas kernel
       when ``inner.fused_deferred`` (and samples are replicated);
       eval-compose otherwise.
-    * the legacy full-vector ``sis_scores``/``l0_scores`` (host-side
-      merge callers, parity suites): sharded math, full result.
     """
-
-    reduces_blocks = True
-    bit_exact_oracle = False
 
     def __init__(self, inner: Union[Backend, Engine, str, None] = None,
                  mesh: Optional[Mesh] = None, **inner_opts):
@@ -153,21 +146,6 @@ class ShardedExecution(Backend):
         row_mask[:f] = True if mask is None else np.asarray(mask, bool)
         return jnp.asarray(vp, self.compute_dtype), jnp.asarray(row_mask), f
 
-    def sis_scores(self, values, ctx: ScoreContext) -> np.ndarray:
-        if len(values) == 0:
-            return np.zeros((0,))
-        if ctx.problem == "classification" \
-                and _sample_axis(self.mesh) is not None:
-            # the overlap score needs whole sample rows; sample-sharded
-            # meshes fall back to the inner backend (host merge upstream)
-            return self.inner.sis_scores(values, ctx)
-        vp, row_mask, f = self._padded_values(values, None)
-        if ctx.problem == "classification":
-            scores = overlap_sis_scores_sharded(self.mesh, vp, ctx, row_mask)
-        else:
-            scores = sis_scores_sharded(self.mesh, vp, ctx, row_mask)
-        return np.asarray(scores, np.float64)[:f]
-
     def sis_topk(self, values, ctx: ScoreContext, n_keep: int,
                  mask=None) -> ReducedBlock:
         if len(values) == 0:
@@ -177,10 +155,9 @@ class ShardedExecution(Backend):
             )
         if ctx.problem == "classification" \
                 and _sample_axis(self.mesh) is not None:
-            return ReducedBlock.reduce_host(
-                self.inner.sis_scores(values, ctx), n_keep, mask=mask,
-                largest=True,
-            )
+            # the overlap score needs whole sample rows; sample-sharded
+            # meshes leave the screen to the inner backend
+            return self.inner.sis_topk(values, ctx, n_keep, mask=mask)
         vp, row_mask, f = self._padded_values(values, mask)
         if ctx.problem == "classification":
             vals, idx = overlap_sis_topk_sharded(
@@ -191,14 +168,6 @@ class ShardedExecution(Backend):
         return ReducedBlock(
             indices=idx[keep].astype(np.int64), scores=vals[keep], n_source=f
         )
-
-    def sis_scores_deferred(self, op_id, a, b, ctx, l_bound, u_bound):
-        # full-vector compose path (host-merge callers): inner eval,
-        # sharded scoring
-        self.record_sis_path(SIS_EVAL_SCREEN)
-        values, valid = self.inner.eval_block(op_id, a, b, l_bound, u_bound)
-        scores = self.sis_scores(values, ctx)
-        return np.where(valid, scores, -np.inf)
 
     def sis_topk_deferred(self, op_id, a, b, ctx, l_bound, u_bound,
                           n_keep) -> ReducedBlock:
@@ -223,28 +192,6 @@ class ShardedExecution(Backend):
         return self.sis_topk(values, ctx, n_keep, mask=valid)
 
     # -- ℓ0: sharded scoring -------------------------------------------
-    def l0_scores(self, prob: L0Problem, tuples: np.ndarray) -> np.ndarray:
-        tuples = np.asarray(tuples)
-        if len(tuples) == 0 or tuples.shape[1] != 2 \
-                or prob.problem != "regression" or prob.method != "gram":
-            # widths the pair shard-map doesn't cover run on the inner
-            # backend (full-vector callers only; the reduced path below
-            # shards every width)
-            return self.inner.l0_scores(prob, tuples)
-        b = len(tuples)
-        bp = self._pad(b)
-        pairs = np.zeros((bp, 2), np.int32)
-        pairs[:b] = tuples
-        pairs[b:] = (0, min(1, prob.m - 1))  # benign pair, +inf'd on device
-        valid = np.zeros((bp,), bool)
-        valid[:b] = True
-        sses = l0_pair_sses_sharded(
-            self.mesh, jnp.asarray(prob.x, prob.dtype),
-            jnp.asarray(prob.y, prob.dtype), prob.layout,
-            jnp.asarray(pairs), jnp.asarray(valid),
-        )
-        return np.asarray(sses, np.float64)[:b]
-
     def _l0_reducer(self, prob: L0Problem, width: int, n_keep: int,
                     b_shard: int):
         """Compiled sharded ℓ0 reducer for one (width, n_keep, shard) shape.
@@ -254,8 +201,8 @@ class ShardedExecution(Backend):
         keeping as many survivors as the inner backend rescores per block
         (``Backend.rescore_window``) — the kernel screen is an fp32 bound,
         so the wrapper rescores merged survivors in fp64 before the final
-        ranking.  Falls back to the full-vector traceable scorers
-        (overlap / Gram closed form / QR) when the inner backend has none.
+        ranking.  Falls back to the exact traceable scorers (overlap /
+        Gram closed form / QR) when the inner backend has none.
         Returns ``(build, operands, prescreen, k_local)``; ``build(tuples,
         b)`` gives the compiled reducer for one padded block of ``b`` live
         tuples (the inner backend specializes its device reducer to what it

@@ -12,10 +12,9 @@ scoring and the host top-k merge moves off the critical path entirely.
 Results are always yielded **in submission order**, which is what keeps the
 work journal's "block index ⇒ tuples" resume contract intact — streaming
 changes *when* work happens, never *what* a block means.  The prefetcher is
-shape-agnostic by design: a scoring ``fn`` may return full score vectors or
-pre-reduced :class:`~repro.core.sis.ReducedBlock` winners (a device-merging
-backend behind the Engine's ``n_keep`` routing) — reduced blocks are
-forwarded unchanged, and only the consumer's merge branch differs.
+shape-agnostic: whatever the scoring ``fn`` returns (in the sweeps, a
+block's :class:`~repro.core.sis.ReducedBlock` winners) is forwarded
+unchanged.
 
 This lives in ``engine/`` (not ``core/``) deliberately: it is cross-phase
 execution policy, the kind of thing the Engine façade exists to own

@@ -86,12 +86,11 @@ def worker_main(rank: int) -> None:
         # here (os._exit) holding its lease — the preemption under test
         faults.check("worker.tick")
         tuples = np.asarray(enum.block_tuples(bi))
-        sses = np.asarray(eng.l0_scores(prob, tuples, n_keep=N_KEEP))
-        # the exact per-block panel l0_search merges: stable argsort so
-        # objective ties resolve identically
-        part = np.argsort(sses, kind="stable")[: min(N_KEEP, len(sses))]
-        panel = {"sse": sses[part].tolist(),
-                 "tuples": tuples[part].astype(np.int64).tolist()}
+        # the exact per-block panel l0_search merges: the block's winners
+        # in stable order, so objective ties resolve identically
+        rb = eng.l0_topk(prob, tuples, N_KEEP)
+        panel = {"sse": rb.scores.tolist(),
+                 "tuples": tuples[rb.indices].astype(np.int64).tolist()}
         sys.stdout.write(f"RESULT {bi} {json.dumps(panel)}\n")
         sys.stdout.flush()
 

@@ -37,35 +37,38 @@ def main() -> int:
     # ---- materialized SIS: f=101 forces in-shard padding masks ----
     f = 101
     x = rng.uniform(0.5, 3.0, (f, 156))
-    serial = np.asarray(eng_j.sis_scores(x, ctx), np.float64)
+    serial = np.asarray(eng_j.backend.sis_scores(x, ctx), np.float64)
     want = set(np.argsort(-serial, kind="stable")[:9])
     for eng in (eng_sh, eng_shp):
-        rb = eng.sis_scores(x, ctx, n_keep=9)
+        rb = eng.sis_topk(x, ctx, 9)
         assert isinstance(rb, ReducedBlock) and len(rb) == 9
         assert (rb.indices < f).all()
         assert set(rb.indices) == want, (sorted(rb.indices), sorted(want))
         np.testing.assert_allclose(
             rb.scores, serial[rb.indices], rtol=1e-9, atol=1e-12)
-        # full-vector path: padded rows must have been masked on device
-        full = eng.sis_scores(x, ctx)
-        np.testing.assert_allclose(full, serial, rtol=1e-9, atol=1e-12)
+        # every row asked for: each real row once, padded rows masked on
+        # device
+        every = eng.sis_topk(x, ctx, f)
+        assert sorted(every.indices) == list(range(f)), every.indices
+        np.testing.assert_allclose(every.scores, serial[every.indices],
+                                   rtol=1e-9, atol=1e-12)
     print("SIS sharded(8) == serial winners: OK")
 
     # ---- deferred SIS: fused shard_map kernel vs pallas host path ----
     pal = get_engine("pallas")
     a, b = x[:48], x[48:96]
-    want_s = pal.sis_scores_deferred(a=a, b=b, op_id=om.DIV, ctx=ctx,
-                                     l_bound=1e-5, u_bound=1e8)
+    want_s = pal.backend.sis_scores_deferred(
+        a=a, b=b, op_id=om.DIV, ctx=ctx, l_bound=1e-5, u_bound=1e8)
     worder = np.argsort(
         -np.where(np.isfinite(want_s), want_s, -np.inf), kind="stable")[:7]
     worder = worder[np.isfinite(np.asarray(want_s, np.float64)[worder])]
-    rb = eng_shp.sis_scores_deferred(om.DIV, a, b, ctx, 1e-5, 1e8, n_keep=7)
+    rb = eng_shp.sis_topk_deferred(om.DIV, a, b, ctx, 1e-5, 1e8, 7)
     assert set(rb.indices) == set(worder), (rb.indices, worder)
     np.testing.assert_allclose(
         np.sort(rb.scores), np.sort(np.asarray(want_s, np.float64)[worder]),
         rtol=1e-6)
     # compose path (sharded-over-jnp) must agree on the winner set too
-    rb_j = eng_sh.sis_scores_deferred(om.DIV, a, b, ctx, 1e-5, 1e8, n_keep=7)
+    rb_j = eng_sh.sis_topk_deferred(om.DIV, a, b, ctx, 1e-5, 1e8, 7)
     assert set(rb_j.indices) == set(worder), (rb_j.indices, worder)
     print("deferred SIS fused+sharded(8) == pallas winners: OK")
 
@@ -96,15 +99,17 @@ def main() -> int:
     cctx = cprob.build_sis_context(np.ones((1, s)), yc, lay,
                                    dtype=eng_sh.backend.score_ctx_dtype)
     xcand = rng.uniform(0.5, 3.0, (53, s))  # 53 % 8 != 0: padding masks
-    serial_c = np.asarray(get_engine("jnp").sis_scores(xcand, cctx))
+    serial_c = np.asarray(eng_j.backend.sis_scores(xcand, cctx))
     want_c = set(np.argsort(-serial_c, kind="stable")[:6])
     for eng in (eng_sh, eng_shp):
-        rb = eng.sis_scores(xcand, cctx, n_keep=6)
+        rb = eng.sis_topk(xcand, cctx, 6)
         assert isinstance(rb, ReducedBlock) and set(rb.indices) == want_c
         np.testing.assert_allclose(rb.scores, serial_c[rb.indices],
                                    rtol=1e-9, atol=1e-12)
-        full = eng.sis_scores(xcand, cctx)
-        np.testing.assert_allclose(full, serial_c, rtol=1e-9, atol=1e-12)
+        every = eng.sis_topk(xcand, cctx, len(xcand))
+        assert sorted(every.indices) == list(range(len(xcand)))
+        np.testing.assert_allclose(every.scores, serial_c[every.indices],
+                                   rtol=1e-9, atol=1e-12)
     ref_c = l0_search(xs, yc, lay, n_dim=2, n_keep=5, block=13,
                       engine=get_engine("reference"),
                       problem="classification")
@@ -119,7 +124,7 @@ def main() -> int:
     prob = eng_sh.prepare_l0(xs, y, lay)
     tuples = np.asarray(
         list(__import__("itertools").combinations(range(m), 3)), np.int32)
-    rb = eng_sh.l0_scores(prob, tuples, n_keep=5)
+    rb = eng_sh.l0_topk(prob, tuples, 5)
     assert isinstance(rb, ReducedBlock) and len(rb) == 5
     assert (rb.indices < len(tuples)).all() and (rb.scores[:-1]
                                                  <= rb.scores[1:]).all()

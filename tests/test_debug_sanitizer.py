@@ -19,14 +19,15 @@ from repro.debug import (
     wrap_backend,
 )
 from repro.engine import get_engine
-from repro.engine.base import Backend, Engine
+from repro.engine.base import Engine, ScoringBackend
 
 
-class FakeBackend(Backend):
-    """Minimal conformant backend the breakage fixtures subclass."""
+class FakeBackend(ScoringBackend):
+    """Minimal conformant backend the breakage fixtures subclass: it
+    scores whole blocks and reduces them on the host unless a fixture
+    overrides a reduced entry."""
 
     name = "fake"
-    reduces_blocks = True
 
     def eval_block(self, op_id, a, b, l_bound, u_bound):
         a = np.asarray(a, np.float64)
@@ -125,7 +126,7 @@ def test_nan_leak_in_scores_is_caught():
 
     wrapped = DebugBackend(NanLeak(), LEVEL_STRUCTURAL)
     with pytest.raises(ContractViolation, match="NaN"):
-        wrapped.sis_scores(_values(), _ctx())
+        wrapped.sis_topk(_values(), _ctx(), 3)
 
 
 def test_nan_leak_in_device_scores_is_caught_via_checkify():
@@ -138,7 +139,7 @@ def test_nan_leak_in_device_scores_is_caught_via_checkify():
 
     wrapped = DebugBackend(DeviceNanLeak(), LEVEL_STRUCTURAL)
     with pytest.raises(ContractViolation, match="NaN"):
-        wrapped.sis_scores(_values(), _ctx())
+        wrapped.sis_topk(_values(), _ctx(), 3)
 
 
 def test_neg_inf_in_l0_objective_is_caught():
@@ -150,7 +151,7 @@ def test_neg_inf_in_l0_objective_is_caught():
 
     wrapped = DebugBackend(NegInfL0(), LEVEL_STRUCTURAL)
     with pytest.raises(ContractViolation, match="-inf"):
-        wrapped.l0_scores(None, np.zeros((4, 2), np.int32))
+        wrapped.l0_topk(None, np.zeros((4, 2), np.int32), 2)
 
 
 def test_shape_mismatch_is_caught():
@@ -160,7 +161,7 @@ def test_shape_mismatch_is_caught():
 
     wrapped = DebugBackend(ShortScores(), LEVEL_STRUCTURAL)
     with pytest.raises(ContractViolation, match="shape"):
-        wrapped.sis_scores(_values(), _ctx())
+        wrapped.sis_topk(_values(), _ctx(), 3)
 
 
 def test_invalid_rows_flagged_valid_are_caught():
@@ -257,7 +258,7 @@ def test_wrap_is_idempotent_and_transparent():
     wrapped = wrap_backend(inner, LEVEL_STRUCTURAL)
     assert wrap_backend(wrapped, LEVEL_STRUCTURAL) is wrapped
     # capability flags read through
-    assert wrapped.reduces_blocks == inner.reduces_blocks
+    assert wrapped.kernel_problems == inner.kernel_problems
     assert wrapped.fused_deferred == inner.fused_deferred
     assert wrapped.compute_dtype == inner.compute_dtype
     eng = maybe_wrap_engine(Engine(wrapped), True)
@@ -272,7 +273,74 @@ def test_healthy_topk_roundtrip_through_engine():
     y = rng.normal(size=24)
     layout = TaskLayout.single(24)
     ctx = build_score_context(y[None, :], layout)
-    out = eng.sis_scores(values, ctx, n_keep=5)
+    out = eng.sis_topk(values, ctx, 5)
     assert isinstance(out, ReducedBlock)
     assert len(out.indices) == 5
     assert np.isfinite(np.asarray(out.scores)).all()
+
+
+def test_host_reduced_entries_match_the_inner_backend_bit_for_bit():
+    """For a backend that reduces on the host, the sanitizer forms the full
+    vector itself and reduces it the same way: the winners it returns are
+    the inner backend's own, bit for bit."""
+    from repro.core import operators as om
+
+    inner = get_engine("jnp").backend
+    wrapped = DebugBackend(inner, LEVEL_STRUCTURAL)
+    rng = np.random.default_rng(11)
+    values = rng.uniform(0.5, 3.0, (40, 24))
+    ctx = build_score_context(rng.normal(size=(2, 24)), TaskLayout.single(24))
+    mask = np.ones(40, bool)
+    mask[[2, 9]] = False
+    pairs = [
+        (wrapped.sis_topk(values, ctx, 7, mask=mask),
+         inner.sis_topk(values, ctx, 7, mask=mask)),
+        (wrapped.sis_topk_deferred(om.DIV, values[:20], values[20:], ctx,
+                                   1e-5, 1e8, 5),
+         inner.sis_topk_deferred(om.DIV, values[:20], values[20:], ctx,
+                                 1e-5, 1e8, 5)),
+    ]
+    prob = inner.prepare_l0(values[:8], values[8], TaskLayout.single(24))
+    tuples = np.asarray([(i, j) for i in range(8) for j in range(i + 1, 8)],
+                        np.int32)
+    pairs.append((wrapped.l0_topk(prob, tuples, 6),
+                  inner.l0_topk(prob, tuples, 6)))
+    for got, want in pairs:
+        assert np.array_equal(got.indices, want.indices)
+        assert np.array_equal(got.scores, want.scores)
+        assert got.n_source == want.n_source
+
+
+def test_verify_level_checks_a_distribution_wrapper_against_reference():
+    """A wrapper that forms no full vector of its own (the sharded
+    execution layer) is cross-checked at verify level against the
+    reference oracle on the same operands — and a wrong winner from such
+    a wrapper is caught."""
+    from repro.core import operators as om
+    from repro.engine import ShardedExecution
+
+    sharded = ShardedExecution(inner="jnp")
+    wrapped = DebugBackend(sharded, LEVEL_VERIFY)
+    rng = np.random.default_rng(12)
+    values = rng.uniform(0.5, 3.0, (30, 24))
+    ctx = build_score_context(rng.normal(size=(1, 24)), TaskLayout.single(24))
+    assert len(wrapped.sis_topk(values, ctx, 6)) == 6
+    assert len(wrapped.sis_topk_deferred(om.MUL, values[:15], values[15:],
+                                         ctx, 1e-5, 1e8, 4)) == 4
+    x = values[:7]
+    y = 1.5 * x[2] - x[5] + 0.01 * rng.normal(size=24)
+    prob = wrapped.prepare_l0(x, y, TaskLayout.single(24))
+    tuples = np.asarray([(i, j) for i in range(7) for j in range(i + 1, 7)],
+                        np.int32)
+    rb = wrapped.l0_topk(prob, tuples, 4)
+    assert tuple(tuples[rb.indices[0]]) == (2, 5)
+
+    class SwapsWinners(ShardedExecution):
+        def sis_topk(self, values, ctx, n_keep, mask=None):
+            rb = super().sis_topk(values, ctx, n_keep, mask=mask)
+            return ReducedBlock(indices=rb.indices[::-1].copy(),
+                                scores=rb.scores, n_source=rb.n_source)
+
+    bad = DebugBackend(SwapsWinners(inner="jnp"), LEVEL_VERIFY)
+    with pytest.raises(ContractViolation, match="wrong candidates"):
+        bad.sis_topk(values, ctx, 6)

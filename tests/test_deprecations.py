@@ -50,7 +50,7 @@ def test_shim_warns_once_and_routes(case, rng):
         # routes to the composable wrapper over jnp, same name/config spec
         assert isinstance(be, ShardedExecution)
         assert isinstance(be.inner, JnpBackend)
-        assert be.name == "sharded" and be.reduces_blocks
+        assert be.name == "sharded"
 
     elif case == "config_use_kernels":
         cfg = _warns_once(lambda: SissoConfig(use_kernels=True), "use_kernels")

@@ -5,6 +5,8 @@ import os
 import subprocess
 import sys
 
+import pytest
+
 
 _DIR = os.path.join(os.path.dirname(__file__), "distributed_progs")
 _SRC = os.path.join(os.path.dirname(__file__), "..", "src")
@@ -68,9 +70,9 @@ def test_sharded_pallas_fit_4dev_gives_the_reference_models():
 
 
 # ---------------------------------------------------------------------------
-# in-process (1-device mesh) regression + contract tests for the
-# distribution layer: the same code path as multi-shard, minus the padding
-# -- which is why padding is injected manually below.
+# regression + contract tests for the distribution layer: padding on a
+# 4-device mesh (subprocess), the rest in-process on a 1-device mesh — the
+# same code path as multi-shard, minus the padding.
 # ---------------------------------------------------------------------------
 
 def _ctx_and_values(rng, f=24, s=96):
@@ -83,54 +85,30 @@ def _ctx_and_values(rng, f=24, s=96):
     return x, ctx, layout
 
 
-def test_sis_padding_rows_masked_inside_sharded_fn():
+@pytest.fixture(scope="module")
+def padding_run():
+    """One 4-device run of the padding program, shared by the two padding
+    tests below (a block length is always divisible on this process's one
+    device, so padding needs its own interpreter)."""
+    return _run("check_sharded_padding.py")
+
+
+def test_sis_padding_rows_masked_inside_sharded_fn(padding_run):
     """Regression (prerequisite for device-side top-k): padding rows must
-    come back -inf from the sharded fn itself, not rely on host slice-off.
-    A zero-padded row scores 0.0 without the in-shard mask — which would
-    beat weakly-correlated real candidates in a device merge."""
-    import jax.numpy as jnp
-    import numpy as np
-    from repro.core.distributed import sis_scores_sharded
-    from repro.engine.sharded import default_mesh
-
-    rng = np.random.default_rng(0)
-    x, ctx, _ = _ctx_and_values(rng)
-    f = len(x)
-    x_pad = np.zeros((f + 8, x.shape[1]))
-    x_pad[:f] = x
-    x_pad[f:] = x[0]  # adversarial padding: a row that would score well
-    mask = np.arange(f + 8) < f
-    scores = np.asarray(
-        sis_scores_sharded(default_mesh(), jnp.asarray(x_pad), ctx,
-                           jnp.asarray(mask)))
-    assert np.all(scores[f:] == -np.inf)
-    assert np.all(np.isfinite(scores[:f]))
+    lose inside the sharded program itself — only the merged winners reach
+    the host, so host slice-off is no defense.  A zero-padded row scores
+    0.0 without the in-shard mask, which would win slots in a device merge;
+    ``ShardedExecution.sis_topk`` on 10 rows over 4 shards, asked for all
+    12 padded rows, must return only real rows."""
+    assert "SIS padding rows never win: OK" in padding_run
 
 
-def test_l0_padding_pairs_masked_inside_sharded_fn():
-    """Benign padding pairs must be +inf on device: a real (0, 1) solve
-    could genuinely win a block top-k and duplicate into the merge."""
-    import jax.numpy as jnp
-    import numpy as np
-    from repro.core.distributed import l0_pair_sses_sharded
-    from repro.engine.sharded import default_mesh
-
-    rng = np.random.default_rng(1)
-    m, s = 10, 80
-    x = rng.uniform(0.5, 3.0, (m, s))
-    y = 2.0 * x[0] - x[1] + 0.1 * rng.normal(size=s)
-    from repro.core.sis import TaskLayout
-
-    layout = TaskLayout.single(s)
-    pairs = np.zeros((8, 2), np.int32)
-    pairs[:4] = [(2, 3), (4, 5), (0, 1), (6, 7)]
-    pairs[4:] = (0, 1)  # padding uses the *best* real pair
-    valid = np.arange(8) < 4
-    sses = np.asarray(l0_pair_sses_sharded(
-        default_mesh(), jnp.asarray(x), jnp.asarray(y), layout,
-        jnp.asarray(pairs), jnp.asarray(valid)))
-    assert np.all(sses[4:] == np.inf)
-    assert np.all(np.isfinite(sses[:4]))
+def test_l0_padding_pairs_masked_inside_sharded_fn(padding_run):
+    """Padding tuples repeat the block's best (a real (0, 1) solve could
+    genuinely win a block top-k and duplicate into the merge): through
+    ``ShardedExecution.l0_topk`` over 4 shards (the generic pair scorer and
+    the Gram-gather kernel's reducer), no padding tuple may win."""
+    assert "L0 padding tuples never win: OK" in padding_run
 
 
 def test_fused_kernel_masks_padding_rows_in_kernel():
@@ -177,23 +155,24 @@ def test_reduced_block_contract_1shard_bit_identical():
     rng = np.random.default_rng(3)
     x, ctx, layout = _ctx_and_values(rng)
     eng = get_engine("sharded")
-    inner = get_engine("jnp")
-    rb = eng.sis_scores(x, ctx, n_keep=6)
+    inner = get_engine("jnp").backend
+    rb = eng.sis_topk(x, ctx, 6)
     assert isinstance(rb, ReducedBlock)
     assert len(rb) <= 6 and rb.n_source == len(x)
     full = np.asarray(inner.sis_scores(x, ctx), np.float64)
     order = np.argsort(-full, kind="stable")[:6]
     assert np.array_equal(rb.indices, order)
     assert np.array_equal(rb.scores, full[order])
-    # without n_keep the wrapper still serves full vectors (legacy path)
-    legacy = np.asarray(eng.sis_scores(x, ctx), np.float64)
-    assert legacy.shape == (len(x),)
-    np.testing.assert_allclose(legacy, full, rtol=1e-12)
+    # asked for every row, the wrapper's winners are the whole vector
+    from _dense import densify
+
+    every = densify(eng.sis_topk(x, ctx, len(x)))
+    np.testing.assert_allclose(every, full, rtol=1e-12)
 
 
 def test_reduced_block_l0_contract():
-    """engine.l0_scores(n_keep=...) returns O(k) ascending-SSE winners
-    whose values match the inner fp64 scores exactly."""
+    """engine.l0_topk returns O(k) ascending-SSE winners whose values
+    match the inner fp64 scores exactly."""
     import numpy as np
     from repro.core.sis import ReducedBlock, TaskLayout
     from repro.engine import get_engine
@@ -207,10 +186,10 @@ def test_reduced_block_l0_contract():
         list(__import__("itertools").combinations(range(m), 3)), np.int32)
     eng = get_engine("sharded")
     prob = eng.prepare_l0(x, y, layout)
-    rb = eng.l0_scores(prob, tuples, n_keep=5)
+    rb = eng.l0_topk(prob, tuples, 5)
     assert isinstance(rb, ReducedBlock) and len(rb) == 5
     assert (np.diff(rb.scores) >= 0).all()
-    inner = get_engine("jnp")
+    inner = get_engine("jnp").backend
     full = np.asarray(inner.l0_scores(inner.prepare_l0(x, y, layout), tuples))
     order = np.argsort(full, kind="stable")[:5]
     assert np.array_equal(rb.indices, order)
@@ -218,10 +197,10 @@ def test_reduced_block_l0_contract():
 
 
 def test_host_reduce_defaults_match_device_merge():
-    """The Backend base-class *_topk defaults (ReducedBlock.reduce_host)
-    are the reference semantics for any reducing backend: a host-reducing
-    jnp backend must produce bit-identical ReducedBlocks to the device
-    merge on a 1-shard mesh — same winners, same scores, same tie order."""
+    """The ScoringBackend *_topk defaults (ReducedBlock.reduce_host) are
+    the reference semantics for every backend: the host-reducing jnp
+    backend must produce bit-identical ReducedBlocks to the device merge
+    on a 1-shard mesh — same winners, same scores, same tie order."""
     import numpy as np
     from repro.core.sis import ReducedBlock, TaskLayout
     from repro.engine import Engine, JnpBackend, get_engine
@@ -229,13 +208,12 @@ def test_host_reduce_defaults_match_device_merge():
     rng = np.random.default_rng(5)
     x, ctx, layout = _ctx_and_values(rng)
     host = JnpBackend()
-    host.reduces_blocks = True  # opt the plain backend into n_keep routing
     eng_host, eng_dev = Engine(host), get_engine("sharded")
 
     mask = np.ones(len(x), bool)
     mask[3] = False
-    rb_h = eng_host.sis_scores(x, ctx, n_keep=6, mask=mask)
-    rb_d = eng_dev.sis_scores(x, ctx, n_keep=6, mask=mask)
+    rb_h = eng_host.sis_topk(x, ctx, 6, mask=mask)
+    rb_d = eng_dev.sis_topk(x, ctx, 6, mask=mask)
     assert isinstance(rb_h, ReducedBlock)
     assert np.array_equal(rb_h.indices, rb_d.indices)
     assert np.array_equal(rb_h.scores, rb_d.scores)
@@ -246,19 +224,17 @@ def test_host_reduce_defaults_match_device_merge():
     lay = TaskLayout.single(s)
     tuples = np.asarray(
         list(__import__("itertools").combinations(range(m), 2)), np.int32)
-    rb_h = eng_host.l0_scores(eng_host.prepare_l0(xs, y, lay), tuples,
-                              n_keep=5)
-    rb_d = eng_dev.l0_scores(eng_dev.prepare_l0(xs, y, lay), tuples,
-                             n_keep=5)
+    rb_h = eng_host.l0_topk(eng_host.prepare_l0(xs, y, lay), tuples, 5)
+    rb_d = eng_dev.l0_topk(eng_dev.prepare_l0(xs, y, lay), tuples, 5)
     assert np.array_equal(rb_h.indices, rb_d.indices)
     np.testing.assert_allclose(rb_h.scores, rb_d.scores, rtol=1e-12)
 
     from repro.core import operators as om
 
-    want = np.asarray(eng_host.sis_scores_deferred(
+    want = np.asarray(host.sis_scores_deferred(
         om.DIV, x[:8], x[8:16], ctx, 1e-5, 1e8), np.float64)
-    rb = eng_host.sis_scores_deferred(
-        om.DIV, x[:8], x[8:16], ctx, 1e-5, 1e8, n_keep=3)
+    rb = eng_host.sis_topk_deferred(
+        om.DIV, x[:8], x[8:16], ctx, 1e-5, 1e8, 3)
     order = np.argsort(-np.where(np.isfinite(want), want, -np.inf),
                        kind="stable")[:3]
     order = order[np.isfinite(want[order])]
@@ -272,7 +248,7 @@ def test_sharded_backend_shim_deprecated():
     with _pytest.warns(DeprecationWarning, match="ShardedBackend is deprecated"):
         shim = ShardedBackend()
     assert isinstance(shim, ShardedExecution)
-    assert shim.name == "sharded" and shim.reduces_blocks
+    assert shim.name == "sharded"
 
 
 def test_sharded_spec_parsing_and_nesting_guard():

@@ -9,6 +9,7 @@ All on the thermal reduced case — multi-task, on-the-fly deferred last rung
 import numpy as np
 import pytest
 
+from _dense import densify
 from repro.configs.sisso_thermal import thermal_conductivity_case
 from repro.core import SissoConfig, SissoSolver, compile_features, \
     operators as om
@@ -99,12 +100,14 @@ def test_sis_topk_parity_thermal(case, backend):
 
 @pytest.mark.parametrize("backend", DEVICE_BACKENDS)
 def test_sis_scores_parity_single_task(rng, backend):
-    """Raw block scores agree on a single-task, multi-residual layout."""
+    """Raw block scores agree on a single-task, multi-residual layout
+    (every row kept: ``n_keep`` is the block length)."""
     x = rng.uniform(0.5, 3.0, (60, 100))
     resid = rng.normal(size=(4, 100))
     ctx = build_score_context(resid, TaskLayout.single(100))
-    ref = get_engine("reference").sis_scores(x, ctx)
-    got = get_engine(backend).sis_scores(x, ctx)
+    ref = densify(get_engine("reference").sis_topk(x, ctx, len(x)))
+    got = densify(get_engine(backend).sis_topk(x, ctx, len(x)))
+    assert np.isfinite(ref).all()
     np.testing.assert_allclose(got, ref, atol=1e-7)
 
 
@@ -118,10 +121,13 @@ def test_sis_deferred_parity(case, backend):
     ref = get_engine("reference")
     eng = get_engine(backend)
     blk = next(fs.iter_candidate_batches(512))
-    want = ref.sis_scores_deferred(
-        blk.op_id, x[blk.child_a], x[blk.child_b], ctx, fs.l_bound, fs.u_bound)
-    got = eng.sis_scores_deferred(
-        blk.op_id, x[blk.child_a], x[blk.child_b], ctx, fs.l_bound, fs.u_bound)
+    n = len(blk.child_a)
+    want = densify(ref.sis_topk_deferred(
+        blk.op_id, x[blk.child_a], x[blk.child_b], ctx, fs.l_bound,
+        fs.u_bound, n))
+    got = densify(eng.sis_topk_deferred(
+        blk.op_id, x[blk.child_a], x[blk.child_b], ctx, fs.l_bound,
+        fs.u_bound, n))
     assert np.array_equal(np.isfinite(got), np.isfinite(want))
     f = np.isfinite(want)
     np.testing.assert_allclose(got[f], want[f], atol=5e-5)
@@ -132,11 +138,12 @@ def test_sis_deferred_parity(case, backend):
 def test_l0_scores_parity(rng, backend, width):
     """Per-tuple SSE matches the lstsq oracle for every tuple width.
 
-    Widths 2–4 are native kernels on pallas (pair gathers + the blocked
-    Gram-gather kernel); width 1 and everything ≥ 3 on sharded exercise
-    the generic jnp delegation.  The suite's tuple counts sit inside the
-    pallas backend's rescore window, so its values here are the exact
-    fp64 phase-2 numbers — which is the bit-exactness contract the
+    Every tuple is kept (``n_keep`` is the block length).  Widths 2–4 are
+    native kernels on pallas (pair gathers + the blocked Gram-gather
+    kernel); width 1 there, and widths without a device reducer on
+    sharded, exercise the generic scorers.  The suite's tuple counts sit
+    inside the pallas backend's rescore window, so its values here are the
+    exact fp64 phase-2 numbers — which is the bit-exactness contract the
     ℓ0 top-k merge relies on (m chosen so C(m, 4) < rescore_k)."""
     m, s = 12, 156
     x = rng.uniform(0.5, 3.0, (m, s))
@@ -145,9 +152,13 @@ def test_l0_scores_parity(rng, backend, width):
     tuples = np.asarray(
         list(__import__("itertools").combinations(range(m), width)), np.int32)
     ref = get_engine("reference")
-    want = ref.l0_scores(ref.prepare_l0(x, y, layout), tuples)
+    n = len(tuples)
+    want = densify(ref.l0_topk(ref.prepare_l0(x, y, layout), tuples, n),
+                   largest=False)
     eng = get_engine(backend)
-    got = eng.l0_scores(eng.prepare_l0(x, y, layout), tuples)
+    got = densify(eng.l0_topk(eng.prepare_l0(x, y, layout), tuples, n),
+                  largest=False)
+    assert np.isfinite(want).all()
     np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-9)
     assert np.argmin(got) == np.argmin(want)
 
@@ -197,9 +208,8 @@ def test_sis_reduced_block_matches_full_reduction(case, backend):
     ctx = build_score_context(case.y[None, :], layout)
     x = fs.values_matrix().astype(np.float64)
     eng = get_engine(backend)
-    assert eng.backend.reduces_blocks
     blk = next(fs.iter_candidate_batches(512))
-    full = get_engine("reference").sis_scores_deferred(
+    full = get_engine("reference").backend.sis_scores_deferred(
         blk.op_id, x[blk.child_a], x[blk.child_b], ctx, fs.l_bound, fs.u_bound)
     want = ReducedBlock.reduce_host(full, 25)
     got = eng.backend.sis_topk_deferred(
@@ -224,7 +234,7 @@ def test_l0_reduced_block_matches_full_reduction(rng, backend, width):
     layout = TaskLayout.from_task_ids(np.repeat([0, 1], 45))
     tuples = np.asarray(list(itertools.combinations(range(m), width)),
                         np.int32)
-    ref = get_engine("reference")
+    ref = get_engine("reference").backend
     full = ref.l0_scores(ref.prepare_l0(x, y, layout), tuples)
     order = np.argsort(full, kind="stable")[:8]
     eng = get_engine(backend)

@@ -204,9 +204,8 @@ def test_merge_block_results_matches_l0_search():
     results = {}
     for bi in range(enum.n_blocks):
         tuples = np.asarray(enum.block_tuples(bi))
-        sses = np.asarray(eng.l0_scores(prob, tuples))
-        part = np.argsort(sses, kind="stable")[:6]
-        results[bi] = (sses[part], tuples[part].astype(np.int64))
+        rb = eng.l0_topk(prob, tuples, 6)
+        results[bi] = (rb.scores, tuples[rb.indices].astype(np.int64))
     sse, tuples = merge_block_results(results, 6)
     np.testing.assert_array_equal(sse, ref.sses)
     np.testing.assert_array_equal(tuples, ref.tuples)
@@ -276,19 +275,20 @@ def test_transient_errors_retry_then_succeed():
     be = _fast_resilient()
     eng = Engine(be)
     prob, tuples = _l0_case(eng)
-    want = np.asarray(eng.l0_scores(prob, tuples))
+    want = eng.l0_topk(prob, tuples, 3)
     faults.install(FaultPlan().add("l0.block_scores", "err", at=1, upto=2))
     calls = {"n": 0}
-    inner_scores = be._inner.l0_scores
+    inner_topk = be._inner.l0_topk
 
-    def flaky(prob, tuples):
+    def flaky(prob, tuples, n_keep):
         calls["n"] += 1
         faults.check("l0.block_scores")
-        return inner_scores(prob, tuples)
+        return inner_topk(prob, tuples, n_keep)
 
-    be._inner.l0_scores = flaky
-    out = np.asarray(be.l0_scores(prob, tuples))
-    np.testing.assert_array_equal(out, want)
+    be._inner.l0_topk = flaky
+    out = be.l0_topk(prob, tuples, 3)
+    np.testing.assert_array_equal(out.indices, want.indices)
+    np.testing.assert_array_equal(out.scores, want.scores)
     assert calls["n"] == 3  # 2 transient failures + 1 success
     assert be.fault_stats["retries"] == 2
     assert be.fault_stats["demotions"] == {}
@@ -298,32 +298,33 @@ def test_exhausted_retries_demote_then_complete():
     be = _fast_resilient(max_attempts=2)
     eng = Engine(be)
     prob, tuples = _l0_case(eng)
-    want = np.asarray(get_engine("reference").l0_scores(
-        get_engine("reference").prepare_l0(prob.x, prob.y, prob.layout),
-        tuples))
+    ref = get_engine("reference")
+    want = ref.l0_topk(ref.prepare_l0(prob.x, prob.y, prob.layout),
+                       tuples, 3)
 
-    def always_down(prob, tuples):
+    def always_down(prob, tuples, n_keep):
         raise TransientDeviceError("l0.block_scores", 1)
 
-    be._inner.l0_scores = always_down
-    out = np.asarray(be.l0_scores(prob, tuples))
-    np.testing.assert_allclose(out, want, rtol=1e-9)
+    be._inner.l0_topk = always_down
+    out = be.l0_topk(prob, tuples, 3)
+    np.testing.assert_array_equal(out.indices, want.indices)
+    np.testing.assert_allclose(out.scores, want.scores, rtol=1e-9)
     st = be.fault_stats
     assert st["retries"] == 1  # max_attempts=2 -> one in-place retry
-    assert st["demotions"]["l0_scores"] >= 1
-    assert st["active_backend"]["l0_scores"] in ("jnp", "reference")
+    assert st["demotions"]["l0_topk"] >= 1
+    assert st["active_backend"]["l0_topk"] in ("jnp", "reference")
 
 
 def test_programming_errors_neither_retried_nor_demoted():
     be = _fast_resilient()
     prob, tuples = _l0_case(Engine(be))
 
-    def buggy(prob, tuples):
+    def buggy(prob, tuples, n_keep):
         raise ValueError("contract violation")
 
-    be._inner.l0_scores = buggy
+    be._inner.l0_topk = buggy
     with pytest.raises(ValueError):
-        be.l0_scores(prob, tuples)
+        be.l0_topk(prob, tuples, 3)
     assert be.fault_stats == {
         "retries": 0, "demotions": {}, "active_backend": {}}
 
@@ -371,7 +372,9 @@ def test_resilient_fit_demotes_broken_pallas_kernel():
 def test_resilient_spec_composes_with_sharded():
     eng = get_engine("resilient:sharded:jnp")
     assert eng.name == "resilient[sharded]"
-    assert eng.backend.reduces_blocks  # transparency: inner's contract
+    # transparency: the inner's capabilities read through
+    assert eng.backend.kernel_problems == eng.backend._inner.kernel_problems
+    assert eng.backend.l0_widths == eng.backend._inner.l0_widths
 
 
 # ---------------------------------------------------------------------------
